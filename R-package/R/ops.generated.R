@@ -1262,12 +1262,13 @@ mx.internal.nd.op.dispatch <- function(op, args, out, input.names = NULL) {
 }
 
 #' Return element-wise maximum of the input arrays.
-#' @param x Input x
-#' @param y Input y
+#' @param data List of input symbols
+#' @param out string, optional, default='None'
+#' @param where string, optional, default='None'
 #' @export
 `mx.nd._Maximum` <- function(..., out = NULL) {
   mx.internal.nd.op.dispatch("_Maximum", list(...), out,
-                             input.names = c("x", "y"))
+                             input.names = NULL)
 }
 
 #' Symbolic form of _Maximum
@@ -1293,12 +1294,13 @@ mx.internal.nd.op.dispatch <- function(op, args, out, input.names = NULL) {
 }
 
 #' Return element-wise minimum of the input arrays.
-#' @param x Input x
-#' @param y Input y
+#' @param data List of input symbols
+#' @param out string, optional, default='None'
+#' @param where string, optional, default='None'
 #' @export
 `mx.nd._Minimum` <- function(..., out = NULL) {
   mx.internal.nd.op.dispatch("_Minimum", list(...), out,
-                             input.names = c("x", "y"))
+                             input.names = NULL)
 }
 
 #' Symbolic form of _Minimum
@@ -2540,12 +2542,13 @@ mx.internal.nd.op.dispatch <- function(op, args, out, input.names = NULL) {
 }
 
 #' Return element-wise maximum of the input arrays.
-#' @param x Input x
-#' @param y Input y
+#' @param data List of input symbols
+#' @param out string, optional, default='None'
+#' @param where string, optional, default='None'
 #' @export
 `mx.nd._maximum` <- function(..., out = NULL) {
   mx.internal.nd.op.dispatch("_maximum", list(...), out,
-                             input.names = c("x", "y"))
+                             input.names = NULL)
 }
 
 #' Symbolic form of _maximum
@@ -2571,12 +2574,13 @@ mx.internal.nd.op.dispatch <- function(op, args, out, input.names = NULL) {
 }
 
 #' Return element-wise minimum of the input arrays.
-#' @param x Input x
-#' @param y Input y
+#' @param data List of input symbols
+#' @param out string, optional, default='None'
+#' @param where string, optional, default='None'
 #' @export
 `mx.nd._minimum` <- function(..., out = NULL) {
   mx.internal.nd.op.dispatch("_minimum", list(...), out,
-                             input.names = c("x", "y"))
+                             input.names = NULL)
 }
 
 #' Symbolic form of _minimum
@@ -3883,12 +3887,13 @@ mx.internal.nd.op.dispatch <- function(op, args, out, input.names = NULL) {
 }
 
 #' Return element-wise maximum of the input arrays.
-#' @param x Input x
-#' @param y Input y
+#' @param data List of input symbols
+#' @param out string, optional, default='None'
+#' @param where string, optional, default='None'
 #' @export
 `mx.nd.broadcast_maximum` <- function(..., out = NULL) {
   mx.internal.nd.op.dispatch("broadcast_maximum", list(...), out,
-                             input.names = c("x", "y"))
+                             input.names = NULL)
 }
 
 #' Symbolic form of broadcast_maximum
@@ -3898,12 +3903,13 @@ mx.internal.nd.op.dispatch <- function(op, args, out, input.names = NULL) {
 }
 
 #' Return element-wise minimum of the input arrays.
-#' @param x Input x
-#' @param y Input y
+#' @param data List of input symbols
+#' @param out string, optional, default='None'
+#' @param where string, optional, default='None'
 #' @export
 `mx.nd.broadcast_minimum` <- function(..., out = NULL) {
   mx.internal.nd.op.dispatch("broadcast_minimum", list(...), out,
-                             input.names = c("x", "y"))
+                             input.names = NULL)
 }
 
 #' Symbolic form of broadcast_minimum

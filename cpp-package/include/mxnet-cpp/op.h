@@ -1816,16 +1816,17 @@ inline Symbol _DivScalar(const std::string& symbol_name,
 /*!
  * \brief Return element-wise maximum of the input arrays.
  * \param symbol_name name of the resulting symbol
- * \param x Input x
- * \param y Input y
+ * \param data variable-length input symbol list
  * \return new symbol
  */
 inline Symbol _Maximum(const std::string& symbol_name,
-    Symbol x,
-    Symbol y) {
+    const std::vector<Symbol>& data,
+    const std::string& out = "None",
+    const std::string& where = "None") {
   Operator op_("_Maximum");
-  op_.SetInput("x", x);
-  op_.SetInput("y", y);
+  op_.SetParam("out", out);
+  op_.SetParam("where", where);
+  for (const auto& s : data) op_.AddInput(s);
   return op_.CreateSymbol(symbol_name);
 }
 
@@ -1851,16 +1852,17 @@ inline Symbol _MaximumScalar(const std::string& symbol_name,
 /*!
  * \brief Return element-wise minimum of the input arrays.
  * \param symbol_name name of the resulting symbol
- * \param x Input x
- * \param y Input y
+ * \param data variable-length input symbol list
  * \return new symbol
  */
 inline Symbol _Minimum(const std::string& symbol_name,
-    Symbol x,
-    Symbol y) {
+    const std::vector<Symbol>& data,
+    const std::string& out = "None",
+    const std::string& where = "None") {
   Operator op_("_Minimum");
-  op_.SetInput("x", x);
-  op_.SetInput("y", y);
+  op_.SetParam("out", out);
+  op_.SetParam("where", where);
+  for (const auto& s : data) op_.AddInput(s);
   return op_.CreateSymbol(symbol_name);
 }
 
@@ -3475,16 +3477,17 @@ inline Symbol _logical_xor_scalar(const std::string& symbol_name,
 /*!
  * \brief Return element-wise maximum of the input arrays.
  * \param symbol_name name of the resulting symbol
- * \param x Input x
- * \param y Input y
+ * \param data variable-length input symbol list
  * \return new symbol
  */
 inline Symbol _maximum(const std::string& symbol_name,
-    Symbol x,
-    Symbol y) {
+    const std::vector<Symbol>& data,
+    const std::string& out = "None",
+    const std::string& where = "None") {
   Operator op_("_maximum");
-  op_.SetInput("x", x);
-  op_.SetInput("y", y);
+  op_.SetParam("out", out);
+  op_.SetParam("where", where);
+  for (const auto& s : data) op_.AddInput(s);
   return op_.CreateSymbol(symbol_name);
 }
 
@@ -3510,16 +3513,17 @@ inline Symbol _maximum_scalar(const std::string& symbol_name,
 /*!
  * \brief Return element-wise minimum of the input arrays.
  * \param symbol_name name of the resulting symbol
- * \param x Input x
- * \param y Input y
+ * \param data variable-length input symbol list
  * \return new symbol
  */
 inline Symbol _minimum(const std::string& symbol_name,
-    Symbol x,
-    Symbol y) {
+    const std::vector<Symbol>& data,
+    const std::string& out = "None",
+    const std::string& where = "None") {
   Operator op_("_minimum");
-  op_.SetInput("x", x);
-  op_.SetInput("y", y);
+  op_.SetParam("out", out);
+  op_.SetParam("where", where);
+  for (const auto& s : data) op_.AddInput(s);
   return op_.CreateSymbol(symbol_name);
 }
 
@@ -5068,32 +5072,34 @@ inline Symbol broadcast_logical_xor(const std::string& symbol_name,
 /*!
  * \brief Return element-wise maximum of the input arrays.
  * \param symbol_name name of the resulting symbol
- * \param x Input x
- * \param y Input y
+ * \param data variable-length input symbol list
  * \return new symbol
  */
 inline Symbol broadcast_maximum(const std::string& symbol_name,
-    Symbol x,
-    Symbol y) {
+    const std::vector<Symbol>& data,
+    const std::string& out = "None",
+    const std::string& where = "None") {
   Operator op_("broadcast_maximum");
-  op_.SetInput("x", x);
-  op_.SetInput("y", y);
+  op_.SetParam("out", out);
+  op_.SetParam("where", where);
+  for (const auto& s : data) op_.AddInput(s);
   return op_.CreateSymbol(symbol_name);
 }
 
 /*!
  * \brief Return element-wise minimum of the input arrays.
  * \param symbol_name name of the resulting symbol
- * \param x Input x
- * \param y Input y
+ * \param data variable-length input symbol list
  * \return new symbol
  */
 inline Symbol broadcast_minimum(const std::string& symbol_name,
-    Symbol x,
-    Symbol y) {
+    const std::vector<Symbol>& data,
+    const std::string& out = "None",
+    const std::string& where = "None") {
   Operator op_("broadcast_minimum");
-  op_.SetInput("x", x);
-  op_.SetInput("y", y);
+  op_.SetParam("out", out);
+  op_.SetParam("where", where);
+  for (const auto& s : data) op_.AddInput(s);
   return op_.CreateSymbol(symbol_name);
 }
 

@@ -6,7 +6,7 @@ net). TPU-native form: the same trade is TrainStep(remat=...) — False
 (save everything), "conv" (save conv/dot outputs, recompute the
 elementwise tail), True (full recompute) — and the cost is read
 straight from the compiled program's memory analysis instead of nvidia
--smi. PROFILE.md records the on-chip throughput side of this trade.
+-smi. The on-chip throughput side of this trade: see ROADMAP S8.
 """
 import argparse
 
@@ -62,9 +62,9 @@ def main():
     # the residual set. Full recompute is NOT automatically a peak win:
     # the backward re-materializes activations, and whether peak falls
     # depends on how the scheduler interleaves recompute with consume
-    # (PROFILE.md measures the TPU side of this trade: on the ResNet
-    # graph it costs bytes-accessed, i.e. it is a memory lever for
-    # memory-LIMITED models, not a default).
+    # (the round-5 chip record, ROADMAP S8: on the ResNet graph it
+    # costs speed, i.e. it is a memory lever for memory-LIMITED
+    # models, not a default).
     print("conv-remat temp: %.3fx of no-remat" % (conv / base))
     print("full-remat temp: %.3fx of no-remat" % (full / base))
     print("memcost ok: %s" % (conv <= base))

@@ -13,6 +13,10 @@ __version__ = "0.1.0"
 from . import base  # noqa: F401
 from .base import MXNetError  # noqa: F401
 from .context import Context, cpu, cpu_pinned, current_context, gpu, num_gpus, num_tpus, tpu  # noqa: F401
+from . import context  # noqa: F401
+
+# before anything can compile (context.compile_cache_dir says why)
+context.compile_cache_dir()
 
 from . import ndarray  # noqa: F401
 from . import ndarray as nd  # noqa: F401

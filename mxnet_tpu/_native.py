@@ -8,6 +8,8 @@ libmxnet.so via ctypes. The library is built from ``src/`` on demand
 from __future__ import annotations
 
 import ctypes
+import hashlib
+import logging
 import os
 import subprocess
 import threading
@@ -15,7 +17,7 @@ import threading
 _LIB = None
 _LOCK = threading.Lock()
 _SRC_FILES = ("common.cc", "engine.cc", "storage.cc", "recordio.cc",
-              "mxtpu_runtime.h")
+              "mxtpu_runtime.h", "Makefile")
 
 
 def _repo_root():
@@ -27,14 +29,31 @@ def _lib_path():
                         "lib", "libmxtpu_runtime.so")
 
 
+def _src_digest(srcdir):
+    """sha256 over the runtime's sources. Staleness is decided by
+    content, not mtime: a checkout or a copied tree resets every mtime,
+    and a git-ignored .so on disk then looks newer than sources it was
+    never built from."""
+    h = hashlib.sha256()
+    for f in _SRC_FILES:
+        h.update(f.encode() + b"\0")
+        with open(os.path.join(srcdir, f), "rb") as fh:
+            h.update(fh.read())
+    return h.hexdigest()
+
+
+def _stamp_path(lib):
+    return lib + ".srchash"
+
+
 def _needs_build(lib, srcdir):
     if not os.path.exists(lib):
         return True
-    lib_mtime = os.path.getmtime(lib)
-    return any(
-        os.path.getmtime(os.path.join(srcdir, f)) > lib_mtime
-        for f in _SRC_FILES if os.path.exists(os.path.join(srcdir, f))
-    )
+    try:
+        with open(_stamp_path(lib)) as fh:
+            return fh.read().strip() != _src_digest(srcdir)
+    except OSError:
+        return True
 
 
 def _build():
@@ -66,6 +85,9 @@ def _build():
             subprocess.run(["make", "-C", srcdir, "OUT=%s" % tmp],
                            check=True, capture_output=True)
             os.replace(tmp, lib)
+            with open(tmp, "w") as fh:
+                fh.write(_src_digest(srcdir) + "\n")
+            os.replace(tmp, _stamp_path(lib))
         finally:
             if os.path.exists(tmp):
                 os.remove(tmp)
@@ -143,7 +165,15 @@ def get_lib():
                 _LIB = False
                 return None
             _LIB = _declare(ctypes.CDLL(lib))
-        except (OSError, subprocess.CalledProcessError):
+        except (OSError, subprocess.CalledProcessError) as e:
+            # the pure-Python fallbacks take over, but never silently:
+            # say once what failed, with the compiler's own words
+            detail = getattr(e, "stderr", None) or b""
+            logging.getLogger("mxnet_tpu").warning(
+                "native runtime unavailable (%s); using the pure-Python "
+                "fallbacks%s", e,
+                "\n" + detail.decode(errors="replace")[-2000:]
+                if detail else "")
             _LIB = False
             return None
     return _LIB or None

@@ -1,8 +1,8 @@
 """Selective rematerialization: a save/recompute pass over the
 training graph (ISSUE 19, ROADMAP item 4).
 
-PROFILE.md's ceiling argument says training is HBM-bandwidth-bound —
-the lever is moving fewer bytes, not more FLOPs — yet the one
+The round-5 ceiling argument (ROADMAP S1) says training is
+HBM-bandwidth-bound — the lever is moving fewer bytes, not more FLOPs — yet the one
 training-side memory knob, ``TrainStep(remat=True)``, is a global
 ``jax.checkpoint`` that recomputes *everything* in backward, MXU ops
 included, and measurably loses throughput. The selective form is a

@@ -17,8 +17,10 @@ Design: O(S) memory — no materialized (S, S) score matrix.
   K blocks), both re-forming p = exp(qk - lse) blockwise on the MXU.
 
 All matmuls use ``preferred_element_type=jnp.float32`` (MXU accumulates
-fp32); inputs may be bf16. ``interpret=None`` auto-selects interpreter
-mode off-TPU so the CPU test mesh exercises the same code path.
+fp32); inputs may be bf16. ``interpret=None`` compiles with Mosaic on a
+TPU and selects interpreter mode on the CPU test mesh, so the tests
+exercise the same code path; any other backend raises
+(``context.kernel_platform``).
 """
 from __future__ import annotations
 
@@ -27,6 +29,8 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+
+from ..context import kernel_platform
 
 _NEG_INF = -1e30
 
@@ -38,7 +42,7 @@ def _round_up(x, m):
 def _need_interpret(interpret):
     if interpret is not None:
         return interpret
-    return jax.default_backend() != "tpu"
+    return kernel_platform() == "cpu"
 
 
 def _mask_scores(s, iq, jk, block_q, block_k, causal, kv_len, seq_k):

@@ -3,8 +3,9 @@
 Reference counterpart: the conv/BN/ReLU chains built by
 ``example/image-classification/symbols/resnet.py`` residual_unit — on the
 reference stack each op is a separate cuDNN/CUDA kernel and the
-activations round-trip device memory between them. Profiling
-(PROFILE.md) shows the TPU port is HBM-bandwidth-bound the same way:
+activations round-trip device memory between them. The round-5
+compiler estimate (ROADMAP S1) says the TPU port is HBM-bandwidth-bound
+the same way:
 XLA materializes every BN input/output, so a ResNet-50 train step moves
 ~78 GB/step where ~48 GB is the structural minimum.
 
@@ -26,7 +27,7 @@ BN semantics can do.
 MXU blocking (round 6): the round-4/5 kernels tiled the grid
 ``(image, row-tile)`` so every MXU call saw a ``(th*W_out, Ci)`` row
 block — at ResNet-50 shapes that is 196-784 rows against Ci,Co as
-small as 64, and the on-chip measurement (PROFILE.md round 5) showed
+small as 64, and the round-5 on-chip measurement (ROADMAP S2) showed
 the resulting MXU underutilization costs 2.5x more than the HBM
 traffic the fusion saves. The grid is now
 ``(channel-block, batch-block, row-tile)`` with **the batch folded
@@ -69,6 +70,7 @@ from jax.experimental.pallas import tpu as pltpu
 from jax.sharding import PartitionSpec as P
 
 from .. import config as _config
+from ..context import kernel_platform
 from ..util import shard_map as _shard_map
 
 # One MXU call must see at least this many multiply-accumulates
@@ -120,7 +122,7 @@ def _row_tile_default():
 def _need_interpret(interpret):
     if interpret is not None:
         return interpret
-    return jax.default_backend() != "tpu"
+    return kernel_platform() == "cpu"
 
 
 def _tile_rows(h_out, limit=None):
@@ -168,7 +170,7 @@ def _dim_semantics(accumulates):
     into a revisited output ref (BN stats, dw)."""
     sem = ("parallel",) + (("arbitrary",) * 2 if accumulates
                            else ("parallel",) * 2)
-    return pltpu.TPUCompilerParams(dimension_semantics=sem)
+    return pltpu.CompilerParams(dimension_semantics=sem)
 
 
 def _pad_w(v, left=1, right=1):
@@ -1129,7 +1131,7 @@ def bottleneck_infer(data, w1, w2, w3, wsc, g1, b1, g2, b2, g3, b3,
 # with the batch axis manual. The wrappers below do that with an explicit
 # custom VJP — fwd and bwd are each their own shard_map region, and every
 # cross-shard reduction (BN statistic sums, weight grads) is an explicit
-# psum over the data axes, so ``check_rep=False`` is sound. Reference
+# psum over the data axes, so ``check_vma=False`` is sound. Reference
 # counterpart of the reduction this replaces: src/kvstore/comm.h:484-690
 # (device-tree gradient reduce); here it rides ICI inside the step.
 

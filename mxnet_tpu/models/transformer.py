@@ -39,6 +39,7 @@ import numpy as np
 from jax import lax
 from jax.sharding import NamedSharding, PartitionSpec as P
 
+from ..context import kernel_platform
 from ..util import shard_map as _shard_map
 
 from ..parallel.ring import ring_attention_inner, full_attention
@@ -159,8 +160,10 @@ def _attention(q, k, v, *, axes, causal=True, attn="auto", blocks=None):
         attn = "ring" if has_sp else "flash"
     if not has_sp:
         # flash_attention pads the head dim to the 128-lane tile internally,
-        # so common head dims (64, 80, ...) all take the O(S)-memory kernel
-        if attn == "flash" and jax.default_backend() == "tpu":
+        # so common head dims (64, 80, ...) all take the O(S)-memory kernel.
+        # The materialized-scores reference is the cpu test path only:
+        # kernel_platform() raises on any backend that is neither.
+        if attn == "flash" and kernel_platform() == "tpu":
             from ..kernels import flash_attention
             bq, bk = blocks or (None, None)
             return flash_attention(q, k, v, causal=causal,
@@ -354,6 +357,7 @@ def make_train_step(config, mesh, optimizer=None, data_axes=("dp",)):
         return (new_p, new_s, n + 1), loss
 
     shardings = {k: NamedSharding(mesh, s) for k, s in specs.items()}
+    rep = NamedSharding(mesh, P())
 
     def place(params):
         opt_state = opt.init(params)
@@ -361,9 +365,20 @@ def make_train_step(config, mesh, optimizer=None, data_axes=("dp",)):
         opt_state = {k: jax.tree_util.tree_map(
             lambda x: jax.device_put(x, shardings[k]), v)
             for k, v in opt_state.items()}
-        return (params, opt_state, jnp.zeros((), jnp.int32))
+        return (params, opt_state, jax.device_put(jnp.zeros((), jnp.int32),
+                                                  rep))
 
-    return jax.jit(step, donate_argnums=(0,)), place
+    # The carry leaves the step laid out exactly as place() put it in.
+    # Left to the compiler, the outputs come back under differently
+    # spelled (equivalent) shardings and the second call compiles the
+    # whole step again (seen on the chip: 6 s of a 21 s phase).
+    # (only the optimizer state's tree structure is needed, so scalars
+    # stand in for the params: param_specs names exactly init_params' keys)
+    like = {k: jax.ShapeDtypeStruct((), jnp.float32) for k in shardings}
+    opt_sh = {k: jax.tree_util.tree_map(lambda _: shardings[k], v)
+              for k, v in jax.eval_shape(opt.init, like).items()}
+    return jax.jit(step, donate_argnums=(0,),
+                   out_shardings=((shardings, opt_sh, rep), rep)), place
 
 
 # ---------------------------------------------------------------------------
@@ -374,10 +389,7 @@ def make_train_step(config, mesh, optimizer=None, data_axes=("dp",)):
 # text counting unstable (the PR 19 lesson).
 # ---------------------------------------------------------------------------
 def _sub_jaxprs(eqn):
-    try:
-        from jax.extend import core as _core
-    except ImportError:  # jax 0.4.x
-        from jax import core as _core
+    from jax.extend import core as _core
 
     for v in eqn.params.values():
         vals = v if isinstance(v, (tuple, list)) else (v,)
@@ -619,9 +631,16 @@ def _stacked_layer_params(params):
                          "final_ln_gamma", "final_ln_beta")}
 
 
-def make_prefill_fn(config, page_size):
+def make_prefill_fn(config, page_size, mesh=None):
     """fn(params, cache, tokens (1, S_pad) int32, length () int32,
     pages (S_pad // page_size,) int32) → (cache', logits (V,) fp32).
+
+    ``mesh``: the replica group's mesh when the program is bound sharded
+    (``GenerativePredictor(mesh=)``). Everything but attention is plain
+    jnp that GSPMD partitions; attention runs under ``shard_map`` over the
+    heads the mesh's mp axis already shards, because a Mosaic kernel
+    cannot be partitioned automatically (on the chip the lowering raises
+    "Mosaic kernels cannot be automatically partitioned").
 
     Runs the SAME causal block forward as ``make_forward_fn`` over the
     padded prompt (so flash/full attention and its schedule consult are
@@ -636,6 +655,13 @@ def make_prefill_fn(config, page_size):
     c = config
     cdt = jnp.dtype(c.dtype)
     page_size = int(page_size)
+    attend = functools.partial(_attention, axes=frozenset(), attn=c.attn,
+                               blocks=(c.attn_block_q, c.attn_block_k))
+    t = _mp_axis(set(mesh.axis_names)) if mesh is not None else None
+    if t:
+        heads = P(None, t, None, None)
+        attend = _shard_map(attend, mesh=mesh, in_specs=(heads,) * 3,
+                            out_specs=heads, check_vma=False)
 
     def prefill(params, cache, tokens, length, pages):
         _b, S = tokens.shape
@@ -658,8 +684,7 @@ def make_prefill_fn(config, page_size):
                 n_pages, page_size, c.n_heads, -1)
             cl = cl.at[0, pages].set(kp.astype(cl.dtype))
             cl = cl.at[1, pages].set(vp.astype(cl.dtype))
-            o = _attention(q, k, v, axes=frozenset(), attn=c.attn,
-                           blocks=(c.attn_block_q, c.attn_block_k))
+            o = attend(q, k, v)
             o = jnp.einsum("bhse,hed->bsd", o,
                            lp["attn_out_weight"].astype(cdt))
             return _ffn(x + o, lp, c, frozenset(), cdt), cl

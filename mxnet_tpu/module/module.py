@@ -31,12 +31,17 @@ class Module(BaseModule):
     def __init__(self, symbol, data_names=("data",), label_names=("softmax_label",),
                  logger=logging, context=None, work_load_list=None,
                  fixed_param_names=None, state_names=None, group2ctxs=None,
-                 compression_params=None, zero=None):
+                 compression_params=None, zero=None, compute_dtype=None):
         super().__init__(logger=logger)
         # ISSUE 7: weight-update sharding on the fused tier. True/False
         # forces it; None defers to the MXNET_TPU_ZERO env knob — so
         # Module.fit users get ZeRO without touching jax.
         self._zero = zero
+        # mixed precision on the fused step (kvstore='tpu'): forward and
+        # backward in this dtype, fp32 masters. Only the fused step can
+        # honour it — init_optimizer raises rather than train in fp32 on
+        # the per-executor path.
+        self._compute_dtype = compute_dtype
         if context is None:
             context = ctx_mod.current_context()
         if isinstance(context, ctx_mod.Context):
@@ -331,21 +336,28 @@ class Module(BaseModule):
                     inputs_need_grad=self.inputs_need_grad,
                     distributed=distributed,
                     zero=self._zero,
+                    compute_dtype=self._compute_dtype,
                 )
-                if hasattr(kvstore, "attach_mesh"):
-                    kvstore.attach_mesh(self._fused.mesh)
-                update_on_kvstore = False
-                self._update_on_kvstore = False
             except MXNetError as e:
+                # typed: a request the fused step cannot mirror (optimizer,
+                # fixed params, ...). Anything else — mesh or device
+                # construction failing — is an error and propagates.
                 self.logger.warning(
                     "kvstore=%r: %s; using per-executor update path",
                     kvstore.type, e)
                 self._fused = None
-            except Exception as e:  # mesh/device construction failed
-                self.logger.warning(
-                    "kvstore=%r: fused step unavailable (%r); using "
-                    "per-executor update path", kvstore.type, e)
-                self._fused = None
+            else:
+                if hasattr(kvstore, "attach_mesh"):
+                    kvstore.attach_mesh(self._fused.mesh)
+                update_on_kvstore = False
+                self._update_on_kvstore = False
+        if self._compute_dtype is not None and self._fused is None:
+            raise MXNetError(
+                "Module(compute_dtype=%r) needs the fused train step "
+                "(kvstore='tpu' or dist_sync, for_training); the "
+                "per-executor path with kvstore=%r computes in the "
+                "parameters' dtype"
+                % (self._compute_dtype, getattr(kvstore, "type", None)))
 
         if self._fused is not None and getattr(self, "_monitor_installed",
                                                False):

@@ -86,7 +86,7 @@ class FusedSPMDGroup:
     def __init__(self, symbol, contexts, optimizer, arg_params, aux_params,
                  data_names, label_names, fixed_param_names=None, logger=None,
                  batch_size=None, inputs_need_grad=False, distributed=False,
-                 zero=None):
+                 zero=None, compute_dtype=None):
         import jax
 
         if fixed_param_names:
@@ -156,6 +156,9 @@ class FusedSPMDGroup:
         if zero is None:
             zero = config.get_strict_bool("MXNET_TPU_ZERO")
         self.zero = bool(zero)
+        # Module(compute_dtype=): TrainStep's mixed precision — masters,
+        # optimizer state and BN stats stay fp32
+        self._compute_dtype = compute_dtype
         self._fopt = functional_from_optimizer(
             optimizer, [n for n in symbol.list_arguments()
                         if n not in data_names and n not in label_names])
@@ -164,7 +167,8 @@ class FusedSPMDGroup:
             symbol, self._fopt, mesh=self.mesh, data_axes=self._data_axes,
             param_rules=self._param_rules,
             data_names=tuple(data_names), label_names=tuple(label_names),
-            compute_dtype=None, normalize_grads=False, return_outputs=True,
+            compute_dtype=self._compute_dtype,
+            normalize_grads=False, return_outputs=True,
             metric_stats=self._device_metrics, zero=self.zero,
         )
         self.param_names = list(self._ts.param_names)
@@ -621,7 +625,8 @@ class FusedSPMDGroup:
             param_rules=self._param_rules,
             data_names=tuple(self._data_names),
             label_names=tuple(self._label_names),
-            compute_dtype=None, normalize_grads=False, return_outputs=True,
+            compute_dtype=self._compute_dtype,
+            normalize_grads=False, return_outputs=True,
             metric_stats=self._device_metrics, zero=self.zero,
         )
         carry = self._ts.place(params, logical, aux)

@@ -4,8 +4,8 @@ Reference counterpart: none as an *op* — the reference reaches these
 fusion boundaries with cuDNN/NNVM graph passes (conv+BN folding is an
 inference-only trick there, src/operator/nn/batch_norm.cc keeps training
 unfused). On TPU the training-time fusion is the single remaining perf
-lever (PROFILE.md), so the framework exposes it as a first-class op that
-the IR fusion pass (``mxnet_tpu/ir/rules.py`` ``bottleneck_fuse``)
+lever (round-5 record; ROADMAP S1/S2), so the framework exposes it as
+a first-class op that the IR fusion pass (``mxnet_tpu/ir/rules.py`` ``bottleneck_fuse``)
 emits when rewriting the unfused builder graph (``fused=True`` routes
 through that pass since ISSUE 13).
 

@@ -750,7 +750,7 @@ class TrainStep:
             # the custom_vjp/pallas prims the fused units trace as) and
             # recompute the cheap elementwise tail (BN apply, ReLU, pad)
             # inside backward — on a bandwidth-bound graph this trades
-            # spare MXU FLOPs for HBM traffic (see PROFILE.md).
+            # spare MXU FLOPs for HBM traffic (ROADMAP S8).
             # remat="pass": the per-SITE IR plan (ir/remat.py) — saved
             # node outputs carry checkpoint_name tags from the graph
             # closure and the policy keeps exactly those names.
@@ -770,7 +770,7 @@ class TrainStep:
 
     def residual_stats(self, params, aux, batch, key=None):
         """AD-level backward-residual accounting for the loss under the
-        current remat mode (``jax.ad_checkpoint.saved_residuals``):
+        current remat mode (jax's ``saved_residuals``):
         ``residual_bytes`` is the total the backward pass must hold,
         ``n_residuals`` the entry count. This is the remat decision's
         direct, backend-independent measure — XLA's CPU pipeline strips
@@ -778,10 +778,9 @@ class TrainStep:
         the forward, so ``compiled_memory_stats`` on CPU cannot see
         what the TPU compiler (which honors the barriers) does; the
         residual set is what the policy actually changed."""
-        try:
-            from jax.ad_checkpoint import saved_residuals
-        except ImportError:  # not re-exported publicly on jax 0.4.x
-            from jax._src.ad_checkpoint import saved_residuals
+        # jax 0.9 has no public re-export (jax.ad_checkpoint lacks it);
+        # the private module is the only place saved_residuals lives
+        from jax._src.ad_checkpoint import saved_residuals
 
         if key is None:
             from .. import random as _rnd

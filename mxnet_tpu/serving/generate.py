@@ -511,14 +511,18 @@ class GenerativePredictor:
 
     def _config_fingerprint(self):
         """Everything a compiled program's closure bakes in besides the
-        bucket/slot tag: the model architecture and the page geometry.
+        bucket/slot tag: the model architecture, the page geometry and
+        the mesh a sharded bind's prefill shard_maps over.
         Part of every cache key so two predictors sharing one
         ExecutableCache under the same model name can never reuse each
         other's programs."""
         import dataclasses
 
+        mesh = None if self._mesh is None else (
+            tuple(self._mesh.shape.items()),
+            tuple(d.id for d in self._mesh.devices.flat))
         return (tuple(sorted(dataclasses.asdict(self.config).items())),
-                self.page_size, self.max_pages_per_slot, self.block_k)
+                self.page_size, self.max_pages_per_slot, self.block_k, mesh)
 
     def _prefill_exec(self, bucket):
         from ..models import transformer as tfm
@@ -526,8 +530,8 @@ class GenerativePredictor:
         key = (self._cache_key, ("prefill", bucket),
                self._config_fingerprint(), self._dtype_name)
         return self._exec_cache.get_or_build(
-            key, lambda: self._jit(tfm.make_prefill_fn(self.config,
-                                                       self.page_size)))
+            key, lambda: self._jit(tfm.make_prefill_fn(
+                self.config, self.page_size, mesh=self._mesh)))
 
     def _decode_exec(self):
         from ..models import transformer as tfm
@@ -675,10 +679,12 @@ class GenerativePredictor:
             return sum(int(s.data.nbytes) for s in arr.addressable_shards
                        if s.device == dev0)
 
+        # read the cache under the lock: a concurrent prefill/decode
+        # donates self._kv, and a donated array's shards are gone
         with self._lock:
-            kv = self._kv
+            kv_chip = chip_bytes(self._kv)
+            kv_total = int(self._kv.nbytes)
         param_chip = sum(chip_bytes(v) for v in self._params.values())
-        kv_chip = chip_bytes(kv)
         mp = int(dict(self._mesh.shape).get(
             "mp", dict(self._mesh.shape).get("tp", 1)))
         from .. import profiler
@@ -689,4 +695,4 @@ class GenerativePredictor:
         return {"group_size": self._group_size, "mp_size": mp,
                 "param_bytes_per_chip": param_chip,
                 "kv_bytes_per_chip": kv_chip,
-                "kv_bytes_total": int(kv.nbytes)}
+                "kv_bytes_total": kv_total}

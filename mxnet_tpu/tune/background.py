@@ -49,19 +49,18 @@ class BackgroundTuner:
     boundaries; see the module docstring for the safety contract."""
 
     def __init__(self, budget=2, table=None, logger=None, sweep_kw=None):
-        import jax
+        from ..context import kernel_platform
 
         self.budget = int(budget)
         self._table = table if table is not None else get_table()
         self._log = logger or log
-        on_tpu = jax.default_backend() == "tpu"
+        on_tpu = kernel_platform() == "tpu"
         # bounded per-slot timing discipline: short calibration target,
         # few repeats — a slot is a sliver of an epoch, not a bench run
         self._sweep_kw = dict(
             repeats=2,
             target_sec=0.2 if on_tpu else 0.02,
-            min_iters=100 if on_tpu else 2,
-            interpret=None if on_tpu else True)
+            min_iters=100 if on_tpu else 2)
         if sweep_kw:
             self._sweep_kw.update(sweep_kw)
 

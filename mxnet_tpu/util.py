@@ -22,21 +22,10 @@ def get_gpu_count():
 
 
 def shard_map(f, mesh, in_specs, out_specs, check_vma=None):
-    """Version-portable ``shard_map``.
-
-    Newer jax exposes ``jax.shard_map(..., check_vma=)``; 0.4.x only
-    has ``jax.experimental.shard_map.shard_map(..., check_rep=)``.
-    Every shard_map in this codebase goes through here so the library
-    imports (and the CPU test mesh runs) on both."""
+    """``jax.shard_map`` with ``check_vma`` passed only when set — the
+    one spelling every shard_map in this codebase goes through."""
     import jax
 
-    sm = getattr(jax, "shard_map", None)
-    if sm is not None:
-        kw = {} if check_vma is None else {"check_vma": check_vma}
-        return sm(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                  **kw)
-    from jax.experimental.shard_map import shard_map as legacy
-
-    kw = {} if check_vma is None else {"check_rep": check_vma}
-    return legacy(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                  **kw)
+    kw = {} if check_vma is None else {"check_vma": check_vma}
+    return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
+                         out_specs=out_specs, **kw)

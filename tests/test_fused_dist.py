@@ -9,10 +9,12 @@ import subprocess
 import sys
 
 import numpy as np
+import pytest
 
 ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
 
+@pytest.mark.slow   # PR 21: tier-1 wall (tests/README.md)
 def test_fused_dist_sync_matches_single_process(tmp_path):
     env = dict(os.environ)
     env.pop("MXNET_TPU_COORDINATOR", None)

@@ -101,6 +101,7 @@ def _assert_unit_parity(args, stride, atol=3e-4, gtol=1e-3):
 # ---------------------------------------------------------------------------
 # 1. parity at the three block flavors, multi-block grids forced
 # ---------------------------------------------------------------------------
+@pytest.mark.slow   # PR 21: tier-1 wall (tests/README.md)
 @pytest.mark.parametrize("stride,dim_match", [(1, True), (1, False),
                                               (2, False)])
 def test_parity_multi_batch_block_grid(stride, dim_match, monkeypatch):
@@ -114,6 +115,7 @@ def test_parity_multi_batch_block_grid(stride, dim_match, monkeypatch):
     _assert_unit_parity(args, stride)
 
 
+@pytest.mark.slow   # PR 21: tier-1 wall (tests/README.md)
 @pytest.mark.parametrize("stride,dim_match", [(1, True), (2, False)])
 def test_parity_channel_blocked_grid(stride, dim_match):
     """co=512 output convs split into two 256-lane channel blocks
@@ -151,6 +153,7 @@ def test_conv_kernels_channel_blocked_parity():
                                rtol=1e-4, atol=2e-3)
 
 
+@pytest.mark.slow   # PR 21: tier-1 wall (tests/README.md)
 def test_row_tile_knob():
     """set_row_tile (and the env knob behind it) changes the planned
     row tile and keeps parity."""
@@ -216,6 +219,7 @@ def test_mxu_floor_not_met_on_tiny_shapes_is_reported():
 # ---------------------------------------------------------------------------
 # 3. the loop-amortized benchmark harness is runnable (plumbing smoke)
 # ---------------------------------------------------------------------------
+@pytest.mark.slow   # PR 21: tier-1 wall (tests/README.md)
 def test_bench_kernel_harness_smoke():
     env = dict(os.environ)
     env["JAX_PLATFORMS"] = "cpu"

@@ -151,8 +151,13 @@ def test_fused_resnet_matches_unfused():
         if k in ("data", "softmax_label"):
             continue
         a, b = grads["fused"][k], grads["unfused"][k]
-        scale = np.abs(b).max() + 1e-6
-        assert np.abs(a - b).max() / scale < 2e-3, k
+        # 2e-3 of the gradient's own scale, over a 1e-6 floor: every
+        # gradient here differs by 1e-8..2e-6 absolute, the fp32 rounding
+        # of the O(0.1) terms summed into it. bn0_gamma's terms cancel
+        # to 7e-5 (the next layer normalizes its scale away), so its
+        # 1.6e-7 is 2.2e-3 of its own max and says nothing about the
+        # kernel; the next worst is bn0_beta at 2.4e-4, the rest < 2e-5.
+        assert np.abs(a - b).max() < 2e-3 * np.abs(b).max() + 1e-6, k
 
 
 def test_fused_resnet_trains_and_infers():

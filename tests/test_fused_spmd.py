@@ -39,6 +39,7 @@ def _mesh(n=8, names=("dp",), shape=None):
     return Mesh(devs, names)
 
 
+@pytest.mark.slow   # PR 21: tier-1 wall (tests/README.md)
 @pytest.mark.parametrize("stride,shortcut", [(1, False), (2, True)])
 def test_bottleneck_spmd_matches_single_device(stride, shortcut):
     n, h, w, ci, csq = 8, 8, 8, 16, 8
@@ -77,6 +78,7 @@ def test_bottleneck_spmd_matches_single_device(stride, shortcut):
                                    rtol=2e-4, atol=1e-5)
 
 
+@pytest.mark.slow   # PR 21: tier-1 wall (tests/README.md)
 def test_bottleneck_infer_spmd_matches_single_device():
     n, h, w, ci, csq = 8, 8, 8, 16, 8
     ks = jax.random.split(jax.random.PRNGKey(1), 12)
@@ -126,7 +128,8 @@ def _run_steps(ts, pn, an, batch_np, n_steps=2, place_sharding=None):
 
 @pytest.mark.parametrize("axes,mesh_kw", [
     (("dp",), dict(names=("dp",))),
-    (("dcn", "dp"), dict(names=("dcn", "dp"), shape=(2, 4))),
+    pytest.param(("dcn", "dp"), dict(names=("dcn", "dp"), shape=(2, 4)),
+                 marks=pytest.mark.slow),  # PR 21: tier-1 wall (tests/README.md)
 ])
 def test_fused_trainstep_mesh_matches_single(axes, mesh_kw):
     """Fused-ResNet TrainStep over the mesh == no-mesh step: losses,
@@ -204,6 +207,7 @@ def test_fused_trainstep_mixed_dp_tp_mesh():
                                    atol=2e-6, err_msg=k)
 
 
+@pytest.mark.slow   # PR 21: tier-1 wall (tests/README.md)
 def test_parity_catches_dropped_psum(monkeypatch):
     """Planted bug: run the shard_map bwd with axis=None (no psums —
     every shard keeps only its local weight-grad/stat contribution).

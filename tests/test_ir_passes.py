@@ -148,6 +148,7 @@ def _graph_signature(s):
     return sig
 
 
+@pytest.mark.slow   # PR 21: tier-1 wall (tests/README.md)
 def test_fusion_schedule_keys_identical():
     """Acceptance: build_resnet(fused=True) and the pass-fused unfused
     graph consult IDENTICAL schedule-table keys. Checked two ways:

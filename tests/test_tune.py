@@ -134,6 +134,7 @@ def test_table_rejects_malformed_record(table_path):
             t.record("fused_fwd", CONV_SHAPE, "bfloat16", "cpu", bad)
 
 
+@pytest.mark.slow   # PR 21: tier-1 wall (tests/README.md)
 def test_empty_table_and_knob_off_are_bit_identical(table_path, monkeypatch):
     x, w, scale, bias = _conv_args()
     y_empty, st_empty = fb.conv_fwd(x, w, stride=1,
@@ -173,6 +174,7 @@ def test_conv_fwd_schedule_parity_bit_exact(sched):
     {"row_tile": 2, "chan_block": 16, "batch_fold": 2},
     {"row_tile": 4, "chan_block": 32, "batch_fold": 1},
 ])
+@pytest.mark.slow   # PR 21: tier-1 wall (tests/README.md)
 def test_conv_grad_schedule_parity(sched):
     x, w, scale, bias = _conv_args()
     g = jax.random.normal(jax.random.PRNGKey(7), (N, HW, HW, CO),
@@ -339,6 +341,7 @@ def test_tune_knobs_registered():
     b"{\"row_tile\": \"x\"}}}}",                       # malformed record
     b"[1, 2, 3]",                                       # wrong top level
 ])
+@pytest.mark.slow   # PR 21: tier-1 wall (tests/README.md)
 def test_corrupt_table_falls_back_and_is_rewritten(table_path, payload,
                                                    caplog):
     table_path.write_bytes(payload)
@@ -361,6 +364,7 @@ def test_corrupt_table_falls_back_and_is_rewritten(table_path, payload,
 # ---------------------------------------------------------------------------
 # search mechanics
 # ---------------------------------------------------------------------------
+@pytest.mark.slow   # PR 21: tier-1 wall (tests/README.md)
 def test_sweep_commits_prunes_then_cache_hits(table_path):
     rep = tune.sweep_fused("fused_fwd", (N, HW, HW, CI), (3, 3, CI, CO),
                            stride=1, **SWEEP_KW)
@@ -460,6 +464,7 @@ def _run_tuner(table, extra=()):
     return json.loads(proc.stdout.strip().splitlines()[-1])
 
 
+@pytest.mark.slow   # PR 21: tier-1 wall (tests/README.md)
 def test_tune_kernels_cli_end_to_end(tmp_path):
     table = str(tmp_path / "table.json")
     rep = _run_tuner(table)

@@ -163,6 +163,7 @@ def test_model_learns_synthetic_ranking(tune_env):
     assert profiler.tuning_stats()["model_refits"] == 1
 
 
+@pytest.mark.slow   # PR 21: tier-1 wall (tests/README.md)
 def test_abstain_too_few_rows_and_low_corr(tune_env):
     table = tune.get_table()
     # 3 rows < MIN_FIT_ROWS: the group is skipped (abstains), no file;
@@ -329,6 +330,7 @@ def test_transfer_across_shapes(tune_env):
 # ---------------------------------------------------------------------------
 # acceptance: no model / ranker off == PR 10 exhaustive, bit-identical
 # ---------------------------------------------------------------------------
+@pytest.mark.slow   # PR 21: tier-1 wall (tests/README.md)
 def test_ranker_off_and_no_model_identical_to_exhaustive(tune_env,
                                                          monkeypatch):
     kw = dict(SWEEP_KW, budget=3)
@@ -478,6 +480,7 @@ def test_background_tuner_commits_only_at_drain_boundary(tune_env,
     assert BackgroundTuner.from_env().on_drain() is None
 
 
+@pytest.mark.slow   # PR 21: tier-1 wall (tests/README.md)
 def test_concurrent_tuners_share_table_without_clobbering(tune_env):
     # two jobs sharing one table file: each commits its own winner
     # through the merge-base-re-reading path — neither clobbers the
@@ -573,6 +576,7 @@ def test_tuning_counters_dump_ride_and_unknown_raise(tmp_path,
     assert profiler.tuning_stats() == {}
 
 
+@pytest.mark.slow   # PR 21: tier-1 wall (tests/README.md)
 def test_sweep_for_key_dispatch(tune_env):
     kw = dict(repeats=2, target_sec=0.01, min_iters=2, interpret=True,
               budget=2)
@@ -590,6 +594,7 @@ def test_sweep_for_key_dispatch(tune_env):
 # ---------------------------------------------------------------------------
 # review-hardening regressions
 # ---------------------------------------------------------------------------
+@pytest.mark.slow   # PR 21: tier-1 wall (tests/README.md)
 def test_ranked_budget_tighter_than_topk_times_predicted_best(tune_env):
     # budget truncation must respect the model's ranking: with
     # BG_BUDGET-style budget=2 < topk=3 the one timed candidate is the

@@ -182,7 +182,7 @@ def main():
         jnp.arange(n * elems, dtype=jnp.float32).reshape(n, elems),
         NamedSharding(mesh, P("x", None)))
 
-    from jax.experimental.shard_map import shard_map
+    from mxnet_tpu.util import shard_map
 
     def timed(name, fn, bytes_moved):
         f = jax.jit(fn)
@@ -211,7 +211,7 @@ def main():
     timed("all_gather",
           shard_map(lambda a: jax.lax.all_gather(a, "x", tiled=True),
                     mesh=mesh, in_specs=P("x", None), out_specs=P(None),
-                    check_rep=False),
+                    check_vma=False),
           (n - 1) / n * payload * n)
     # reduce_scatter
     timed("reduce_scatter",

@@ -39,6 +39,7 @@ def measure_mp(mp=2, d_model=256, n_layers=4, seq=64, batch_per_dp=2,
     from jax.sharding import PartitionSpec as P
 
     from mxnet_tpu import profiler
+    from mxnet_tpu.context import device_record, kernel_platform
     from mxnet_tpu.models import transformer as tfm
     from mxnet_tpu.parallel.mesh import train_mesh
 
@@ -50,7 +51,7 @@ def measure_mp(mp=2, d_model=256, n_layers=4, seq=64, batch_per_dp=2,
     cfg = tfm.TransformerConfig(
         vocab=4096, d_model=d_model, n_heads=8, d_ff=4 * d_model,
         n_layers=n_layers, max_len=seq,
-        dtype="bfloat16" if jax.default_backend() == "tpu" else "float32")
+        dtype="bfloat16" if kernel_platform() == "tpu" else "float32")
     params = tfm.init_params(cfg, seed=0)
     rng = np.random.RandomState(0)
     # One global batch (divisible by every dp size) so the mp and
@@ -109,7 +110,7 @@ def measure_mp(mp=2, d_model=256, n_layers=4, seq=64, batch_per_dp=2,
         "psum_outside": counts["psum_outside"],
         "all_gather_per_step": counts["all_gather"],
         "loss_abs_diff": round(abs(r_mp["loss"] - r_dp["loss"]), 8),
-        "backend": jax.default_backend(),
+        **device_record(),
     }
 
 
@@ -159,11 +160,14 @@ def main():
 
     import mxnet_tpu as mx
     from bench_io import pack_dataset
+    from mxnet_tpu.context import device_record, kernel_platform
     from mxnet_tpu.parallel import make_mesh
     from mxnet_tpu.parallel.spmd import (TrainStep, data_sharding,
                                          functional_optimizer)
     from mxnet_tpu.models import resnet
 
+    # bf16 on the chip, fp32 on the cpu test backend, nothing else
+    compute_dtype = "bfloat16" if kernel_platform() == "tpu" else None
     os.makedirs(args.workdir, exist_ok=True)
     prefix = os.path.join(args.workdir, "e2e%d_%d" % (args.num_images,
                                                       args.edge))
@@ -179,7 +183,7 @@ def main():
     ts = TrainStep(
         sym, functional_optimizer("sgd", learning_rate=0.1, momentum=0.9),
         mesh=make_mesh({"dp": n_dev}),
-        compute_dtype="bfloat16" if jax.default_backend() == "tpu" else None,
+        compute_dtype=compute_dtype,
     )
     params, opt_state, aux = ts.init_params(
         {"data": (batch, 3, ds, ds), "softmax_label": (batch,)},
@@ -226,8 +230,7 @@ def main():
                                       momentum=0.9),
             mesh=make_mesh({"dp": n_dev}), remat="pass",
             train_passes=("layout",),
-            compute_dtype="bfloat16" if jax.default_backend() == "tpu"
-            else None,
+            compute_dtype=compute_dtype,
         )
         p_p, s_p, a_p = ts_p.init_params(
             {"data": (batch, 3, ds, ds), "softmax_label": (batch,)},
@@ -257,7 +260,7 @@ def main():
             pass
         try:
             # AD-level residual bytes: the backend-independent remat
-            # metric (CPU XLA strips the barriers; see PROFILE.md)
+            # metric (CPU XLA strips the barriers)
             res_p = ts_p.residual_stats(p_p, a_p, syn, key)
             res_0 = ts.residual_stats(p_p, a_p, syn, key)
             passes_rec["residual_bytes"] = res_p["residual_bytes"]
@@ -275,8 +278,7 @@ def main():
             sym, functional_optimizer("sgd", learning_rate=0.1,
                                       momentum=0.9),
             mesh=make_mesh({"dp": n_dev}), zero=True,
-            compute_dtype="bfloat16" if jax.default_backend() == "tpu"
-            else None,
+            compute_dtype=compute_dtype,
         )
         p_z, s_z, a_z = ts_z.init_params(
             {"data": (batch, 3, ds, ds), "softmax_label": (batch,)},
@@ -309,8 +311,7 @@ def main():
             sym, functional_optimizer("sgd", learning_rate=0.1,
                                       momentum=0.9),
             mesh=make_mesh({"dp": n_dev}), sentinel="skip",
-            compute_dtype="bfloat16" if jax.default_backend() == "tpu"
-            else None,
+            compute_dtype=compute_dtype,
         )
         p_s, s_s, a_s = ts_s.init_params(
             {"data": (batch, 3, ds, ds), "softmax_label": (batch,)},
@@ -363,9 +364,7 @@ def main():
         from mxnet_tpu import profiler
         from mxnet_tpu.parallel.feed import DeviceQueueIter
 
-        contexts = [mx.Context("cpu" if jax.default_backend() == "cpu"
-                               else "tpu", i)
-                    for i in range(len(jax.devices()))]
+        contexts = [mx.tpu(i) for i in range(len(jax.devices()))]
         n_fit = batch * max(2, args.num_images // batch)
         rng_f = np.random.RandomState(1)
         Xf = rng_f.randn(n_fit, 3, ds, ds).astype(np.float32)
@@ -400,7 +399,7 @@ def main():
         "bottleneck": "decode" if io_img_s < synthetic_img_s else "compute",
         "num_layers": args.num_layers, "data_shape": ds,
         "batch_size": batch, "threads": args.threads,
-        "fused": bool(args.fused), "backend": jax.default_backend(),
+        "fused": bool(args.fused), **device_record(),
     }
     if compiled_mem is not None:
         rec["peak_bytes"] = compiled_mem["peak_bytes"]

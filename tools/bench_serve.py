@@ -261,16 +261,38 @@ REPLICA_BOOT_CODE = ("import sys; from mxnet_tpu.serving import fleet; "
                      "sys.exit(fleet.main())")
 
 
+def _generate_dtype():
+    """bf16 on the chip, fp32 on the cpu test backend; any other backend
+    raises (context.kernel_platform)."""
+    from mxnet_tpu.context import kernel_platform
+
+    return "bfloat16" if kernel_platform() == "tpu" else "float32"
+
+
+def _replica_env():
+    """The replica subprocesses' environment: clean_dist_env, which pins
+    JAX to the CPU backend — a chip belongs to one process, and this
+    control-plane bench measures routing, not the device."""
+    from mxnet_tpu.test_utils import clean_dist_env
+
+    return clean_dist_env(repo_root=os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__))))
+
+
+def _replica_platform():
+    """What fleet/autoscale records name as the platform their replicas
+    ran on — read from the environment they are actually given, never
+    from the measuring process's own backend."""
+    return _replica_env()["JAX_PLATFORMS"]
+
+
 def _spawn_replica(rank, coord, prefix, dim, ladder, pin_core=None):
     """One replica subprocess (CPU-pinned when asked: on a shared host
     per-replica core pinning is what makes process-level scaling
     measurable at all)."""
     import subprocess
 
-    from mxnet_tpu.test_utils import clean_dist_env
-
-    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-    env = clean_dist_env(repo_root=root)
+    env = _replica_env()
     host, port = coord.rsplit(":", 1)
     env.update({"DMLC_ROLE": "replica", "DMLC_REPLICA_ID": str(rank),
                 "DMLC_PS_ROOT_URI": host, "DMLC_PS_ROOT_PORT": port})
@@ -402,8 +424,6 @@ def measure_fleet(replicas=3, clients=24, seconds=6.0, think_ms=1.0,
     scaling ratio is only meaningful with >= replicas+1 cores — the
     record carries the core count so the trajectory tooling can tell a
     regression from a small host."""
-    import jax
-
     from mxnet_tpu.model import save_checkpoint
 
     symbol, args_np = build_model(dim, hidden, layers, classes)
@@ -433,7 +453,7 @@ def measure_fleet(replicas=3, clients=24, seconds=6.0, think_ms=1.0,
         "cores": cores,
         "cores_pinned": pin,
         "model": {"dim": dim, "hidden": hidden, "layers": layers},
-        "backend": jax.default_backend(),
+        "replica_platform": _replica_platform(),
     }
     return rec
 
@@ -694,8 +714,6 @@ def measure_autoscale(seconds=5.0, think_ms=1.0, dim=128, hidden=256,
     trace. CPU-honest: the record carries the core count — on a small
     host the elastic fleet's replicas contend for the same cores and
     the p99 gap narrows."""
-    import jax
-
     from mxnet_tpu.model import save_checkpoint
 
     symbol, args_np = build_model(dim, hidden, layers, classes)
@@ -730,7 +748,7 @@ def measure_autoscale(seconds=5.0, think_ms=1.0, dim=128, hidden=256,
         "think_ms": think_ms,
         "cores": cores,
         "model": {"dim": dim, "hidden": hidden, "layers": layers},
-        "backend": jax.default_backend(),
+        "replica_platform": _replica_platform(),
     }
 
 
@@ -822,7 +840,7 @@ def measure_generate(requests=64, rate=400.0, slots=8, page_size=16,
     config = tfm.TransformerConfig(
         vocab=vocab, d_model=d_model, n_heads=n_heads, n_layers=n_layers,
         d_ff=d_ff, max_len=max_len,
-        dtype="float32" if jax.default_backend() == "cpu" else "bfloat16")
+        dtype=_generate_dtype())
     params = tfm.init_params(config, seed=seed)
     workload = _sample_generate_workload(requests, rate, seed)
     drain = run_generate_mode("drain", config, params, workload, slots,
@@ -971,7 +989,7 @@ def measure_prefix(requests=64, rate=400.0, slots=4, page_size=16, seed=0,
     config = tfm.TransformerConfig(
         vocab=vocab, d_model=d_model, n_heads=n_heads, n_layers=n_layers,
         d_ff=d_ff, max_len=max_len,
-        dtype="float32" if jax.default_backend() == "cpu" else "bfloat16")
+        dtype=_generate_dtype())
     params = tfm.init_params(config, seed=seed)
     prefix, workload = _sample_prefix_workload(requests, rate, seed,
                                                prefix_len, vocab)
@@ -1091,7 +1109,7 @@ def measure_spec(k=6, requests=12, rate=50.0, slots=4, page_size=16,
     config = tfm.TransformerConfig(
         vocab=vocab, d_model=d_model, n_heads=n_heads, n_layers=n_layers,
         d_ff=d_ff, max_len=max_len,
-        dtype="float32" if jax.default_backend() == "cpu" else "bfloat16")
+        dtype=_generate_dtype())
     params = _damp_upper_layers(tfm.init_params(config, seed=seed), damp)
     rng = random.Random(seed)
     t, workload = 0.0, []

@@ -39,6 +39,8 @@ sys.path.insert(0, os.path.dirname(os.path.dirname(
 
 import jax  # noqa: E402
 
+from mxnet_tpu.context import device_record, kernel_platform  # noqa: E402
+
 
 def _run_one(sweep_fn, kw, args):
     """One sweep, honoring --compare: run the exhaustive sweep first
@@ -81,11 +83,10 @@ def _run_one(sweep_fn, kw, args):
 def run_sweeps(args, on_tpu, strict=True):
     from mxnet_tpu import profiler, tune
 
-    interpret = None if on_tpu else True
     common = dict(budget=args.budget, repeats=args.repeats,
                   iters=args.iters, target_sec=args.target_sec,
                   min_iters=1000 if on_tpu else 5,
-                  interpret=interpret, force=args.force,
+                  force=args.force,
                   ranked=args.ranked, topk=args.topk)
     kernels = args.kernels.split(",")
     unsweepable = {}
@@ -154,7 +155,7 @@ def run_sweeps(args, on_tpu, strict=True):
                          rep["n_pruned"], w["schedule"], w["ms_per_iter"],
                          w["default_ms_per_iter"], w["speedup_vs_default"],
                          rep.get("wall_s") or 0.0, extra))
-    report = {"tune": reports, "backend": jax.default_backend(),
+    report = {"tune": reports, "device": device_record(),
               "table": tune.default_table_path(),
               "model": tune.default_model_path(),
               "rule_kernels": tune.rule_kernels(),
@@ -237,7 +238,7 @@ def main(argv=None):
         jax.config.update("jax_platforms", "cpu")
     if args.table:
         os.environ["MXNET_TPU_TUNE_TABLE"] = args.table
-    on_tpu = jax.default_backend() == "tpu"
+    on_tpu = kernel_platform() == "tpu"
     if not on_tpu:
         from mxnet_tpu.tune.harness import pin_single_core
 
