@@ -192,7 +192,14 @@ def compile_cache_dir():
       directory that moves never hits.
 
     The compile-time threshold drops to 0 so small programs (the generate
-    prefill buckets) are cached too."""
+    prefill buckets) are cached too. The key takes in the programs'
+    metadata: left out (JAX's default), a program that differs from a
+    cached one only in its ``jax.named_scope`` names or source lines is
+    answered with the cached executable, whose operations then carry the
+    older names into every trace (seen on the chip, PR 26: the parent's
+    ResNet step served the change, and no operation was under
+    ``mx.opt.update``). An edit that moves a traced line costs one
+    compile."""
     import os
 
     import jax
@@ -201,6 +208,7 @@ def compile_cache_dir():
     if _platform_forced_to_cpu():
         return env or jax.config.jax_compilation_cache_dir
     jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
+    jax.config.update("jax_compilation_cache_include_metadata_in_key", True)
     if env:
         return env
     path = os.path.join(

@@ -553,7 +553,7 @@ class Executor:
         self._last_key = key  # backward() must replay the same PRNG draws
         # ref: executor RunOps stamps each push (graph_executor.cc:1461);
         # one XLA program = one event here
-        with _profiler.maybe_scope(self._symbol.name or "executor", "forward"):
+        with _profiler.span("mx.executor.forward"):
             outs, aux_updates = fn(self._values(), key)
         self._last_fwd_train = is_train
         self._set_outputs(outs)
@@ -584,7 +584,7 @@ class Executor:
         program the fast path; see class docstring)."""
         heads = self._normalize_head_grads(out_grads)
         fn = self._get_compiled("fwd_bwd")
-        with _profiler.maybe_scope(self._symbol.name or "executor", "backward"):
+        with _profiler.span("mx.executor.backward"):
             outs, grads, aux_updates = fn(self._values(), self._reuse_key(), heads)
         self._set_outputs(outs)
         if not getattr(self, "_aux_applied", False):
@@ -612,8 +612,7 @@ class Executor:
         fn = self._get_compiled("fwd_bwd")
         key = self._next_key()
         self._last_key = key
-        with _profiler.maybe_scope(self._symbol.name or "executor",
-                                   "forward_backward"):
+        with _profiler.span("mx.executor.forward_backward"):
             outs, grads, aux_updates = fn(self._values(), key, heads)
         self._set_outputs(outs)
         self._apply_aux(aux_updates)

@@ -199,6 +199,7 @@ def _fwd_call(q, k, v, scale, causal, block_q, block_k, interpret, kv_len):
             jax.ShapeDtypeStruct((bh, sq, 1), jnp.float32),
         ],
         interpret=interpret,
+        name="mx_flash_fwd",
     )(q, k, v)
     return out, lse
 
@@ -226,6 +227,7 @@ def _bwd_call(q, k, v, do, out, lse, scale, causal, block_q, block_k,
         out_specs=pl.BlockSpec((1, block_q, d), lambda b, i: (b, i, 0)),
         out_shape=jax.ShapeDtypeStruct((bh, sq, d), q.dtype),
         interpret=interpret,
+        name="mx_flash_dq",
     )(q, k, v, do, lse, delta)
 
     dk, dv = pl.pallas_call(
@@ -250,6 +252,7 @@ def _bwd_call(q, k, v, do, out, lse, scale, causal, block_q, block_k,
             jax.ShapeDtypeStruct((bh, sk, d), v.dtype),
         ],
         interpret=interpret,
+        name="mx_flash_dkv",
     )(q, k, v, do, lse, delta)
     return dq, dk, dv
 

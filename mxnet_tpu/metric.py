@@ -8,9 +8,11 @@ CustomMetric/np wrapper.
 from __future__ import annotations
 
 import math
+import time
 
 import numpy
 
+from . import profiler
 from .base import MXNetError
 
 _METRIC_REGISTRY = {}
@@ -42,7 +44,8 @@ def _materialize_dicts(label, pred):
         return label, pred
     import jax
 
-    host = jax.device_get(vals)
+    with profiler.span("mx.fit.host_sync"):
+        host = jax.device_get(vals)
     label, pred = dict(label), dict(pred)
     for (which, k), h in zip(keys, host):
         (label if which == "l" else pred)[k] = h
@@ -104,11 +107,20 @@ class EvalMetric:
             srcs.append(source)
 
     def _fold_device_sources(self):
-        for src in self.__dict__.get("_device_sources", ()):
-            s, n = src.drain()
-            if n:
-                self.sum_metric += s
-                self.num_inst += n
+        sources = self.__dict__.get("_device_sources", ())
+        if not sources:
+            return
+        t0 = time.perf_counter()
+        fetched = 0
+        with profiler.span("mx.metric.drain", sources=len(sources)):
+            for src in sources:
+                s, n = src.drain()
+                if n:
+                    self.sum_metric += s
+                    self.num_inst += n
+                    fetched = 1
+        profiler.h2d_record(metric_drains=fetched,
+                            sync_seconds=time.perf_counter() - t0)
 
     def reset(self):
         self.num_inst = 0

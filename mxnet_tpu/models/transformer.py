@@ -192,16 +192,18 @@ def _attention(q, k, v, *, axes, causal=True, attn="auto", blocks=None):
 
 def _block(x, lp, c, axes, cdt):
     """One transformer block on local shards. lp: this layer's params."""
-    h = _layernorm(x, lp["ln1_gamma"], lp["ln1_beta"])
-    qkv = jnp.einsum("bsd,dthe->tbhse", h, lp["attn_qkv_weight"].astype(cdt))
-    q, k, v = qkv[0], qkv[1], qkv[2]
-    o = _attention(q, k, v, axes=axes, attn=c.attn,
-                   blocks=(c.attn_block_q, c.attn_block_k))
-    o = jnp.einsum("bhse,hed->bsd", o, lp["attn_out_weight"].astype(cdt))
-    t = _mp_axis(axes)
-    if t:
-        o = lax.psum(o, t)         # row-parallel out-proj
-    x = x + o
+    with jax.named_scope("mx.lm.attn"):
+        h = _layernorm(x, lp["ln1_gamma"], lp["ln1_beta"])
+        qkv = jnp.einsum("bsd,dthe->tbhse", h,
+                         lp["attn_qkv_weight"].astype(cdt))
+        q, k, v = qkv[0], qkv[1], qkv[2]
+        o = _attention(q, k, v, axes=axes, attn=c.attn,
+                       blocks=(c.attn_block_q, c.attn_block_k))
+        o = jnp.einsum("bhse,hed->bsd", o, lp["attn_out_weight"].astype(cdt))
+        t = _mp_axis(axes)
+        if t:
+            o = lax.psum(o, t)         # row-parallel out-proj
+        x = x + o
     return _ffn(x, lp, c, axes, cdt)
 
 
@@ -209,31 +211,32 @@ def _ffn(x, lp, c, axes, cdt):
     """The ffn half of a block (post-attention residual included) —
     shared verbatim between the training forward and the incremental
     decode step, so the two paths cannot drift numerically."""
-    h = _layernorm(x, lp["ln2_gamma"], lp["ln2_beta"])
-    t = _mp_axis(axes)
-    if c.n_experts:
-        gate = jax.nn.softmax(
-            jnp.einsum("bsd,de->bse", h.astype(jnp.float32),
-                       lp["moe_gate_weight"].astype(jnp.float32)), axis=-1)
-        e_loc = lp["ffn_up_weight"].shape[0]
-        e0 = lax.axis_index("ep") * e_loc if "ep" in axes else 0
-        g_loc = lax.dynamic_slice_in_dim(gate, e0, e_loc, axis=-1).astype(cdt)
-        up = jnp.einsum("bsd,edf->besf", h, lp["ffn_up_weight"].astype(cdt))
-        act = jax.nn.relu(up)
-        down = jnp.einsum("besf,efd->besd", act,
-                          lp["ffn_down_weight"].astype(cdt))
-        f = jnp.einsum("besd,bse->bsd", down, g_loc)
-        if "ep" in axes:
-            f = lax.psum(f, "ep")
-        if t:
-            f = lax.psum(f, t)     # d_ff was also mp-sharded
-    else:
-        up = jax.nn.relu(jnp.einsum("bsd,df->bsf", h,
-                                    lp["ffn_up_weight"].astype(cdt)))
-        f = jnp.einsum("bsf,fd->bsd", up, lp["ffn_down_weight"].astype(cdt))
-        if t:
-            f = lax.psum(f, t)     # row-parallel ffn-down
-    return x + f
+    with jax.named_scope("mx.lm.ffn"):
+        h = _layernorm(x, lp["ln2_gamma"], lp["ln2_beta"])
+        t = _mp_axis(axes)
+        if c.n_experts:
+            gate = jax.nn.softmax(
+                jnp.einsum("bsd,de->bse", h.astype(jnp.float32),
+                           lp["moe_gate_weight"].astype(jnp.float32)), axis=-1)
+            e_loc = lp["ffn_up_weight"].shape[0]
+            e0 = lax.axis_index("ep") * e_loc if "ep" in axes else 0
+            g_loc = lax.dynamic_slice_in_dim(gate, e0, e_loc, axis=-1).astype(cdt)
+            up = jnp.einsum("bsd,edf->besf", h, lp["ffn_up_weight"].astype(cdt))
+            act = jax.nn.relu(up)
+            down = jnp.einsum("besf,efd->besd", act,
+                              lp["ffn_down_weight"].astype(cdt))
+            f = jnp.einsum("besd,bse->bsd", down, g_loc)
+            if "ep" in axes:
+                f = lax.psum(f, "ep")
+            if t:
+                f = lax.psum(f, t)     # d_ff was also mp-sharded
+        else:
+            up = jax.nn.relu(jnp.einsum("bsd,df->bsf", h,
+                                        lp["ffn_up_weight"].astype(cdt)))
+            f = jnp.einsum("bsf,fd->bsd", up, lp["ffn_down_weight"].astype(cdt))
+            if t:
+                f = lax.psum(f, t)     # row-parallel ffn-down
+        return x + f
 
 
 def _forward_local(params, tokens, c, axes):
@@ -244,17 +247,19 @@ def _forward_local(params, tokens, c, axes):
     # vocab(mp)-sharded embedding: mask + psum
     t = _mp_axis(axes)
     emb_w = params["embed_weight"]
-    v_loc = emb_w.shape[0]
-    v0 = lax.axis_index(t) * v_loc if t else 0
-    local_ids = tokens - v0
-    in_range = (local_ids >= 0) & (local_ids < v_loc)
-    x = jnp.take(emb_w, jnp.clip(local_ids, 0, v_loc - 1), axis=0)
-    x = jnp.where(in_range[..., None], x, 0.0)
-    if t:
-        x = lax.psum(x, t)
-    s0 = lax.axis_index("sp") * S_loc if "sp" in axes else 0
-    pos = lax.dynamic_slice_in_dim(params["pos_embed_weight"], s0, S_loc, 0)
-    x = (x + pos).astype(cdt)
+    with jax.named_scope("mx.lm.embed"):
+        v_loc = emb_w.shape[0]
+        v0 = lax.axis_index(t) * v_loc if t else 0
+        local_ids = tokens - v0
+        in_range = (local_ids >= 0) & (local_ids < v_loc)
+        x = jnp.take(emb_w, jnp.clip(local_ids, 0, v_loc - 1), axis=0)
+        x = jnp.where(in_range[..., None], x, 0.0)
+        if t:
+            x = lax.psum(x, t)
+        s0 = lax.axis_index("sp") * S_loc if "sp" in axes else 0
+        pos = lax.dynamic_slice_in_dim(params["pos_embed_weight"], s0,
+                                       S_loc, 0)
+        x = (x + pos).astype(cdt)
 
     n_layers = params["ln1_gamma"].shape[0]
 
@@ -269,13 +274,14 @@ def _forward_local(params, tokens, c, axes):
                             "final_ln_gamma", "final_ln_beta")}
     x, _ = lax.scan(layer, x, stacked)
 
-    x = _layernorm(x, params["final_ln_gamma"], params["final_ln_beta"])
-    logits_loc = jnp.einsum("bsd,vd->bsv", x, emb_w.astype(cdt))
-    if t:
-        logits = lax.all_gather(logits_loc, t, axis=2, tiled=True)
-    else:
-        logits = logits_loc
-    return logits.astype(jnp.float32)
+    with jax.named_scope("mx.lm.head_loss"):
+        x = _layernorm(x, params["final_ln_gamma"], params["final_ln_beta"])
+        logits_loc = jnp.einsum("bsd,vd->bsv", x, emb_w.astype(cdt))
+        if t:
+            logits = lax.all_gather(logits_loc, t, axis=2, tiled=True)
+        else:
+            logits = logits_loc
+        return logits.astype(jnp.float32)
 
 
 def make_loss_fn(config, mesh, data_axes=("dp",)):
@@ -298,15 +304,16 @@ def make_loss_fn(config, mesh, data_axes=("dp",)):
     def local_loss(params, tokens):
         inp, tgt = tokens[:, :-1], tokens[:, 1:]
         logits = _forward_local(params, inp, c, axes)
-        logp = jax.nn.log_softmax(logits, axis=-1)
-        nll = -jnp.take_along_axis(logp, tgt[..., None].astype(jnp.int32),
-                                   axis=-1)[..., 0]
-        loss_sum = jnp.sum(nll)
-        count = jnp.float32(nll.size)
-        if reduce_axes:
-            loss_sum = lax.psum(loss_sum, reduce_axes)
-            count = lax.psum(count, reduce_axes)
-        return loss_sum / count
+        with jax.named_scope("mx.lm.head_loss"):
+            logp = jax.nn.log_softmax(logits, axis=-1)
+            nll = -jnp.take_along_axis(
+                logp, tgt[..., None].astype(jnp.int32), axis=-1)[..., 0]
+            loss_sum = jnp.sum(nll)
+            count = jnp.float32(nll.size)
+            if reduce_axes:
+                loss_sum = lax.psum(loss_sum, reduce_axes)
+                count = lax.psum(count, reduce_axes)
+            return loss_sum / count
 
     # tokens enter with seq split over sp: shard (B_loc, S_loc + 1) needs
     # the +1 target shift *before* sharding — handled by passing the full
@@ -353,7 +360,8 @@ def make_train_step(config, mesh, optimizer=None, data_axes=("dp",)):
     def step(carry, tokens):
         params, opt_state, n = carry
         loss, grads = jax.value_and_grad(loss_fn)(params, tokens)
-        new_p, new_s = opt.apply(params, grads, opt_state, n)
+        with jax.named_scope("mx.opt.update"):
+            new_p, new_s = opt.apply(params, grads, opt_state, n)
         return (new_p, new_s, n + 1), loss
 
     shardings = {k: NamedSharding(mesh, s) for k, s in specs.items()}
@@ -682,9 +690,11 @@ def make_prefill_fn(config, page_size, mesh=None):
                 n_pages, page_size, c.n_heads, -1)
             vp = v[0].transpose(1, 0, 2).reshape(
                 n_pages, page_size, c.n_heads, -1)
-            cl = cl.at[0, pages].set(kp.astype(cl.dtype))
-            cl = cl.at[1, pages].set(vp.astype(cl.dtype))
-            o = attend(q, k, v)
+            with jax.named_scope("mx.gen.pool_write"):
+                cl = cl.at[0, pages].set(kp.astype(cl.dtype))
+                cl = cl.at[1, pages].set(vp.astype(cl.dtype))
+            with jax.named_scope("mx.gen.attn"):
+                o = attend(q, k, v)
             o = jnp.einsum("bhse,hed->bsd", o,
                            lp["attn_out_weight"].astype(cdt))
             return _ffn(x + o, lp, c, frozenset(), cdt), cl
@@ -745,15 +755,20 @@ def make_decode_fn(config, slots, max_pages_per_slot, page_size,
             qkv = jnp.einsum("bsd,dthe->tbhse", h,
                              lp["attn_qkv_weight"].astype(cdt))
             q, k, v = qkv[0], qkv[1], qkv[2]          # (S, H, 1, Dh)
-            cl = cl.at[0, page, offset].set(k[:, :, 0, :].astype(cl.dtype))
-            cl = cl.at[1, page, offset].set(v[:, :, 0, :].astype(cl.dtype))
+            with jax.named_scope("mx.gen.pool_write"):
+                cl = cl.at[0, page, offset].set(
+                    k[:, :, 0, :].astype(cl.dtype))
+                cl = cl.at[1, page, offset].set(
+                    v[:, :, 0, :].astype(cl.dtype))
             # paged gather: (S, MP, page, H, Dh) → (S, H, L, Dh)
-            kg = cl[0][block_tables].reshape(
-                S, max_ctx, c.n_heads, -1).transpose(0, 2, 1, 3)
-            vg = cl[1][block_tables].reshape(
-                S, max_ctx, c.n_heads, -1).transpose(0, 2, 1, 3)
-            o = _paged_decode_attention(q.astype(cdt), kg, vg, positions,
-                                        block_k)
+            with jax.named_scope("mx.gen.gather_kv"):
+                kg = cl[0][block_tables].reshape(
+                    S, max_ctx, c.n_heads, -1).transpose(0, 2, 1, 3)
+                vg = cl[1][block_tables].reshape(
+                    S, max_ctx, c.n_heads, -1).transpose(0, 2, 1, 3)
+            with jax.named_scope("mx.gen.attn"):
+                o = _paged_decode_attention(q.astype(cdt), kg, vg,
+                                            positions, block_k)
             o = jnp.einsum("bhse,hed->bsd", o,
                            lp["attn_out_weight"].astype(cdt))
             return _ffn(x + o, lp, c, frozenset(), cdt), cl
@@ -830,16 +845,19 @@ def make_extend_fn(config, slots, steps, max_pages_per_slot, page_size,
             qkv = jnp.einsum("bsd,dthe->tbhse", h,
                              lp["attn_qkv_weight"].astype(cdt))
             q, k, v = qkv[0], qkv[1], qkv[2]          # (S, H, T, Dh)
-            cl = cl.at[0, page, offset].set(
-                k.transpose(0, 2, 1, 3).astype(cl.dtype))
-            cl = cl.at[1, page, offset].set(
-                v.transpose(0, 2, 1, 3).astype(cl.dtype))
-            kg = cl[0][block_tables].reshape(
-                S, max_ctx, c.n_heads, -1).transpose(0, 2, 1, 3)
-            vg = cl[1][block_tables].reshape(
-                S, max_ctx, c.n_heads, -1).transpose(0, 2, 1, 3)
-            o = _paged_extend_attention(q.astype(cdt), kg, vg, positions,
-                                        block_k)
+            with jax.named_scope("mx.gen.pool_write"):
+                cl = cl.at[0, page, offset].set(
+                    k.transpose(0, 2, 1, 3).astype(cl.dtype))
+                cl = cl.at[1, page, offset].set(
+                    v.transpose(0, 2, 1, 3).astype(cl.dtype))
+            with jax.named_scope("mx.gen.gather_kv"):
+                kg = cl[0][block_tables].reshape(
+                    S, max_ctx, c.n_heads, -1).transpose(0, 2, 1, 3)
+                vg = cl[1][block_tables].reshape(
+                    S, max_ctx, c.n_heads, -1).transpose(0, 2, 1, 3)
+            with jax.named_scope("mx.gen.attn"):
+                o = _paged_extend_attention(q.astype(cdt), kg, vg,
+                                            positions, block_k)
             o = jnp.einsum("bhse,hed->bsd", o,
                            lp["attn_out_weight"].astype(cdt))
             return _ffn(x + o, lp, c, frozenset(), cdt), cl

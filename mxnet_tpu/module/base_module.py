@@ -13,6 +13,7 @@ import time
 import numpy as np
 
 from .. import metric as metric_mod
+from .. import profiler
 from ..base import MXNetError
 from ..io import DataBatch, DataDesc
 from ..model import BatchEndParam
@@ -191,41 +192,50 @@ class BaseModule:
             end_of_batch = False
             next_data_batch = next(data_iter)
             while not end_of_batch:
-                data_batch = next_data_batch
-                if monitor is not None:
-                    monitor.tic()
-                self.forward_backward(data_batch)
-                self.update()
-                try:
-                    next_data_batch = next(data_iter)
-                    self.prepare(next_data_batch, sparse_row_id_fn=sparse_row_id_fn)
-                except StopIteration:
-                    end_of_batch = True
-                self.update_metric(eval_metric, data_batch.label)
-                if health_guard is not None:
-                    # batch-boundary health/preemption hook: may roll
-                    # the module back to the latest checkpoint, or
-                    # raise SystemExit(EXIT_PREEMPTED) after a
-                    # grace-window checkpoint
-                    health_guard.on_batch(epoch, nbatch, eval_metric,
-                                          data_batch.label)
-                if monitor is not None:
-                    monitor.toc_print()
-                if batch_end_callback is not None:
-                    batch_end_params = BatchEndParam(
-                        epoch=epoch, nbatch=nbatch, eval_metric=eval_metric, locals=locals()
-                    )
-                    for callback in _as_list(batch_end_callback):
-                        callback(batch_end_params)
-                nbatch += 1
+                with profiler.span("mx.fit.batch", epoch=epoch, nbatch=nbatch):
+                    data_batch = next_data_batch
+                    if monitor is not None:
+                        monitor.tic()
+                    with profiler.span("mx.fit.forward_backward"):
+                        self.forward_backward(data_batch)
+                    with profiler.span("mx.fit.update"):
+                        self.update()
+                    with profiler.span("mx.fit.next_batch"):
+                        try:
+                            next_data_batch = next(data_iter)
+                            self.prepare(next_data_batch,
+                                         sparse_row_id_fn=sparse_row_id_fn)
+                        except StopIteration:
+                            end_of_batch = True
+                    with profiler.span("mx.fit.update_metric"):
+                        self.update_metric(eval_metric, data_batch.label)
+                    if health_guard is not None:
+                        # batch-boundary health/preemption hook: may roll
+                        # the module back to the latest checkpoint, or
+                        # raise SystemExit(EXIT_PREEMPTED) after a
+                        # grace-window checkpoint
+                        health_guard.on_batch(epoch, nbatch, eval_metric,
+                                              data_batch.label)
+                    if monitor is not None:
+                        monitor.toc_print()
+                    if batch_end_callback is not None:
+                        batch_end_params = BatchEndParam(
+                            epoch=epoch, nbatch=nbatch, eval_metric=eval_metric,
+                            locals=locals()
+                        )
+                        with profiler.span("mx.fit.callbacks"):
+                            for callback in _as_list(batch_end_callback):
+                                callback(batch_end_params)
+                    nbatch += 1
 
             for name, val in eval_metric.get_name_value():
                 self.logger.info("Epoch[%d] Train-%s=%f", epoch, name, val)
             toc = time.time()
             self.logger.info("Epoch[%d] Time cost=%.3f", epoch, (toc - tic))
 
-            arg_params_, aux_params_ = self.get_params()
-            self.set_params(arg_params_, aux_params_)
+            with profiler.span("mx.fit.epoch_end", epoch=epoch):
+                arg_params_, aux_params_ = self.get_params()
+                self.set_params(arg_params_, aux_params_)
 
             if bg_tuner is not None:
                 # drained boundary: get_params() above blocked on the

@@ -302,13 +302,13 @@ class FusedSPMDGroup:
             for name, value in values
         }
         key = jax.random.fold_in(self._key, self._step_no)
+        with profiler.span("mx.fit.dispatch", step=self._step_no):
+            self._carry, result = self._ts(self._carry, batch, key)
         if self._device_metrics:
-            self._carry, (loss, outs, stats) = self._ts(self._carry, batch,
-                                                        key)
-            self._stats = stats
+            loss, outs, self._stats = result
             self._stats_consumers.clear()
         else:
-            self._carry, (loss, outs) = self._ts(self._carry, batch, key)
+            loss, outs = result
         self._step_no += 1
         self._loss = loss
         # keep raw device arrays — materialization is deferred to
@@ -329,7 +329,8 @@ class FusedSPMDGroup:
         self._inflight.append(token)
         while len(self._inflight) > self._max_inflight:
             t0 = time.perf_counter()
-            jax.block_until_ready(self._inflight.popleft())
+            with profiler.span("mx.fit.throttle"):
+                jax.block_until_ready(self._inflight.popleft())
             profiler.h2d_record(
                 stall_compute=time.perf_counter() - t0)
         profiler.h2d_record(steps=1, inflight=len(self._inflight))
@@ -355,9 +356,10 @@ class FusedSPMDGroup:
         # is what the ISSUE 5 acceptance test asserts is zero on the
         # device-metric path
         profiler.h2d_record(host_syncs=1)
-        if not self.distributed or jax.process_count() == 1:
-            return [nd.NDArray(o) for o in outs]
-        return [nd.array(self._local_rows_host(o)) for o in outs]
+        with profiler.span("mx.fit.host_sync"):
+            if not self.distributed or jax.process_count() == 1:
+                return [nd.NDArray(o) for o in outs]
+            return [nd.array(self._local_rows_host(o)) for o in outs]
 
     @staticmethod
     def _local_rows_host(o):
@@ -480,9 +482,17 @@ class FusedSPMDGroup:
         # DataParallelExecutorGroup.update_metric so metrics with
         # output_names/label_names pick the right arrays. Materializes
         # outputs: a per-batch host sync (profiler host_syncs counts it).
+        from .. import metric as metric_mod
+
+        t0 = time.perf_counter()
         labels_ = dict(zip(self._label_names,
                            self._materialize_labels(labels)))
         preds_ = dict(zip(self._output_names, self.get_outputs()))
+        # the blocking read, timed here and not in the metric: the
+        # executor-group path shares update_dict and is no part of the
+        # pipeline counters (update_dict then finds host arrays)
+        labels_, preds_ = metric_mod._materialize_dicts(labels_, preds_)
+        profiler.h2d_record(sync_seconds=time.perf_counter() - t0)
         eval_metric.update_dict(labels_, preds_)
 
     # -- host sync -----------------------------------------------------------

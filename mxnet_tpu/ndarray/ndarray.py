@@ -14,6 +14,7 @@ from __future__ import annotations
 import numpy as _np
 
 from .. import autograd as _ag
+from .. import profiler as _prof
 from .. import random as _random
 from ..base import MXNetError, dtype_name, dtype_np
 from ..context import Context, cpu, current_context
@@ -644,13 +645,13 @@ def invoke(op, inputs, attrs, out=None, ctx=None):
     key = _random.next_key(ctx) if op.needs_rng else None
     arrays = ([key] + raw) if op.needs_rng else raw
 
-    from .. import profiler as _prof
-
-    # kAllOperator mode: stamp every imperative dispatch (ref: profiler
-    # modes, src/engine/profiler.h:97-98)
-    with _prof.maybe_scope(op.name, "operator", mode="all"):
-        results = (_reg.apply_op_with_key(op, arrays, parsed)
-                   if op.needs_rng else _reg.apply_op(op, raw, parsed))
+    apply, operands = (_reg.apply_op_with_key, arrays) if op.needs_rng \
+        else (_reg.apply_op, raw)
+    if _prof.all_operators():
+        with _prof.span("mx.nd.operator", op=op.name):
+            results = apply(op, operands, parsed)
+    else:
+        results = apply(op, operands, parsed)
     if not isinstance(results, tuple):
         results = (results,)
 

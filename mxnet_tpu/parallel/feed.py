@@ -66,29 +66,30 @@ def place_batch_array(mesh, data_axes, distributed, name, value,
     if is_preplaced(value, sharding):
         profiler.h2d_record(preplaced=1)
         return value
-    t0 = time.perf_counter()
-    if not distributed or jax.process_count() == 1:
-        ndev = mesh.devices.size
-        if value.shape[0] % ndev != 0:
-            raise MXNetError(
-                "async feed: batch dim %d of %r not divisible by "
-                "%d mesh devices" % (value.shape[0], name, ndev))
-        out = jax.device_put(value, sharding)
-    else:
-        local = np.asarray(value)
-        nproc = jax.process_count()
-        if local.shape[0] % jax.local_device_count() != 0:
-            raise MXNetError(
-                "async feed: local batch dim %d of %r not divisible "
-                "by %d local devices"
-                % (local.shape[0], name, jax.local_device_count()))
-        out = jax.make_array_from_process_local_data(
-            sharding, local,
-            global_shape=(local.shape[0] * nproc,) + local.shape[1:])
     # size*itemsize, NOT np.asarray(value).nbytes: forcing a host
     # materialization just for byte accounting would re-add the very
     # per-batch copy this path exists to remove
     nbytes = int(value.size) * np.dtype(value.dtype).itemsize
+    t0 = time.perf_counter()
+    with profiler.span("mx.fit.h2d", name=name, nbytes=nbytes):
+        if not distributed or jax.process_count() == 1:
+            ndev = mesh.devices.size
+            if value.shape[0] % ndev != 0:
+                raise MXNetError(
+                    "async feed: batch dim %d of %r not divisible by "
+                    "%d mesh devices" % (value.shape[0], name, ndev))
+            out = jax.device_put(value, sharding)
+        else:
+            local = np.asarray(value)
+            nproc = jax.process_count()
+            if local.shape[0] % jax.local_device_count() != 0:
+                raise MXNetError(
+                    "async feed: local batch dim %d of %r not divisible "
+                    "by %d local devices"
+                    % (local.shape[0], name, jax.local_device_count()))
+            out = jax.make_array_from_process_local_data(
+                sharding, local,
+                global_shape=(local.shape[0] * nproc,) + local.shape[1:])
     profiler.h2d_record(nbytes=nbytes, puts=1,
                         seconds=time.perf_counter() - t0)
     return out

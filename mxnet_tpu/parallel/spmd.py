@@ -931,9 +931,14 @@ class TrainStep:
                 cast_params = {k: v.astype(cdtype) for k, v in params_c.items()}
             else:
                 cast_params = params_c
-            (loss, (outs, aux_updates)), grads = jax.value_and_grad(
-                loss_of, has_aux=True
-            )(cast_params, aux_c, batch, key)
+            # value_and_grad, taken apart so that the trace tells the
+            # forward's operations from the backward's
+            with jax.named_scope("mx.step.forward"):
+                loss, pullback, (outs, aux_updates) = jax.vjp(
+                    lambda p: loss_of(p, aux_c, batch, key), cast_params,
+                    has_aux=True)
+            with jax.named_scope("mx.step.backward"):
+                grads, = pullback(jnp.ones_like(loss))
             if normalize:
                 # Module convention: rescale_grad = 1/global_batch (model.py)
                 bsz = batch[data_names[0]].shape[0]
@@ -941,8 +946,9 @@ class TrainStep:
             sent = opt_state_c.get(sent_key) if sentinel != "off" else None
             core_opt = opt_state_c if sent is None else \
                 {k: v for k, v in opt_state_c.items() if k != sent_key}
-            new_params, new_opt = apply_update(params_c, grads,
-                                               core_opt, step_no)
+            with jax.named_scope("mx.opt.update"):
+                new_params, new_opt = apply_update(params_c, grads,
+                                                   core_opt, step_no)
             new_aux = dict(aux_c)
             for k, v in aux_updates.items():
                 if k in new_aux:

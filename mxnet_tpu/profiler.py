@@ -1,10 +1,64 @@
-"""Profiler — Chrome trace-event JSON dumps.
+"""Profiler — one span primitive, Chrome trace-event dumps, always-on counters.
 
 Reference counterpart: ``src/engine/profiler.{h,cc}`` +
-``python/mxnet/profiler.py`` (SURVEY §5.1). TPU-native design: wraps the
-JAX/XLA profiler for device truth (XPlane → TensorBoard), while also
-keeping an in-process host-side event recorder that emits the reference's
-Chrome ``trace.json`` format for API parity.
+``python/mxnet/profiler.py`` (SURVEY §5.1). TPU-native design: the device's
+truth is the JAX/XLA profiler's trace (XPlane → xprof / TensorBoard), and the
+program's own spans are written into that same trace, so host and device
+share one clock.  The reference's Chrome ``trace.json`` stays for API parity.
+
+``span(name, **args)`` is the only way the program opens a span.  It enters a
+``jax.profiler.TraceAnnotation``: whenever a ``jax.profiler`` trace is running
+(a benchmark's traced run, or ``profiler_set_state('run')`` with
+``MXNET_TPU_JAX_TRACE_DIR`` set) the span lands on the host plane of the same
+``.xplane.pb`` as the device's operations.  While ``profiler_set_state('run')``
+is on it also appends the Chrome event ``dump_profile`` writes.  Off, it costs
+one test of ``_STATE["running"]`` and the annotation's own inactive path.
+
+Names are fixed strings ``mx.<layer>.<what>``; arguments carry identity
+(``epoch``, ``nbatch``, ``rid``, ``slot``, ``bucket``, ``tokens``, ``active``,
+``step``), never free text.  Every span of the program:
+
+==========================  ==================================================
+``mx.executor.forward``     ``Executor.forward``: one compiled program
+``mx.executor.backward``    ``Executor.backward``
+``mx.executor.forward_backward``  ``Executor.forward_backward``
+``mx.nd.operator``          one imperative operator, ``mode="all"`` only (op)
+``mx.fit.batch``            one turn of ``fit``'s batch loop (epoch, nbatch)
+``mx.fit.forward_backward`` ``forward_backward(data_batch)``
+``mx.fit.h2d``              one batch array really copied to the mesh
+                            (name, nbytes)
+``mx.fit.dispatch``         the call into the compiled train step (step)
+``mx.fit.throttle``         dispatch-ahead bound blocking on the oldest step
+``mx.fit.update``           ``update()``
+``mx.fit.next_batch``       ``next(data_iter)`` and ``prepare``
+``mx.fit.update_metric``    ``update_metric`` (asynchronous on the
+                            device-metrics path)
+``mx.metric.drain``         ``EvalMetric.get``'s one blocking read of the
+                            device-resident sums (sources)
+``mx.fit.host_sync``        the per-batch blocking read of the host-fallback
+                            metric path
+``mx.fit.callbacks``        the ``batch_end_callback`` loop
+``mx.fit.epoch_end``        ``get_params`` / ``set_params`` after an epoch
+                            (epoch)
+``mx.serve.submit``         ``GenerateServer.submit``, client thread
+                            (rid, prompt_tokens)
+``mx.serve.loop``           one turn of the broker loop (active, queued)
+``mx.serve.admit``          admission up to the first prefill (admitted)
+``mx.serve.prefill``        one request's prefill (rid, slot, prompt_tokens,
+                            bucket, prefix_len, queue_wait_ms, active)
+``mx.serve.prefill.device`` the predictor's prefill call, dispatch to logits
+``mx.serve.grow_pages``     page growth before a decode step
+``mx.serve.decode_step``    one decode or speculative step (step, active)
+``mx.serve.decode.device``  the predictor's decode call, dispatch to logits
+``mx.serve.decode.sample``  argmax, append, stream and finish, all slots
+``mx.serve.finish``         a request leaves its slot (rid, reason, tokens)
+==========================  ==================================================
+
+Inside the compiled programs the same naming is ``jax.named_scope`` metadata
+(``mx.lm.embed``, ``mx.lm.attn``, ``mx.lm.ffn``, ``mx.lm.head_loss``,
+``mx.opt.update``, ``mx.step.forward``, ``mx.step.backward``,
+``mx.gen.gather_kv``, ``mx.gen.attn``, ``mx.gen.pool_write``) and the flash
+kernels' own names (``mx_flash_fwd``, ``mx_flash_dq``, ``mx_flash_dkv``).
 """
 from __future__ import annotations
 
@@ -12,6 +66,8 @@ import json
 import os
 import threading
 import time
+
+from jax.profiler import TraceAnnotation as _Annotation
 
 _STATE = {
     "mode": "symbolic",
@@ -53,31 +109,52 @@ set_config = profiler_set_config
 set_state = profiler_set_state
 
 
-def record_event(name, category, start_us, dur_us, tid=0):
-    if not _STATE["running"]:
-        return
-    with _LOCK:
-        _STATE["events"].append(
-            {"name": name, "cat": category, "ph": "X", "ts": start_us, "dur": dur_us,
-             "pid": os.getpid(), "tid": tid}
-        )
+class _ChromeSpan:
+    """A span while ``profiler_set_state('run')`` is on: the annotation, and
+    the Chrome event ``dump_profile`` writes (its category is the last part
+    of the span's name; an ``op`` argument names the event, as the
+    reference's operator events are named)."""
 
+    __slots__ = ("_ann", "_name", "_args", "_start")
 
-class scope:
-    """Context manager recording one host-side trace event."""
-
-    def __init__(self, name, category="operator"):
-        self.name = name
-        self.category = category
+    def __init__(self, name, args):
+        self._ann = _Annotation(name, **args)
+        self._name, self._args = name, args
 
     def __enter__(self):
-        self.start = time.perf_counter_ns() // 1000
+        self._start = time.perf_counter_ns()
+        self._ann.__enter__()
         return self
 
+    def set_metadata(self, **args):
+        self._ann.set_metadata(**args)
+        self._args.update(args)
+
     def __exit__(self, *exc):
-        end = time.perf_counter_ns() // 1000
-        record_event(self.name, self.category, self.start, end - self.start)
+        self._ann.__exit__(*exc)
+        end = time.perf_counter_ns()
+        event = {"name": self._args.get("op", self._name),
+                 "cat": self._name.rpartition(".")[2], "ph": "X",
+                 "ts": self._start // 1000, "dur": (end - self._start) // 1000,
+                 "pid": os.getpid(), "tid": threading.get_ident(),
+                 "args": self._args}
+        with _LOCK:
+            _STATE["events"].append(event)
         return False
+
+
+def span(name, /, **args):
+    """Open the span ``name`` (a row of the table above) as a context
+    manager; ``set_metadata(**args)`` adds arguments known only inside."""
+    if _STATE["running"]:
+        return _ChromeSpan(name, args)
+    return _Annotation(name, **args)
+
+
+def all_operators():
+    """Whether every imperative operator is stamped (ref: kAllOperator,
+    src/engine/profiler.h:97-98): running, in ``mode="all"``."""
+    return _STATE["running"] and _STATE["mode"] == "all"
 
 
 def dump_profile():
@@ -188,13 +265,18 @@ def comm_reset():
 # steady-state fit loop* — the acceptance number for a stall-free loop
 # is host_syncs == 0; `stall_feed`/`stall_compute` split consumer wait
 # time between "waiting on the feed queue" and "throttling dispatch
-# ahead of the device".
+# ahead of the device". `metric_drains` counts the reads of the
+# device-resident metric sums that had something to fetch
+# (`EvalMetric.get` from a callback: a loop can block in one every batch
+# while `host_syncs` stays 0), and `sync_seconds` is the host time spent
+# blocked in both kinds of read, on the clock `put_seconds` is on.
 # ---------------------------------------------------------------------------
 _PIPE_LOCK = threading.Lock()
 _PIPE_ZERO = {
     "puts": 0, "preplaced": 0, "batches": 0, "steps": 0, "nbytes": 0,
     "put_seconds": 0.0, "stall_feed_seconds": 0.0,
     "stall_compute_seconds": 0.0, "host_syncs": 0,
+    "metric_drains": 0, "sync_seconds": 0.0,
     "max_queue_depth": 0, "max_inflight": 0,
 }
 _PIPE = dict(_PIPE_ZERO)
@@ -202,7 +284,8 @@ _PIPE = dict(_PIPE_ZERO)
 
 def h2d_record(nbytes=0, puts=0, preplaced=0, batches=0, steps=0,
                seconds=0.0, stall_feed=0.0, stall_compute=0.0,
-               queue_depth=None, inflight=None, host_syncs=0):
+               queue_depth=None, inflight=None, host_syncs=0,
+               metric_drains=0, sync_seconds=0.0):
     """Accumulate input-pipeline counters (thread-safe; cheap enough to
     run unconditionally, like comm_record)."""
     with _PIPE_LOCK:
@@ -216,6 +299,8 @@ def h2d_record(nbytes=0, puts=0, preplaced=0, batches=0, steps=0,
         s["stall_feed_seconds"] += stall_feed
         s["stall_compute_seconds"] += stall_compute
         s["host_syncs"] += host_syncs
+        s["metric_drains"] += metric_drains
+        s["sync_seconds"] += sync_seconds
         if queue_depth is not None and queue_depth > s["max_queue_depth"]:
             s["max_queue_depth"] = queue_depth
         if inflight is not None and inflight > s["max_inflight"]:
@@ -230,7 +315,7 @@ def pipeline_stats(reset=False):
         if reset:
             _PIPE.update(_PIPE_ZERO)
     if not any(snap[k] for k in ("puts", "preplaced", "batches", "steps",
-                                 "host_syncs")):
+                                 "host_syncs", "metric_drains")):
         return {}
     if snap["puts"]:
         snap["avg_put_ms"] = round(
@@ -607,7 +692,16 @@ _GEN_ZERO = {
     "decode_steps": 0, "tokens": 0, "finished": 0, "eos": 0, "length": 0,
     "deadline": 0, "exhausted": 0, "errors": 0, "shed": 0,
     "slot_steps": 0, "active_slot_steps": 0, "max_queue_depth": 0,
-    "busy_seconds": 0.0,   # prefill + decode compute time (floats)
+    # host seconds (floats): prefill and decode are the two predictor
+    # calls from dispatch to logits on the host (``busy_seconds`` in the
+    # snapshot is their sum); loop = the worker's whole working time
+    # outside the condition wait, so loop - busy is the host loop's own;
+    # stream = inside users' stream_fn callbacks
+    "prefill_seconds": 0.0, "decode_seconds": 0.0,
+    "loop_seconds": 0.0, "stream_seconds": 0.0,
+    # decode steps that had at least one prefill since the step before:
+    # how often a gap between two tokens holds a prefill
+    "decode_steps_after_prefill": 0,
     # shared-prefix KV cache (ISSUE 16): admissions that matched a
     # cached prefix, pages borrowed copy-on-write, prompt tokens whose
     # prefill was skipped, and least-recently-matched evictions
@@ -618,20 +712,31 @@ _GEN_ZERO = {
     # verify rounds run
     "draft_proposed": 0, "draft_accepted": 0, "spec_rounds": 0,
 }
-_GEN_FLOATS = ("busy_seconds",)
+_GEN_FLOATS = ("prefill_seconds", "decode_seconds", "loop_seconds",
+               "stream_seconds")
 _GEN_GAUGES = ("pages_in_use", "pages_high_water", "pool_pages",
                "page_ref_high_water", "prefix_pages")
 _GEN = dict(_GEN_ZERO)
 _GEN_PAGES = {}
 _GEN_TTFT_CAP = 8192
 _GEN_TTFT = None  # deque, created lazily
+_GEN_QUEUE_WAIT = None  # deque of submit-to-admission seconds, same cap
 
 
-def generate_record(queue_depth=None, ttfts=None, **adds):
+def _extended(reservoir, values):
+    if reservoir is None:
+        from collections import deque
+
+        reservoir = deque(maxlen=_GEN_TTFT_CAP)
+    reservoir.extend(values)
+    return reservoir
+
+
+def generate_record(queue_depth=None, ttfts=None, queue_waits=None, **adds):
     """Accumulate generative-serving counters (thread-safe). The
     ``pages_*``/``pool_pages`` names are gauges (latest pool snapshot);
     everything else accumulates. Unknown names raise."""
-    global _GEN_TTFT
+    global _GEN_TTFT, _GEN_QUEUE_WAIT
     with _GEN_LOCK:
         for k, v in adds.items():
             if k in _GEN_GAUGES:
@@ -645,37 +750,36 @@ def generate_record(queue_depth=None, ttfts=None, **adds):
         if queue_depth is not None and queue_depth > _GEN["max_queue_depth"]:
             _GEN["max_queue_depth"] = int(queue_depth)
         if ttfts:
-            if _GEN_TTFT is None:
-                from collections import deque
-
-                _GEN_TTFT = deque(maxlen=_GEN_TTFT_CAP)
-            _GEN_TTFT.extend(ttfts)
+            _GEN_TTFT = _extended(_GEN_TTFT, ttfts)
+        if queue_waits:
+            _GEN_QUEUE_WAIT = _extended(_GEN_QUEUE_WAIT, queue_waits)
 
 
 def generate_stats(reset=False):
     """Snapshot with derived slot occupancy and TTFT p50/p99 (ms);
     empty dict when the generative tier never ran."""
-    global _GEN_TTFT
+    global _GEN_TTFT, _GEN_QUEUE_WAIT
     with _GEN_LOCK:
         snap = dict(_GEN)
         pages = dict(_GEN_PAGES)
         ttft = sorted(_GEN_TTFT) if _GEN_TTFT else []
+        queue_wait = sorted(_GEN_QUEUE_WAIT) if _GEN_QUEUE_WAIT else []
         if reset:
             _GEN.update(_GEN_ZERO)
             _GEN_PAGES.clear()
-            _GEN_TTFT = None
+            _GEN_TTFT = _GEN_QUEUE_WAIT = None
     if not (any(snap.values()) or pages):
         return {}
     snap.update(pages)
     if snap["slot_steps"]:
         snap["slot_occupancy"] = round(
             snap["active_slot_steps"] / snap["slot_steps"], 3)
+    snap["busy_seconds"] = snap["prefill_seconds"] + snap["decode_seconds"]
     if snap["busy_seconds"] > 0:
         # generated tokens over prefill+decode compute time — the
         # server-side throughput gauge (bench_serve reports the
         # arrival-to-completion wall-clock variant next to it)
         snap["tokens_s"] = round(snap["tokens"] / snap["busy_seconds"], 1)
-        snap["busy_seconds"] = round(snap["busy_seconds"], 4)
     if snap["draft_proposed"]:
         # the speculative-decoding health gauge: what fraction of draft
         # proposals the target's verify step accepted
@@ -684,15 +788,25 @@ def generate_stats(reset=False):
     if ttft:
         snap["ttft_p50_ms"] = _percentile_ms(ttft, 0.50)
         snap["ttft_p99_ms"] = _percentile_ms(ttft, 0.99)
+    if queue_wait:
+        snap["queue_wait_count"] = len(queue_wait)
+        snap["queue_wait_p50_ms"] = _percentile_ms(queue_wait, 0.50)
+        snap["queue_wait_p95_ms"] = _percentile_ms(queue_wait, 0.95)
+    if snap["prefills"]:
+        snap["prefill_ms_avg"] = snap["prefill_seconds"] / snap["prefills"] * 1e3
+    if snap["decode_steps"]:
+        # the host loop's own time a decode step: everything the worker
+        # did that was neither of the two predictor calls
+        snap["loop_host_ms_per_step"] = (
+            snap["loop_seconds"] - snap["prefill_seconds"]
+            - snap["decode_seconds"]) / snap["decode_steps"] * 1e3
+        snap["decode_after_prefill_share"] = (
+            snap["decode_steps_after_prefill"] / snap["decode_steps"])
     return snap
 
 
 def generate_reset():
-    global _GEN_TTFT
-    with _GEN_LOCK:
-        _GEN.update(_GEN_ZERO)
-        _GEN_PAGES.clear()
-        _GEN_TTFT = None
+    generate_stats(reset=True)
 
 
 # ---------------------------------------------------------------------------
@@ -1087,16 +1201,6 @@ def resume():
 
 def is_running():
     return _STATE["running"]
-
-
-def maybe_scope(name, category="operator", mode=None):
-    """A trace scope when the profiler runs (and matches ``mode`` if
-    given), else a no-op context — keeps call sites single-expression."""
-    import contextlib
-
-    if _STATE["running"] and (mode is None or _STATE["mode"] == mode):
-        return scope(name, category)
-    return contextlib.nullcontext()
 
 
 # MXNET_PROFILER_AUTOSTART (ref: profiler.cc:65): begin collecting at

@@ -27,6 +27,7 @@ nothing is dropped.
 """
 from __future__ import annotations
 
+import itertools
 import threading
 import time
 from collections import deque
@@ -507,13 +508,17 @@ class ModelServer:
 # ---------------------------------------------------------------------------
 # generative serving (ISSUE 12): continuous-batching decode loop
 # ---------------------------------------------------------------------------
+_REQUEST_IDS = itertools.count(1)   # process-wide, across servers
+
+
 class _GenRequest:
-    __slots__ = ("tokens", "max_new", "eos_id", "future", "stream_fn",
+    __slots__ = ("rid", "tokens", "max_new", "eos_id", "future", "stream_fn",
                  "t_submit", "deadline", "no_eos", "out", "pages",
                  "slot", "ttft", "unflushed", "prefix_len", "shared",
-                 "draft_pages", "draft_pos")
+                 "draft_pages", "draft_pos", "queue_wait")
 
     def __init__(self, tokens, max_new, eos_id, deadline, stream_fn):
+        self.rid = next(_REQUEST_IDS)
         self.tokens = tokens
         self.max_new = max_new
         self.eos_id = eos_id
@@ -526,6 +531,7 @@ class _GenRequest:
         self.pages = []
         self.slot = None
         self.ttft = None
+        self.queue_wait = None       # submit to admission, seconds
         self.unflushed = []
         self.prefix_len = 0          # tokens covered by shared prefix pages
         self.shared = 0              # pages borrowed from the prefix index
@@ -651,6 +657,9 @@ class GenerateServer:
         self._q = deque()
         self._stopped = False
         self._error = None
+        self._decode_steps = 0       # this server's decode/spec steps so far
+        self._prefilled = False      # a prefill ran since the last decode step
+        self._stream_s = 0.0         # seconds in stream_fn callbacks this turn
         self._step_hook = None       # test seam: called before each decode
         self._thread = threading.Thread(target=self._run, daemon=True,
                                         name="generate-%s" % name)
@@ -661,12 +670,18 @@ class GenerateServer:
                deadline=None, stream_fn=None, timeout=None):
         """Enqueue one generate request; returns a Future resolving to
         ``{"tokens": [int], "finish_reason": "eos"|"length",
-        "ttft_s", "latency_s", "prompt_tokens"}``. ``deadline``
+        "ttft_s", "latency_s", "prompt_tokens", "rid"}``. ``deadline``
         (seconds from now) marks it sheddable at dequeue (PR 9) AND
         bounds the decode run itself — a mid-generation expiry fails
         the future with :class:`DeadlineExceeded` and recycles the
         slot + pages. ``max_new_tokens`` is capped by
         ``MXNET_GENERATE_MAX_STEPS`` and the per-slot context bound."""
+        with profiler.span("mx.serve.submit") as span:
+            return self._submit(span, tokens, max_new_tokens, eos_id,
+                                deadline, stream_fn, timeout)
+
+    def _submit(self, span, tokens, max_new_tokens, eos_id, deadline,
+                stream_fn, timeout):
         pred = self.predictor
         tokens = np.asarray(tokens, np.int32).reshape(-1)
         if tokens.shape[0] < 1:
@@ -703,6 +718,7 @@ class GenerateServer:
                                     "seconds, got %r" % deadline)
             deadline = time.monotonic() + deadline
         req = _GenRequest(tokens, max_new, eos_id, deadline, stream_fn)
+        span.set_metadata(rid=req.rid, prompt_tokens=int(tokens.shape[0]))
         wait_until = time.monotonic() + (
             self._submit_timeout if timeout is None else float(timeout))
         with self._cond:
@@ -805,6 +821,7 @@ class GenerateServer:
                 break     # backpressure: completions will recycle pages
             self._q.popleft()
             r.slot = free.pop(0)
+            r.queue_wait = time.perf_counter() - r.t_submit
             self._slot_req[r.slot] = r
             admitted.append(r)
         if shed or admitted:
@@ -848,29 +865,37 @@ class GenerateServer:
             return
         if r.unflushed and (final or len(r.unflushed) >= self._flush_every):
             chunk, r.unflushed = r.unflushed, []
+            t0 = time.perf_counter()
             try:
                 r.stream_fn(chunk)
             except Exception:
                 pass     # a broken stream consumer must not kill the loop
+            self._stream_s += time.perf_counter() - t0
 
     def _finish(self, r, reason):
-        self._vacate(r)
-        self._flush_stream(r, final=True)
-        profiler.generate_record(finished=1, **{reason: 1})
-        r.future.set_result({
-            "tokens": list(r.out),
-            "finish_reason": reason,
-            "prompt_tokens": int(r.tokens.shape[0]),
-            "ttft_s": r.ttft,
-            "latency_s": time.perf_counter() - r.t_submit,
-        })
+        with profiler.span("mx.serve.finish", rid=r.rid, reason=reason,
+                           tokens=len(r.out)):
+            self._vacate(r)
+            self._flush_stream(r, final=True)
+            profiler.generate_record(finished=1, **{reason: 1})
+            r.future.set_result({
+                "tokens": list(r.out),
+                "finish_reason": reason,
+                "prompt_tokens": int(r.tokens.shape[0]),
+                "ttft_s": r.ttft,
+                "latency_s": time.perf_counter() - r.t_submit,
+                "rid": r.rid,
+            })
 
     def _fail(self, r, exc, counter=None):
-        self._vacate(r)
-        self._flush_stream(r, final=True)
-        profiler.generate_record(finished=1, **{counter or "errors": 1})
-        if not r.future.done():
-            r.future.set_exception(exc)
+        counter = counter or "errors"
+        with profiler.span("mx.serve.finish", rid=r.rid, reason=counter,
+                           tokens=len(r.out)):
+            self._vacate(r)
+            self._flush_stream(r, final=True)
+            profiler.generate_record(finished=1, **{counter: 1})
+            if not r.future.done():
+                r.future.set_exception(exc)
 
     def _check_done(self, r, tok):
         """EOS / length / deadline disposition for a just-produced
@@ -889,29 +914,41 @@ class GenerateServer:
         return False
 
     def _prefill_one(self, r):
+        n_prompt = int(r.tokens.shape[0])
+        with profiler.span("mx.serve.prefill", rid=r.rid, slot=r.slot,
+                           prompt_tokens=n_prompt,
+                           bucket=self.predictor.pick_bucket(
+                               n_prompt - r.prefix_len),
+                           prefix_len=r.prefix_len,
+                           queue_wait_ms=round(r.queue_wait * 1e3, 3),
+                           active=self._active_count()):
+            self._prefill(r, n_prompt)
+
+    def _prefill(self, r, n_prompt):
         pred = self.predictor
         if chaos.generate_fault() == "stall":
             r.no_eos = True    # the request that never emits EOS
         t0 = time.perf_counter()
         try:
-            if r.prefix_len:
-                # shared-prefix admission: the first prefix_len tokens'
-                # K/V already live in the matched (shared) pages — run
-                # only the uncovered tail, which attends the shared
-                # pages but writes exclusively the private ones (COW)
-                logits = pred.extend_tail(r.tokens[r.prefix_len:],
-                                          r.prefix_len, r.pages)
-            else:
-                logits = pred.prefill(r.tokens, r.pages)
+            with profiler.span("mx.serve.prefill.device"):
+                if r.prefix_len:
+                    # shared-prefix admission: the first prefix_len tokens'
+                    # K/V already live in the matched (shared) pages — run
+                    # only the uncovered tail, which attends the shared
+                    # pages but writes exclusively the private ones (COW)
+                    logits = pred.extend_tail(r.tokens[r.prefix_len:],
+                                              r.prefix_len, r.pages)
+                else:
+                    logits = pred.prefill(r.tokens, r.pages)
             if self._draft is not None:
                 r.draft_pages = self._draft.pool.alloc(
-                    self._draft.pages_needed(r.tokens.shape[0]))
+                    self._draft.pages_needed(n_prompt))
                 self._draft.prefill(r.tokens, r.draft_pages)
         except BaseException as e:
             self._fail(r, e)
             return
         now = time.perf_counter()
-        profiler.generate_record(busy_seconds=now - t0)
+        self._prefilled = True
         r.ttft = now - r.t_submit
         tok = int(np.argmax(logits))
         r.out.append(tok)
@@ -921,8 +958,8 @@ class GenerateServer:
         # counts tokens actually RUN — a matched prefix's tokens land
         # in prefill_tokens_saved instead (their sum is the prompt)
         profiler.generate_record(prefills=1, tokens=1,
-                                 prefill_tokens=int(r.tokens.shape[0])
-                                 - r.prefix_len,
+                                 prefill_tokens=n_prompt - r.prefix_len,
+                                 prefill_seconds=now - t0,
                                  ttfts=[r.ttft])
         if r.prefix_len:
             profiler.generate_record(prefix_hits=1,
@@ -937,16 +974,20 @@ class GenerateServer:
         self._record_pool()
         slot = r.slot
         self._block_tables[slot, :len(r.pages)] = r.pages
-        self._positions[slot] = r.tokens.shape[0]
+        self._positions[slot] = n_prompt
         self._tokens[slot] = tok
         if self._draft is not None:
             self._draft_bt[slot, :len(r.draft_pages)] = r.draft_pages
-            r.draft_pos = int(r.tokens.shape[0])
+            r.draft_pos = n_prompt
         self._flush_stream(r)
         if not self._check_done(r, tok):
             self._active[slot] = True
 
     def _grow_pages(self, headroom=0):
+        with profiler.span("mx.serve.grow_pages"):
+            self._grow(headroom)
+
+    def _grow(self, headroom):
         """Before a decode step, make sure every active slot owns the
         page(s) its next write positions land in — up to ``headroom``
         extra positions past the pending one for a speculative round's
@@ -978,27 +1019,38 @@ class GenerateServer:
                     counter="exhausted")
                 continue
 
+    def _step_counts(self, active, tokens, seconds, **more):
+        """One decode or speculative step's counters, in one record."""
+        profiler.generate_record(
+            decode_steps=1, tokens=tokens, slot_steps=self.predictor.slots,
+            active_slot_steps=active, decode_seconds=seconds,
+            decode_steps_after_prefill=int(self._prefilled), **more)
+        self._prefilled = False
+        self._decode_steps += 1
+
     def _decode_step(self):
         pred = self.predictor
         if self._step_hook is not None:
             self._step_hook()
-        t0 = time.perf_counter()
-        logits = pred.decode(self._tokens, self._positions,
-                             self._block_tables, self._active)
         active = np.flatnonzero(self._active)
-        self._positions[active] += 1
-        profiler.generate_record(decode_steps=1, tokens=len(active),
-                                 slot_steps=pred.slots,
-                                 active_slot_steps=len(active),
-                                 busy_seconds=time.perf_counter() - t0)
-        for slot in active:
-            r = self._slot_req[slot]
-            tok = int(np.argmax(logits[slot]))
-            r.out.append(tok)
-            r.unflushed.append(tok)
-            self._tokens[slot] = tok
-            self._flush_stream(r)
-            self._check_done(r, tok)
+        with profiler.span("mx.serve.decode_step", step=self._decode_steps,
+                           active=len(active)):
+            t0 = time.perf_counter()
+            with profiler.span("mx.serve.decode.device"):
+                logits = pred.decode(self._tokens, self._positions,
+                                     self._block_tables, self._active)
+            self._positions[active] += 1
+            self._step_counts(len(active), len(active),
+                              time.perf_counter() - t0)
+            with profiler.span("mx.serve.decode.sample"):
+                for slot in active:
+                    r = self._slot_req[slot]
+                    tok = int(np.argmax(logits[slot]))
+                    r.out.append(tok)
+                    r.unflushed.append(tok)
+                    self._tokens[slot] = tok
+                    self._flush_stream(r)
+                    self._check_done(r, tok)
 
     def _spec_step(self):
         """One speculative-decoding round (ISSUE 16), replacing one
@@ -1024,13 +1076,27 @@ class GenerateServer:
         K/V garbage at positions past the accepted prefix in both
         caches; the next round's writes land there before any query
         attends them (the padded-prefill-tail invariant)."""
-        pred, draft, k = self.predictor, self._draft, self._spec_k
         if self._step_hook is not None:
             self._step_hook()
-        t0 = time.perf_counter()
         active = [int(s) for s in np.flatnonzero(self._active)]
         if not active:
             return
+        with profiler.span("mx.serve.decode_step", step=self._decode_steps,
+                           active=len(active)):
+            t0 = time.perf_counter()
+            with profiler.span("mx.serve.decode.device"):
+                chain_len, k_i, props, logits = self._spec_propose_verify(
+                    active)
+            seconds = time.perf_counter() - t0
+            with profiler.span("mx.serve.decode.sample"):
+                emitted = self._spec_accept(active, chain_len, k_i, props,
+                                            logits)
+            self._step_counts(len(active), emitted, seconds, spec_rounds=1)
+
+    def _spec_propose_verify(self, active):
+        """Draft and verify phases of one round: per-slot chain lengths,
+        proposal budgets, proposals, and the target's logits for them."""
+        pred, draft, k = self.predictor, self._draft, self._spec_k
         S = pred.slots
 
         chain_len, k_i, feed, props = {}, {}, {}, {}
@@ -1084,8 +1150,11 @@ class GenerateServer:
                                   chain_len[s] - 1 + n)
             vv[s, :n] = True
         logits = pred.extend(vt, vp, self._block_tables, vv)
+        return chain_len, k_i, props, logits
 
-        # -- accept phase ---------------------------------------------
+    def _spec_accept(self, active, chain_len, k_i, props, logits):
+        """Accept phase: emit the agreed prefix and the target's own next
+        token per slot; returns the tokens emitted."""
         emitted_total = 0
         for s in active:
             r = self._slot_req[s]
@@ -1117,49 +1186,72 @@ class GenerateServer:
                     break
             if not done:
                 self._positions[s] = L - 1 + len(emit)
-        profiler.generate_record(
-            decode_steps=1, spec_rounds=1, tokens=emitted_total,
-            slot_steps=S, active_slot_steps=len(active),
-            busy_seconds=time.perf_counter() - t0)
+        return emitted_total
+
+    def _wait_for_work(self):
+        """Block until there is something to do; False once stopped."""
+        with self._cond:
+            while (not self._q and not self._active_count()
+                   and not self._stopped):
+                self._cond.wait()
+            return not self._stopped
+
+    def _turn(self):
+        """One turn of the loop: admit, prefill what was admitted, one
+        decode step over the active slots. False once stopped."""
+        with profiler.span("mx.serve.admit") as admit:
+            with self._cond:
+                if self._stopped:
+                    return False
+                admitted, shed, starved = self._admit_locked()
+            admit.set_metadata(admitted=len(admitted))
+            if admitted:
+                profiler.generate_record(
+                    queue_waits=[r.queue_wait for r in admitted])
+            if shed:
+                exc = DeadlineExceeded(
+                    "generate: deadline expired before admission "
+                    "(shed at dequeue)")
+                for r in shed:
+                    if not r.future.done():
+                        r.future.set_exception(exc)
+                profiler.generate_record(shed=len(shed))
+            if starved is not None:
+                self._fail(starved, PagePoolExhausted(
+                    "generate: prompt of %d token(s) cannot be "
+                    "admitted — pool empty with no requests in "
+                    "flight to recycle from"
+                    % starved.tokens.shape[0]), counter="exhausted")
+        for r in admitted:
+            self._prefill_one(r)
+        if not self._active_count():
+            return True
+        if self._draft is not None:
+            # speculative round: verify writes up to spec_k
+            # positions past the pending token
+            self._grow_pages(headroom=self._spec_k)
+            if self._active_count():
+                self._spec_step()
+        else:
+            self._grow_pages()
+            if self._active_count():
+                self._decode_step()
+        return True
 
     def _run(self):
         try:
-            while True:
-                with self._cond:
-                    while (not self._q and not self._active_count()
-                           and not self._stopped):
-                        self._cond.wait()
-                    if self._stopped:
-                        return
-                    admitted, shed, starved = self._admit_locked()
-                if shed:
-                    exc = DeadlineExceeded(
-                        "generate: deadline expired before admission "
-                        "(shed at dequeue)")
-                    for r in shed:
-                        if not r.future.done():
-                            r.future.set_exception(exc)
-                    profiler.generate_record(shed=len(shed))
-                if starved is not None:
-                    self._fail(starved, PagePoolExhausted(
-                        "generate: prompt of %d token(s) cannot be "
-                        "admitted — pool empty with no requests in "
-                        "flight to recycle from"
-                        % starved.tokens.shape[0]), counter="exhausted")
-                for r in admitted:
-                    self._prefill_one(r)
-                if not self._active_count():
-                    continue
-                if self._draft is not None:
-                    # speculative round: verify writes up to spec_k
-                    # positions past the pending token
-                    self._grow_pages(headroom=self._spec_k)
-                    if self._active_count():
-                        self._spec_step()
-                else:
-                    self._grow_pages()
-                    if self._active_count():
-                        self._decode_step()
+            while self._wait_for_work():
+                t0 = time.perf_counter()
+                with profiler.span("mx.serve.loop",
+                                   active=self._active_count(),
+                                   queued=len(self._q)):
+                    alive = self._turn()
+                profiler.generate_record(
+                    loop_seconds=time.perf_counter() - t0,
+                    stream_seconds=self._stream_s)
+                self._stream_s = 0.0
+                if not alive:
+                    return
         except BaseException as e:   # loop death: sticky, fail everything
             with self._cond:
                 self._error = e

@@ -117,6 +117,9 @@ def test_compile_cache_dir_placement(monkeypatch):
         assert ctx_mod.compile_cache_dir() == "/some/where/else"
         assert jax.config.jax_compilation_cache_dir == before  # untouched
         assert jax.config.jax_persistent_cache_min_compile_time_secs == 0
+        # scope names are metadata: a trace must not show a cached
+        # executable's older ones (ISSUE 26)
+        assert jax.config.jax_compilation_cache_include_metadata_in_key
         monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR")
         first = ctx_mod.compile_cache_dir()
         assert first == os.path.join(ROOT, ".jax_cache")
@@ -126,6 +129,8 @@ def test_compile_cache_dir_placement(monkeypatch):
         jax.config.update("jax_compilation_cache_dir", before)
         jax.config.update("jax_persistent_cache_min_compile_time_secs",
                           threshold)
+        jax.config.update("jax_compilation_cache_include_metadata_in_key",
+                          False)
 
 
 def test_import_places_the_compile_cache():
