@@ -553,10 +553,12 @@ class ShardedBatchIter:
 
     Once an epoch ends, next() keeps raising StopIteration until
     reset() (the DataIter contract); after reset() the next call opens
-    the NEXT lease-book epoch. A read-ahead consumer (DeviceQueueIter)
-    that resets after its final epoch may therefore lease a chunk of an
-    epoch nobody trains — those records stay resumable at the
-    committed cursor because that epoch never completes."""
+    the NEXT lease-book epoch. A read-ahead consumer that starts
+    reading at reset() may therefore lease a chunk of an epoch nobody
+    trains (those records stay resumable at the committed cursor
+    because that epoch never completes); DeviceQueueIter starts its
+    worker only at the first next() after a reset, so ``fit``'s last
+    reset leases nothing."""
 
     def __init__(self, stream, batch_size, data_shape, label_shape=(),
                  data_name="data", label_name="softmax_label",

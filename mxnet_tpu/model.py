@@ -214,35 +214,19 @@ class FeedForward:
         if self.allow_extra_params and arg_params:
             known = set(self.symbol.list_arguments())
             arg_params = {k: v for k, v in arg_params.items() if k in known}
-        # fused kvstore tiers get the async host→device input pipeline
-        # (ISSUE 5): batches are sharded onto the mesh on a background
-        # thread while the compiled step runs. Binding is deferred to the
-        # first batch, i.e. after fit's init_optimizer built the group.
-        kv_type = kvstore if isinstance(kvstore, str) \
-            else getattr(kvstore, "type", "")
-        pipelined = None
-        if kv_type in ("tpu", "dist_sync", "dist_sync_device", "dist_async"):
-            from .parallel.feed import DeviceQueueIter
-
-            # close_source=False: the caller owns `train` and may fit()
-            # again with it — only the wrapper's worker shuts down here
-            train = pipelined = DeviceQueueIter(train, module=mod,
-                                                close_source=False)
-        try:
-            mod.fit(train, eval_data=eval_data, eval_metric=eval_metric,
-                    epoch_end_callback=epoch_end_callback,
-                    batch_end_callback=batch_end_callback, kvstore=kvstore,
-                    optimizer=self.optimizer, optimizer_params=opt_params,
-                    eval_end_callback=eval_end_callback,
-                    eval_batch_end_callback=eval_batch_end_callback,
-                    initializer=self.initializer, arg_params=arg_params,
-                    aux_params=self.aux_params, allow_missing=True,
-                    begin_epoch=self.begin_epoch,
-                    num_epoch=self.num_epoch, monitor=monitor,
-                    force_rebind=True)  # a prior predict/score bound for inference
-        finally:
-            if pipelined is not None:
-                pipelined.close()
+        # on a fused kvstore tier Module.fit feeds itself through the
+        # device queue (parallel/feed.py) and leaves `train` open
+        mod.fit(train, eval_data=eval_data, eval_metric=eval_metric,
+                epoch_end_callback=epoch_end_callback,
+                batch_end_callback=batch_end_callback, kvstore=kvstore,
+                optimizer=self.optimizer, optimizer_params=opt_params,
+                eval_end_callback=eval_end_callback,
+                eval_batch_end_callback=eval_batch_end_callback,
+                initializer=self.initializer, arg_params=arg_params,
+                aux_params=self.aux_params, allow_missing=True,
+                begin_epoch=self.begin_epoch,
+                num_epoch=self.num_epoch, monitor=monitor,
+                force_rebind=True)  # a prior predict/score bound for inference
         self.arg_params, self.aux_params = mod.get_params()
         return self
 

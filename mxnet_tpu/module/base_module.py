@@ -184,81 +184,98 @@ class BaseModule:
         if not isinstance(eval_metric, metric_mod.EvalMetric):
             eval_metric = metric_mod.create(eval_metric)
 
-        for epoch in range(begin_epoch, num_epoch):
-            tic = time.time()
-            eval_metric.reset()
-            nbatch = 0
-            data_iter = iter(train_data)
-            end_of_batch = False
-            next_data_batch = next(data_iter)
-            while not end_of_batch:
-                with profiler.span("mx.fit.batch", epoch=epoch, nbatch=nbatch):
-                    data_batch = next_data_batch
-                    if monitor is not None:
-                        monitor.tic()
-                    with profiler.span("mx.fit.forward_backward"):
-                        self.forward_backward(data_batch)
-                    with profiler.span("mx.fit.update"):
-                        self.update()
-                    with profiler.span("mx.fit.next_batch"):
-                        try:
-                            next_data_batch = next(data_iter)
-                            self.prepare(next_data_batch,
-                                         sparse_row_id_fn=sparse_row_id_fn)
-                        except StopIteration:
-                            end_of_batch = True
-                    with profiler.span("mx.fit.update_metric"):
-                        self.update_metric(eval_metric, data_batch.label)
-                    if health_guard is not None:
-                        # batch-boundary health/preemption hook: may roll
-                        # the module back to the latest checkpoint, or
-                        # raise SystemExit(EXIT_PREEMPTED) after a
-                        # grace-window checkpoint
-                        health_guard.on_batch(epoch, nbatch, eval_metric,
-                                              data_batch.label)
-                    if monitor is not None:
-                        monitor.toc_print()
-                    if batch_end_callback is not None:
-                        batch_end_params = BatchEndParam(
-                            epoch=epoch, nbatch=nbatch, eval_metric=eval_metric,
-                            locals=locals()
-                        )
-                        with profiler.span("mx.fit.callbacks"):
-                            for callback in _as_list(batch_end_callback):
-                                callback(batch_end_params)
-                    nbatch += 1
+        # the fused step takes its batches already on the mesh: put the
+        # device queue between the caller's iterator and this loop, so the
+        # slice and copy of batch n+1 run on the queue's worker while step
+        # n computes. fit owns the wrapper: every reset goes through it and
+        # it is closed on the way out, never the caller's iterator, which
+        # stays usable for another fit
+        from ..parallel.feed import DeviceQueueIter
 
-            for name, val in eval_metric.get_name_value():
-                self.logger.info("Epoch[%d] Train-%s=%f", epoch, name, val)
-            toc = time.time()
-            self.logger.info("Epoch[%d] Time cost=%.3f", epoch, (toc - tic))
+        feed = None
+        if (getattr(self, "_fused", None) is not None
+                and not isinstance(train_data, DeviceQueueIter)):
+            train_data = feed = DeviceQueueIter(train_data, module=self,
+                                                close_source=False)
+        try:
+            for epoch in range(begin_epoch, num_epoch):
+                tic = time.time()
+                eval_metric.reset()
+                nbatch = 0
+                data_iter = iter(train_data)
+                end_of_batch = False
+                next_data_batch = next(data_iter)
+                while not end_of_batch:
+                    with profiler.span("mx.fit.batch", epoch=epoch, nbatch=nbatch):
+                        data_batch = next_data_batch
+                        if monitor is not None:
+                            monitor.tic()
+                        with profiler.span("mx.fit.forward_backward"):
+                            self.forward_backward(data_batch)
+                        with profiler.span("mx.fit.update"):
+                            self.update()
+                        with profiler.span("mx.fit.next_batch"):
+                            try:
+                                next_data_batch = next(data_iter)
+                                self.prepare(next_data_batch,
+                                             sparse_row_id_fn=sparse_row_id_fn)
+                            except StopIteration:
+                                end_of_batch = True
+                        with profiler.span("mx.fit.update_metric"):
+                            self.update_metric(eval_metric, data_batch.label)
+                        if health_guard is not None:
+                            # batch-boundary health/preemption hook: may roll
+                            # the module back to the latest checkpoint, or
+                            # raise SystemExit(EXIT_PREEMPTED) after a
+                            # grace-window checkpoint
+                            health_guard.on_batch(epoch, nbatch, eval_metric,
+                                                  data_batch.label)
+                        if monitor is not None:
+                            monitor.toc_print()
+                        if batch_end_callback is not None:
+                            batch_end_params = BatchEndParam(
+                                epoch=epoch, nbatch=nbatch, eval_metric=eval_metric,
+                                locals=locals()
+                            )
+                            with profiler.span("mx.fit.callbacks"):
+                                for callback in _as_list(batch_end_callback):
+                                    callback(batch_end_params)
+                        nbatch += 1
 
-            with profiler.span("mx.fit.epoch_end", epoch=epoch):
-                arg_params_, aux_params_ = self.get_params()
-                self.set_params(arg_params_, aux_params_)
+                for name, val in eval_metric.get_name_value():
+                    self.logger.info("Epoch[%d] Train-%s=%f", epoch, name, val)
+                toc = time.time()
+                self.logger.info("Epoch[%d] Time cost=%.3f", epoch, (toc - tic))
 
-            if bg_tuner is not None:
-                # drained boundary: get_params() above blocked on the
-                # dispatch-ahead pipeline, so the tuner's bounded slot
-                # cannot overlap a steady-state step; winners commit
-                # atomically and the next trace of this shape picks
-                # them up
-                bg_tuner.on_drain()
+                with profiler.span("mx.fit.epoch_end", epoch=epoch):
+                    arg_params_, aux_params_ = self.get_params()
+                    self.set_params(arg_params_, aux_params_)
 
-            if epoch_end_callback is not None:
-                for callback in _as_list(epoch_end_callback):
-                    callback(epoch, self.symbol, arg_params_, aux_params_)
+                if bg_tuner is not None:
+                    # drained boundary: get_params() above blocked on the
+                    # dispatch-ahead pipeline, so the tuner's bounded slot
+                    # cannot overlap a steady-state step; winners commit
+                    # atomically and the next trace of this shape picks
+                    # them up
+                    bg_tuner.on_drain()
 
-            if eval_data is not None:
-                res = self.score(
-                    eval_data, validation_metric,
-                    score_end_callback=eval_end_callback,
-                    batch_end_callback=eval_batch_end_callback, epoch=epoch,
-                )
-                for name, val in res:
-                    self.logger.info("Epoch[%d] Validation-%s=%f", epoch, name, val)
+                if epoch_end_callback is not None:
+                    for callback in _as_list(epoch_end_callback):
+                        callback(epoch, self.symbol, arg_params_, aux_params_)
 
-            train_data.reset()
+                if eval_data is not None:
+                    res = self.score(
+                        eval_data, validation_metric,
+                        score_end_callback=eval_end_callback,
+                        batch_end_callback=eval_batch_end_callback, epoch=epoch,
+                    )
+                    for name, val in res:
+                        self.logger.info("Epoch[%d] Validation-%s=%f", epoch, name, val)
+
+                train_data.reset()
+        finally:
+            if feed is not None:
+                feed.close()
 
     # -- abstract ------------------------------------------------------------
     @property

@@ -102,7 +102,9 @@ _ABORT = object()  # worker thread died; see self._exc
 class DeviceQueueIter(DataIter):
     """Wrap any :class:`DataIter` so batches arrive on the mesh already
     sharded, converted on a background thread while the previous step
-    computes (ISSUE 5 tentpole).
+    computes (ISSUE 5 tentpole). ``Module.fit`` on a fused kvstore puts
+    one around the iterator it is given; wrap by hand only in a loop that
+    calls ``forward_backward`` itself.
 
     Parameters
     ----------
@@ -125,8 +127,8 @@ class DeviceQueueIter(DataIter):
         consumer). Default ``MXNET_TPU_FEED_DEPTH`` (2).
     close_source : bool
         Whether :meth:`close` also closes ``data_iter``. Default True;
-        auto-wrappers around a CALLER-owned iterator (``FeedForward.fit``)
-        pass False so the caller can keep using it.
+        ``Module.fit``, which wraps a CALLER-owned iterator itself, passes
+        False so the caller can keep using it.
 
     Supports ``with DeviceQueueIter(...) as it:`` and explicit
     :meth:`close`; ``reset()`` restarts cleanly after ``StopIteration``
@@ -291,7 +293,8 @@ class DeviceQueueIter(DataIter):
         if self._passthrough:
             return self.data_iter.next()
         t0 = time.perf_counter()
-        item = self._q.get()
+        with profiler.span("mx.fit.feed_wait"):
+            item = self._q.get()
         profiler.h2d_record(stall_feed=time.perf_counter() - t0)
         if item is _END:
             # leave a sentinel for repeated next() calls post-epoch
@@ -372,17 +375,16 @@ class DeviceQueueIter(DataIter):
         self._thread = None
 
     def reset(self):
-        """Restart from the top of the (reset) source iterator — valid
-        after StopIteration AND after abandoning an epoch mid-stream."""
+        """Stop the worker and reset the source iterator — valid after
+        StopIteration AND after abandoning an epoch mid-stream. The
+        worker starts again at the next ``next()``, as it first did: a
+        reset nobody reads after (``fit``'s last) pulls nothing from the
+        source, which is left as the bare loop would leave it."""
         if self._closed:
             raise MXNetError("DeviceQueueIter: iterator is closed")
-        if self._passthrough or self._thread is None:
-            self.data_iter.reset()
-            return
         self._shutdown()
         self.data_iter.reset()
         self._current_batch = None
-        self._start()
 
     def close(self):
         """Stop the worker, drop queued device batches, close the source

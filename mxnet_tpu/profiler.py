@@ -31,6 +31,8 @@ Names are fixed strings ``mx.<layer>.<what>``; arguments carry identity
 ``mx.fit.throttle``         dispatch-ahead bound blocking on the oldest step
 ``mx.fit.update``           ``update()``
 ``mx.fit.next_batch``       ``next(data_iter)`` and ``prepare``
+``mx.fit.feed_wait``        ``DeviceQueueIter.next`` waiting for its worker's
+                            next placed batch
 ``mx.fit.update_metric``    ``update_metric`` (asynchronous on the
                             device-metrics path)
 ``mx.metric.drain``         ``EvalMetric.get``'s one blocking read of the

@@ -48,6 +48,11 @@ def _fused_module(X, y, batch=64, contexts=None, seed=0):
     return mod, it
 
 
+def _no_feed_thread():
+    return not any(t.name == "DeviceQueueIter" and t.is_alive()
+                   for t in threading.enumerate())
+
+
 class _CountingIter(mx.io.DataIter):
     """Wraps a DataIter, counting next() calls and supporting close()."""
 
@@ -147,9 +152,7 @@ def test_device_queue_reset_mid_epoch_and_close():
         dq.next()
     with pytest.raises(MXNetError):
         dq.reset()
-    # no lingering worker threads
-    assert not any(t.name == "DeviceQueueIter" and t.is_alive()
-                   for t in threading.enumerate())
+    assert _no_feed_thread()
 
 
 def test_device_queue_depth_validation():
@@ -402,8 +405,196 @@ def test_fit_api_end_to_end_with_pipeline(tmp_path):
     assert os.path.exists(str(tmp_path / "pipe-0004.params"))
 
 
+# ---------------------------------------------------------------------------
+# ISSUE 27: Module.fit puts the queue around the caller's iterator itself
+# ---------------------------------------------------------------------------
+class _ThreadLoggingIter(_CountingIter):
+    """Also notes the thread each next() ran on; ``boom_at`` makes the
+    n-th next() raise."""
+
+    def __init__(self, inner, boom_at=None):
+        super().__init__(inner)
+        self.threads = []
+        self.boom_at = boom_at
+
+    def next(self):
+        self.threads.append(threading.current_thread().name)
+        if self.boom_at is not None and self.pulled == self.boom_at:
+            raise ValueError("decoder exploded")
+        return super().next()
+
+
+def _plain_fit(src, kvstore="tpu", num_epoch=2, contexts=None, **kw):
+    mod = mx.mod.Module(_mlp(), context=contexts or
+                        [mx.cpu(i) for i in range(8)])
+    mod.fit(src, num_epoch=num_epoch, kvstore=kvstore, optimizer="sgd",
+            optimizer_params={"learning_rate": 0.1, "momentum": 0.9},
+            initializer=mx.initializer.Xavier(), **kw)
+    return mod
+
+
+def test_module_fit_places_every_batch_on_the_queue_thread(monkeypatch):
+    from mxnet_tpu.module import spmd_group
+    from mxnet_tpu.parallel import feed as feed_mod
+
+    copied_on, found_placed_on = [], []
+    real = feed_mod.place_batch_array
+
+    def spy(mesh, data_axes, distributed, name, value, sharding=None):
+        placed = is_preplaced(value, sharding)
+        (found_placed_on if placed else copied_on).append(
+            threading.current_thread().name)
+        return real(mesh, data_axes, distributed, name, value,
+                    sharding=sharding)
+
+    monkeypatch.setattr(feed_mod, "place_batch_array", spy)    # the worker's
+    monkeypatch.setattr(spmd_group, "place_batch_array", spy)  # the step's
+    X, y = _data(n=256)
+    src = _ThreadLoggingIter(mx.io.NDArrayIter(X, y, batch_size=64))
+    seen = []
+    profiler.pipeline_reset()
+    mod = _plain_fit(src, batch_end_callback=lambda p: seen.append(
+        p.locals["data_batch"]))
+    assert mod._fused is not None
+    stats = profiler.pipeline_stats()
+    steps = 2 * 4                       # 2 epochs x 4 batches
+    assert stats["steps"] == steps, stats
+    # the worker read and copied every batch; the step found them placed
+    assert copied_on == ["DeviceQueueIter"] * (2 * steps)
+    assert found_placed_on == [threading.current_thread().name] * (2 * steps)
+    assert set(src.threads) == {"DeviceQueueIter"}
+    assert stats["puts"] == 2 * steps and stats["batches"] == steps, stats
+    assert stats["preplaced"] == 2 * steps, stats    # two arrays a step
+    assert 1 <= stats["max_queue_depth"] <= 2, stats
+    # callbacks see the device-resident batch the step ran on
+    sharding = mod._fused._batch_sharding
+    assert len(seen) == steps
+    assert all(is_preplaced(a._data(), sharding)
+               for b in seen for a in b.data + b.label)
+
+
+def test_module_fit_through_queue_matches_host_batches_bitexact():
+    X, y = _data(n=256, seed=3)
+    opt = {"learning_rate": 0.1, "momentum": 0.9}
+    ctx = [mx.cpu(i) for i in range(8)]
+    probe, _ = _fused_module(X, y, seed=21)
+    arg0, aux0 = probe.get_params()
+    arg0 = {k: v.asnumpy() for k, v in arg0.items()}
+
+    def start():
+        return {k: nd.array(v) for k, v in arg0.items()}
+
+    fitted = mx.mod.Module(_mlp(), context=ctx)
+    fitted.fit(mx.io.NDArrayIter(X, y, batch_size=64), num_epoch=2,
+               kvstore="tpu", optimizer="sgd", optimizer_params=opt,
+               arg_params=start(), aux_params=aux0)
+
+    it = mx.io.NDArrayIter(X, y, batch_size=64)
+    driven = mx.mod.Module(_mlp(), context=ctx)
+    driven.bind(data_shapes=it.provide_data, label_shapes=it.provide_label)
+    driven.init_params(arg_params=start(), aux_params=aux0)
+    driven.init_optimizer(kvstore="tpu", optimizer="sgd",
+                          optimizer_params=opt)
+    profiler.pipeline_reset()
+    for _ in range(2):
+        it.reset()
+        for batch in it:                # host batches, copied by the step
+            driven.forward_backward(batch)
+            driven.update()
+    assert profiler.pipeline_stats()["preplaced"] == 0
+    got, want = fitted.get_params()[0], driven.get_params()[0]
+    assert got.keys() == want.keys()
+    for k in want:
+        np.testing.assert_array_equal(got[k].asnumpy(), want[k].asnumpy(),
+                                      err_msg=k)
+        assert not np.array_equal(want[k].asnumpy(), arg0[k]), k
+
+
+def test_module_fit_leaves_no_feed_thread_and_the_iterator_reset():
+    X, y = _data(n=256)
+    src = _ThreadLoggingIter(mx.io.NDArrayIter(X, y, batch_size=64))
+    _plain_fit(src, num_epoch=3)
+    assert _no_feed_thread()
+    # fit read exactly the batches it trained on: its last reset starts
+    # no read-ahead, so the iterator is left as the bare loop leaves it
+    assert src.pulled == 3 * 4
+    assert not src.closed
+    assert sum(1 for _ in src) == 4
+
+
+def test_module_fit_surfaces_iterator_error_and_stops_the_feed():
+    X, y = _data(n=256)
+    src = _ThreadLoggingIter(mx.io.NDArrayIter(X, y, batch_size=64),
+                             boom_at=6)  # third batch of the second epoch
+    profiler.pipeline_reset()
+    with pytest.raises(ValueError, match="decoder exploded"):
+        _plain_fit(src, num_epoch=3)
+    assert _no_feed_thread()
+    assert not src.closed
+    # every batch read before the fault was trained on
+    assert profiler.pipeline_stats()["steps"] == 6
+
+
+def test_module_fit_twice_on_the_callers_iterator():
+    X, y = _data(n=256, seed=6)
+    src = _ThreadLoggingIter(mx.io.NDArrayIter(X, y, batch_size=64))
+    _plain_fit(src, num_epoch=1)
+    profiler.pipeline_reset()
+    mod = _plain_fit(src, num_epoch=4)
+    assert not src.closed and src.pulled == 5 * 4
+    stats = profiler.pipeline_stats()
+    assert stats["steps"] == 16 and stats["preplaced"] == 32, stats
+    assert _no_feed_thread()
+    acc = dict(mod.score(mx.io.NDArrayIter(X, y, batch_size=64),
+                         mx.metric.Accuracy()))["accuracy"]
+    assert acc > 0.8
+
+
+def test_module_fit_local_kvstore_starts_no_feed_thread(monkeypatch):
+    from mxnet_tpu.parallel import feed as feed_mod
+
+    def refuse(self):
+        raise AssertionError("kvstore='local' started a feed thread")
+
+    monkeypatch.setattr(feed_mod.DeviceQueueIter, "_start", refuse)
+    X, y = _data(n=128)
+    src = _ThreadLoggingIter(mx.io.NDArrayIter(X, y, batch_size=64))
+    profiler.pipeline_reset()
+    mod = _plain_fit(src, kvstore="local", num_epoch=1,
+                     contexts=[mx.cpu(0)])
+    assert mod._fused is None
+    assert set(src.threads) == {threading.current_thread().name}
+    assert profiler.pipeline_stats().get("preplaced", 0) == 0
+
+
+def test_module_fit_does_not_wrap_a_queue_twice(monkeypatch):
+    X, y = _data(n=256)
+    mod = mx.mod.Module(_mlp(), context=[mx.cpu(i) for i in range(8)])
+    dq = DeviceQueueIter(mx.io.NDArrayIter(X, y, batch_size=64), module=mod)
+    built = []
+    real_init = DeviceQueueIter.__init__
+
+    def counting_init(self, *args, **kwargs):
+        built.append(self)
+        real_init(self, *args, **kwargs)
+
+    monkeypatch.setattr(DeviceQueueIter, "__init__", counting_init)
+    profiler.pipeline_reset()
+    mod.fit(dq, num_epoch=2, kvstore="tpu", optimizer="sgd",
+            optimizer_params={"learning_rate": 0.1},
+            initializer=mx.initializer.Xavier())
+    assert built == []
+    stats = profiler.pipeline_stats()
+    assert stats["puts"] == 16 and stats["preplaced"] == 16, stats
+    # the caller's queue is the caller's to close
+    assert not dq._closed
+    assert sum(1 for _ in dq) == 4
+    dq.close()
+    assert _no_feed_thread()
+
+
 def test_feedforward_fit_uses_pipeline():
-    """model.FeedForward.fit auto-wraps the feed for fused kvstores."""
+    """model.FeedForward.fit trains through the queue Module.fit puts in."""
     X, y = _data(n=256, seed=4)
     ff = mx.model.FeedForward(_mlp(), ctx=[mx.cpu(i) for i in range(4)],
                               num_epoch=3, learning_rate=0.1,
@@ -412,8 +603,7 @@ def test_feedforward_fit_uses_pipeline():
     ff.fit(X, y, kvstore="tpu")
     stats = profiler.pipeline_stats()
     assert stats.get("preplaced", 0) > 0, stats  # pipeline engaged
-    assert not any(t.name == "DeviceQueueIter" and t.is_alive()
-                   for t in threading.enumerate())  # closed after fit
+    assert _no_feed_thread()  # closed after fit
 
 
 def test_feedforward_refit_keeps_user_iterator_usable():

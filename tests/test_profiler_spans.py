@@ -130,12 +130,16 @@ def _mlp():
     return mx.sym.SoftmaxOutput(net, name="softmax")
 
 
-def _fit_two_batches(eval_metric, callback):
+def _two_batches():
     rng = np.random.RandomState(0)
     X = rng.randn(128, 8).astype(np.float32)
     y = rng.randint(0, 4, 128).astype(np.float32)
     it = mx.io.NDArrayIter(X, y, batch_size=64, shuffle=False)
-    mod = mx.mod.Module(_mlp(), context=[mx.cpu(i) for i in range(8)])
+    return it, mx.mod.Module(_mlp(), context=[mx.cpu(i) for i in range(8)])
+
+
+def _fit_two_batches(eval_metric, callback):
+    it, mod = _two_batches()
     mod.fit(it, num_epoch=1, kvstore="tpu", optimizer="sgd",
             optimizer_params={"learning_rate": 0.1}, eval_metric=eval_metric,
             initializer=mx.initializer.Xavier(),
@@ -156,20 +160,34 @@ def test_fit_spans_nest_in_order_on_the_device_metrics_path():
                                             {"epoch": 0, "nbatch": 1}]
     order = ["mx.fit.forward_backward", "mx.fit.update", "mx.fit.next_batch",
              "mx.fit.update_metric", "mx.fit.callbacks"]
+    main = threading.get_ident()
     for b in batches:
         children = [next(e for e in by[name] if _inside(e, b))
                     for name in order]
         starts = [c["ts"] for c in children]
         assert starts == sorted(starts)
-        fb, callbacks = children[0], children[-1]
-        h2d = [e for e in by["mx.fit.h2d"] if _inside(e, fb)]
-        assert sorted(e["args"]["name"] for e in h2d) == ["data",
-                                                         "softmax_label"]
-        assert all(e["args"]["nbytes"] > 0 for e in h2d)
+        fb, next_batch, callbacks = children[0], children[2], children[-1]
+        # fit feeds the fused step through the device queue (ISSUE 27): the
+        # step copies nothing, and the loop waits for the worker's batch
+        assert not any(_inside(e, fb) for e in by["mx.fit.h2d"])
         dispatch = [e for e in by["mx.fit.dispatch"] if _inside(e, fb)]
         assert len(dispatch) == 1
-        assert min(e["ts"] for e in h2d) <= dispatch[0]["ts"]
+        waits = [e for e in by["mx.fit.feed_wait"] if _inside(e, next_batch)]
+        assert len(waits) == 1
         assert any(_inside(e, callbacks) for e in by["mx.metric.drain"])
+    # the copies are the worker's, one thread, two arrays a batch, each
+    # batch's before the dispatch of the step that takes it
+    h2d = sorted(by["mx.fit.h2d"], key=lambda e: e["ts"])
+    assert len({e["tid"] for e in h2d}) == 1 and h2d[0]["tid"] != main
+    assert [e["args"]["name"] for e in h2d] == ["data", "softmax_label"] * 2
+    assert all(e["args"]["nbytes"] > 0 for e in h2d)
+    dispatches = sorted(by["mx.fit.dispatch"], key=lambda e: e["ts"])
+    for i, d in enumerate(dispatches):
+        assert h2d[2 * i + 1]["ts"] + h2d[2 * i + 1]["dur"] <= d["ts"]
+    # the epoch's first next() waits before the first batch's span opens,
+    # the last one finds the end of the epoch
+    assert len(by["mx.fit.feed_wait"]) == 3
+    assert {e["tid"] for e in by["mx.fit.feed_wait"]} == {main}
     steps = sorted(e["args"]["step"] for e in by["mx.fit.dispatch"])
     assert steps == [steps[0], steps[0] + 1]
     assert by["mx.fit.epoch_end"][0]["args"] == {"epoch": 0}
@@ -181,6 +199,38 @@ def test_fit_spans_nest_in_order_on_the_device_metrics_path():
     assert pipe["sync_seconds"] > 0
     drains = sum(e["dur"] for e in by["mx.metric.drain"]) / 1e6
     assert pipe["sync_seconds"] == pytest.approx(drains, abs=0.05)
+
+
+def test_explicit_forward_backward_on_host_batches_copies_in_place():
+    """Outside ``fit`` the step still slices and copies the host batch it
+    is given, on the caller's thread, before it dispatches."""
+    it, mod = _two_batches()
+    mod.bind(data_shapes=it.provide_data, label_shapes=it.provide_label)
+    mod.init_params(initializer=mx.initializer.Xavier())
+    mod.init_optimizer(kvstore="tpu", optimizer="sgd",
+                       optimizer_params={"learning_rate": 0.1})
+    assert mod._fused is not None
+
+    def drive():
+        for batch in it:
+            with profiler.span("mx.fit.forward_backward"):
+                mod.forward_backward(batch)
+            mod.update()
+
+    events = _recorded(drive)
+    by = {}
+    for e in events:
+        by.setdefault(e["name"], []).append(e)
+    assert len(by["mx.fit.forward_backward"]) == 2
+    for fb in by["mx.fit.forward_backward"]:
+        h2d = [e for e in by["mx.fit.h2d"] if _inside(e, fb)]
+        assert sorted(e["args"]["name"] for e in h2d) == ["data",
+                                                         "softmax_label"]
+        dispatch = [e for e in by["mx.fit.dispatch"] if _inside(e, fb)]
+        assert len(dispatch) == 1
+        assert max(e["ts"] + e["dur"] for e in h2d) <= dispatch[0]["ts"]
+    assert "mx.fit.feed_wait" not in by
+    assert profiler.pipeline_stats()["preplaced"] == 0
 
 
 def test_fit_host_fallback_path_blocks_in_host_sync_every_batch():
