@@ -344,6 +344,54 @@ def phase_transformer(sz, devices, on_tpu):
 # ---------------------------------------------------------------------------
 # phase 3: the server answers a few requests
 # ---------------------------------------------------------------------------
+def _paged_decode_parity(cfg, slots, page_size, device):
+    """kernels.paged_decode against _paged_decode_attention over the
+    gathered pages, at the server's own geometry on ``device``: ragged
+    lengths (one slot inactive, one ending on a page boundary, one
+    starting a page, one full), block tables in shuffled page order."""
+    import jax
+    import jax.numpy as jnp
+
+    from mxnet_tpu.kernels.paged_decode import paged_decode_attention
+    from mxnet_tpu.models import transformer as tfm
+
+    H, hd = cfg.n_heads, cfg.d_model
+    pages_per_slot = cfg.max_len // page_size
+    num_pages = slots * pages_per_slot
+    rng = np.random.RandomState(3)
+    tables = (rng.permutation(num_pages) + 1).reshape(slots, pages_per_slot)
+    lengths = rng.randint(1, cfg.max_len + 1, (slots,))
+    lengths[:4] = (0, 3 * page_size, 3 * page_size + 1, cfg.max_len)
+    with jax.default_device(device):
+        kq, kp = jax.random.split(jax.random.PRNGKey(11))
+        pool = jax.random.normal(
+            kp, (2, 2, num_pages + 1, page_size, hd), jnp.float32
+        ).astype(jnp.bfloat16)
+        q = jax.random.normal(kq, (slots, hd), jnp.float32).astype(jnp.bfloat16)
+        tables, lengths = jnp.asarray(tables, jnp.int32), jnp.asarray(lengths, jnp.int32)
+
+        def ref(q, pool, tables, lengths):
+            kg, vg = tfm._gather_pages(pool[1], tables, H)
+            return tfm._paged_decode_attention(
+                q.reshape(slots, H, 1, -1), kg, vg, lengths - 1, 128
+            ).reshape(slots, hd)
+
+        got = jax.jit(lambda *a: paged_decode_attention(
+            a[0], a[1], jnp.int32(1), a[2], a[3], n_heads=H))(
+                q, pool, tables, lengths)
+        with jax.default_matmul_precision("highest"):
+            want = jax.jit(ref)(q, pool, tables, lengths)
+    got, want = (np.asarray(a, np.float32) for a in (got, want))
+    if np.any(got[0] != 0):
+        raise AssertionError("paged decode: an inactive slot's output is not zero")
+    err = float(np.max(np.abs(got[1:] - want[1:])) / np.max(np.abs(want[1:])))
+    if not np.isfinite(err) or err > FLASH_TOL:
+        raise AssertionError(
+            "paged decode differs from the gathered reference by %.3e of "
+            "its max (tolerance %.0e)" % (err, FLASH_TOL))
+    return float("%.2e" % err)
+
+
 def phase_server(sz, devices):
     import jax
 
@@ -389,23 +437,42 @@ def phase_server(sz, devices):
         pages = pred.pool.alloc(pred.pages_needed(len(prompt)))
         try:
             got = pred.prefill(prompt, pages)
+            # and one decode step through the same cache: the prompt's last
+            # token again at its own position, so the step rewrites the row
+            # prefill wrote and must give the same logits
+            table = np.zeros((pred.slots, pred.max_pages_per_slot), np.int32)
+            table[0, :len(pages)] = pages
+            feed = np.zeros((pred.slots,), np.int32)
+            feed[0] = prompt[-1]
+            at = np.zeros((pred.slots,), np.int32)
+            at[0] = len(prompt) - 1
+            stepped = pred.decode(feed, at, table,
+                                  np.arange(pred.slots) == 0)[0]
         finally:
             pred.pool.free(pages)
         # the reference runs unsharded on the group's first device
         want = np.asarray(tfm.make_forward_fn(cfg)(
             params, prompt[None, :]))[0, -1]
-        err = float(np.max(np.abs(got - want)) / np.max(np.abs(want)))
-        if not np.isfinite(err) or err > LOGITS_TOL:
-            raise AssertionError(
-                "prefill logits differ from make_forward_fn by %.3e of the "
-                "largest logit (tolerance %.0e)" % (err, LOGITS_TOL))
+        errs = {}
+        for name, logits in (("prefill", got), ("decode", stepped)):
+            errs[name] = float(np.max(np.abs(logits - want))
+                               / np.max(np.abs(want)))
+            if not np.isfinite(errs[name]) or errs[name] > LOGITS_TOL:
+                raise AssertionError(
+                    "%s logits differ from make_forward_fn by %.3e of the "
+                    "largest logit (tolerance %.0e)"
+                    % (name, errs[name], LOGITS_TOL))
         sharded = pred.sharded_stats() if len(group) > 1 else None
+        decode_parity = _paged_decode_parity(cfg, sz["slots"],
+                                             sz["page_size"], group[0])
         if srv.stats()["pages_in_use"] != 0:
             raise AssertionError("parity prefill leaked KV pages")
     return {"group": [str(d) for d in group], "buckets": buckets,
             "requests": len(results), "tokens_each": sz["new_tokens"],
             "ttft_s": [round(r["ttft_s"], 3) for r in results],
-            "prefill_logits_rel_err": float("%.2e" % err),
+            "prefill_logits_rel_err": float("%.2e" % errs["prefill"]),
+            "decode_logits_rel_err": float("%.2e" % errs["decode"]),
+            "paged_decode_rel_err": decode_parity,
             "logits_tol": LOGITS_TOL, "donated_kv": pred._donate,
             "sharded": sharded, "memory": memory}
 

@@ -7,5 +7,6 @@ CPU mesh (SURVEY §4 test strategy); on TPU backends the compiled Mosaic
 kernel runs.
 """
 from .flash_attention import flash_attention
+from .paged_decode import paged_decode_attention
 
-__all__ = ["flash_attention"]
+__all__ = ["flash_attention", "paged_decode_attention"]

@@ -456,12 +456,16 @@ def block_collective_counts(config, mesh, data_axes=("dp",)):
 
 
 def kv_cache_spec(mesh):
-    """PartitionSpec of the paged KV cache (L, 2, P+1, page, H, Dh)
-    on an mp mesh: heads sharded over the tensor-parallel axis — each
-    chip holds 1/mp of every page (the sharded-serving-group memory
-    claim). Replicated when the mesh has no mp/tp axis."""
+    """PartitionSpec of the paged KV cache (L, 2, P+1, page, H*Dh) on an
+    mp mesh: the head-major lane axis sharded over the tensor-parallel
+    axis, so each chip holds the lanes of its own H/mp heads of every page
+    (the sharded-serving-group memory claim). Replicated when the mesh has
+    no mp/tp axis."""
     t = _mp_axis(set(mesh.axis_names))
-    return P(None, None, None, None, t, None)
+    return P(None, None, None, None, t)
+
+
+# ---------------------------------------------------------------------------
 # PAGED per-layer KV cache. The serving tier (serving/generate.py) owns
 # page allocation and batch-slot bookkeeping; the functions here are the
 # pure compiled programs:
@@ -469,26 +473,47 @@ def kv_cache_spec(mesh):
 # - ``make_forward_fn``      one-shot logits (B, S, V) on a single
 #                            device — the numerical reference the decode
 #                            path must match per token.
-# - ``init_kv_cache``        the cache buffer: (L, 2, P, page, H, Dh).
-#                            Page 0 is the SCRATCH page — never handed
-#                            out by the allocator; inactive slots and
-#                            padded prompt tail positions write there.
+# - ``init_kv_cache``        the cache buffer: (L, 2, P, page, H*Dh). A
+#                            page of one layer is one contiguous
+#                            (page, H*Dh) slab holding every head: the
+#                            two minor dims fill the TPU's (16, 128)
+#                            bfloat16 tiles whatever the head dim, so the
+#                            device keeps the array in this order (with
+#                            (..., H, Dh) and Dh = 64 it made the page
+#                            index the minor dim and every access a
+#                            relayout). Page 0 is the SCRATCH page —
+#                            never handed out by the allocator; inactive
+#                            slots and padded prompt tail positions write
+#                            there.
 # - ``make_prefill_fn``      causal forward over one padded prompt that
 #                            scatters every position's K/V into its
 #                            page (block-table order) and returns the
 #                            last valid position's logits — the first
 #                            generated token comes out of prefill.
-# - ``make_decode_fn``       one token per active batch slot: write the
-#                            token's K/V at (page, offset) derived from
-#                            its position, then attend over the pages
-#                            named by the slot's block table with a
-#                            flash-style blocked online softmax whose
-#                            ``block_k`` is consulted from the PR 10
-#                            schedule table at trace time (decode-shape
-#                            key: seq_q == 1, causal == 0 — the decode
-#                            query attends to ALL cached keys, masked
-#                            by length, not by the kernel's causal
+# - ``make_decode_fn``       one token per active batch slot. The layer
+#                            loop CARRIES the whole pool: each layer
+#                            writes the token's K/V row in place at
+#                            [layer, kv, page, offset] derived from its
+#                            position, then attends the slot's pages. On
+#                            a TPU that is the Pallas kernel
+#                            ``kernels/paged_decode.py``: it walks the
+#                            block table, copies each page from the pool
+#                            where it lies and stops after the page that
+#                            holds the slot's position, so a step reads
+#                            the valid keys and values and nothing else.
+#                            The CPU test path gathers the slot's pages
+#                            and runs ``_paged_decode_attention``, the
+#                            same online softmax in lax and the kernel's
+#                            reference in the tests. ``block_k`` (key
+#                            columns per softmax turn) is consulted from
+#                            the PR 10 schedule table at trace time
+#                            (decode-shape key: seq_q == 1, causal == 0 —
+#                            the decode query attends to ALL cached keys,
+#                            masked by length, not by the kernel's causal
 #                            row>=col rule).
+# - ``make_extend_fn``       several tokens per slot (prefix-tail
+#                            prefill, speculative verify): still scans
+#                            the pool and gathers the slot's pages.
 #
 # The attention math mirrors kernels/flash_attention.py's online
 # softmax (running max / denominator / unnormalized accumulator, fp32),
@@ -509,14 +534,15 @@ def make_forward_fn(config):
 
 def init_kv_cache(config, num_pages, page_size, dtype=None):
     """Zeroed paged KV cache (n_layers, 2, num_pages + 1, page_size,
-    n_heads, head_dim) in the compute dtype. Index 0 on the page axis
+    n_heads * head_dim) in the compute dtype, head h in lanes
+    ``[h * head_dim, (h + 1) * head_dim)``. Index 0 on the page axis
     is the scratch page (see module comment); callers allocate real
     page ids from 1..num_pages."""
     c = config
     cdt = jnp.dtype(dtype if dtype is not None else c.dtype)
     dh = c.d_model // c.n_heads
     return jnp.zeros((c.n_layers, 2, int(num_pages) + 1, int(page_size),
-                      c.n_heads, dh), cdt)
+                      c.n_heads * dh), cdt)
 
 
 def decode_schedule_shape(config, slots, max_ctx):
@@ -686,10 +712,8 @@ def make_prefill_fn(config, page_size, mesh=None):
                              lp["attn_qkv_weight"].astype(cdt))
             q, k, v = qkv[0], qkv[1], qkv[2]
             # scatter K/V into this layer's pages: (1,H,S,Dh) → page grid
-            kp = k[0].transpose(1, 0, 2).reshape(
-                n_pages, page_size, c.n_heads, -1)
-            vp = v[0].transpose(1, 0, 2).reshape(
-                n_pages, page_size, c.n_heads, -1)
+            kp = k[0].transpose(1, 0, 2).reshape(n_pages, page_size, -1)
+            vp = v[0].transpose(1, 0, 2).reshape(n_pages, page_size, -1)
             with jax.named_scope("mx.gen.pool_write"):
                 cl = cl.at[0, pages].set(kp.astype(cl.dtype))
                 cl = cl.at[1, pages].set(vp.astype(cl.dtype))
@@ -710,27 +734,80 @@ def make_prefill_fn(config, page_size, mesh=None):
     return prefill
 
 
+def _gather_pages(cl, block_tables, n_heads):
+    """One layer's K and V of every slot, gathered from the layer's slice
+    ``cl`` (2, P+1, page, H*Dh) of the pool in block-table order:
+    (S, MP, page, H*Dh) → two (S, H, MP * page, Dh)."""
+    S = block_tables.shape[0]
+    kg, vg = (cl[kv][block_tables].reshape(S, -1, n_heads,
+                                           cl.shape[-1] // n_heads)
+              .transpose(0, 2, 1, 3) for kv in (0, 1))
+    return kg, vg
+
+
+def _decode_attend(config, block_k, mesh):
+    """attend(q (S, H*Dh), cache, layer, block_tables, lengths) → (S, H*Dh)
+    over columns ``< lengths[b]``, chosen from what the code can see: on a
+    TPU the Pallas kernel over the pool in place (under ``shard_map`` over
+    the heads an mp axis shards: a Mosaic kernel cannot be partitioned
+    automatically); on the CPU test path the gathered pages through
+    ``_paged_decode_attention``. ``kernel_platform()`` raises on any other
+    backend."""
+    c = config
+    t = _mp_axis(set(mesh.axis_names)) if mesh is not None else None
+
+    if kernel_platform() == "tpu":
+        from ..kernels.paged_decode import paged_decode_attention
+
+        attend = functools.partial(
+            paged_decode_attention, block_k=block_k,
+            n_heads=c.n_heads // (mesh.shape[t] if t else 1))
+        if t:
+            lanes = P(None, t)
+            attend = _shard_map(
+                attend, mesh=mesh,
+                in_specs=(lanes, kv_cache_spec(mesh), P(), P(), P()),
+                out_specs=lanes, check_vma=False)
+        return attend
+
+    def attend(q, cache, layer, block_tables, lengths):
+        S = q.shape[0]
+        with jax.named_scope("mx.gen.gather_kv"):
+            kg, vg = _gather_pages(cache[layer], block_tables, c.n_heads)
+        o = _paged_decode_attention(q.reshape(S, c.n_heads, 1, -1), kg, vg,
+                                    lengths - 1, block_k)
+        return o.reshape(S, -1)
+
+    return attend
+
+
 def make_decode_fn(config, slots, max_pages_per_slot, page_size,
-                   block_k=None):
+                   block_k=None, mesh=None):
     """fn(params, cache, tokens (S,) int32, positions (S,) int32,
     block_tables (S, max_pages_per_slot) int32, active (S,) bool) →
     (cache', logits (S, V) fp32).
 
     One decode step for ``slots`` batch slots: embed token b at
-    ``positions[b]``, write its per-layer K/V at page
-    ``block_tables[b, positions[b] // page_size]`` offset
-    ``positions[b] % page_size``, attend over the slot's gathered pages
-    (columns <= position), and emit next-token logits. Inactive slots
-    compute too (the batch shape is static) but their writes are routed
-    to the scratch page and their logits zeroed. ``block_k`` defaults
-    to the schedule-table consult at the decode shape
-    (:func:`decode_schedule_shape`)."""
+    ``positions[b]``, write its per-layer K/V row in place at
+    ``cache[layer, kv, block_tables[b, positions[b] // page_size],
+    positions[b] % page_size]``, attend the slot's pages (columns <=
+    position) where they lie in the pool, and emit next-token logits. The
+    pool is a carry of the layer loop, never a scanned input: with the
+    predictor's donation the program holds one pool and touches the rows
+    it writes and the pages it reads. Inactive slots compute too (the
+    batch shape is static) but their writes are routed to the scratch
+    page, they attend nothing and their logits are zeroed. ``block_k``
+    (key columns per online-softmax turn) defaults to the schedule-table
+    consult at the decode shape (:func:`decode_schedule_shape`); ``mesh``
+    is the replica group's mesh of a sharded bind (see
+    :func:`_decode_attend` for what runs where)."""
     c = config
     cdt = jnp.dtype(c.dtype)
     page_size = int(page_size)
     max_ctx = int(max_pages_per_slot) * page_size
     if block_k is None:
         block_k = _decode_block_k(c, slots, max_ctx)
+    attend = _decode_attend(c, block_k, mesh)
 
     def decode(params, cache, tokens, positions, block_tables, active):
         S = tokens.shape[0]
@@ -748,32 +825,30 @@ def make_decode_fn(config, slots, max_pages_per_slot, page_size,
                                    axis=1)[:, 0]
         # inactive slots (and any unset table entry) write to scratch
         page = jnp.where(active, page, 0)
+        lengths = jnp.where(active, positions + 1, 0)
 
-        def layer(x, xs):
-            lp, cl = xs
+        def layer(carry, xs):
+            x, cache = carry
+            lp, i = xs
             h = _layernorm(x, lp["ln1_gamma"], lp["ln1_beta"])
             qkv = jnp.einsum("bsd,dthe->tbhse", h,
                              lp["attn_qkv_weight"].astype(cdt))
-            q, k, v = qkv[0], qkv[1], qkv[2]          # (S, H, 1, Dh)
+            q, k, v = (a.reshape(S, -1) for a in qkv)  # (S, H*Dh), head-major
             with jax.named_scope("mx.gen.pool_write"):
-                cl = cl.at[0, page, offset].set(
-                    k[:, :, 0, :].astype(cl.dtype))
-                cl = cl.at[1, page, offset].set(
-                    v[:, :, 0, :].astype(cl.dtype))
-            # paged gather: (S, MP, page, H, Dh) → (S, H, L, Dh)
-            with jax.named_scope("mx.gen.gather_kv"):
-                kg = cl[0][block_tables].reshape(
-                    S, max_ctx, c.n_heads, -1).transpose(0, 2, 1, 3)
-                vg = cl[1][block_tables].reshape(
-                    S, max_ctx, c.n_heads, -1).transpose(0, 2, 1, 3)
+                cache = cache.at[i, 0, page, offset].set(
+                    k.astype(cache.dtype))
+                cache = cache.at[i, 1, page, offset].set(
+                    v.astype(cache.dtype))
             with jax.named_scope("mx.gen.attn"):
-                o = _paged_decode_attention(q.astype(cdt), kg, vg,
-                                            positions, block_k)
-            o = jnp.einsum("bhse,hed->bsd", o,
+                o = attend(q.astype(cdt), cache, i, block_tables, lengths)
+            o = jnp.einsum("bhse,hed->bsd",
+                           o.reshape(S, c.n_heads, 1, -1),
                            lp["attn_out_weight"].astype(cdt))
-            return _ffn(x + o, lp, c, frozenset(), cdt), cl
+            return (_ffn(x + o, lp, c, frozenset(), cdt), cache), None
 
-        x, cache = lax.scan(layer, x, (_stacked_layer_params(params), cache))
+        (x, cache), _ = lax.scan(
+            layer, (x, cache),
+            (_stacked_layer_params(params), jnp.arange(c.n_layers)))
         x = _layernorm(x, params["final_ln_gamma"], params["final_ln_beta"])
         logits = jnp.einsum("bsd,vd->bsv", x,
                             params["embed_weight"].astype(cdt))[:, 0]
@@ -847,14 +922,11 @@ def make_extend_fn(config, slots, steps, max_pages_per_slot, page_size,
             q, k, v = qkv[0], qkv[1], qkv[2]          # (S, H, T, Dh)
             with jax.named_scope("mx.gen.pool_write"):
                 cl = cl.at[0, page, offset].set(
-                    k.transpose(0, 2, 1, 3).astype(cl.dtype))
+                    k.transpose(0, 2, 1, 3).reshape(S, T, -1).astype(cl.dtype))
                 cl = cl.at[1, page, offset].set(
-                    v.transpose(0, 2, 1, 3).astype(cl.dtype))
+                    v.transpose(0, 2, 1, 3).reshape(S, T, -1).astype(cl.dtype))
             with jax.named_scope("mx.gen.gather_kv"):
-                kg = cl[0][block_tables].reshape(
-                    S, max_ctx, c.n_heads, -1).transpose(0, 2, 1, 3)
-                vg = cl[1][block_tables].reshape(
-                    S, max_ctx, c.n_heads, -1).transpose(0, 2, 1, 3)
+                kg, vg = _gather_pages(cl, block_tables, c.n_heads)
             with jax.named_scope("mx.gen.attn"):
                 o = _paged_extend_attention(q.astype(cdt), kg, vg,
                                             positions, block_k)

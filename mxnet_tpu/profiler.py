@@ -59,8 +59,10 @@ Names are fixed strings ``mx.<layer>.<what>``; arguments carry identity
 Inside the compiled programs the same naming is ``jax.named_scope`` metadata
 (``mx.lm.embed``, ``mx.lm.attn``, ``mx.lm.ffn``, ``mx.lm.head_loss``,
 ``mx.opt.update``, ``mx.step.forward``, ``mx.step.backward``,
-``mx.gen.gather_kv``, ``mx.gen.attn``, ``mx.gen.pool_write``) and the flash
-kernels' own names (``mx_flash_fwd``, ``mx_flash_dq``, ``mx_flash_dkv``).
+``mx.gen.pool_write``, ``mx.gen.attn``, and ``mx.gen.gather_kv`` where a
+program still gathers a slot's pages: extend, and decode off the TPU) and the
+kernels' own names (``mx_flash_fwd``, ``mx_flash_dq``, ``mx_flash_dkv``,
+``mx_paged_decode``).
 """
 from __future__ import annotations
 
@@ -704,6 +706,12 @@ _GEN_ZERO = {
     # decode steps that had at least one prefill since the step before:
     # how often a gap between two tokens holds a prefill
     "decode_steps_after_prefill": 0,
+    # KV pages of the pool, over all decode steps: ``read`` holds a valid
+    # column of an active slot (ceil((position + 1) / page_size), what the
+    # paged decode kernel copies a layer), ``spanned`` is slots x pages per
+    # slot (what a gather of every block table reads); their ratio rides
+    # generate_stats as decode_kv_read_share
+    "decode_kv_pages_read": 0, "decode_kv_pages_spanned": 0,
     # shared-prefix KV cache (ISSUE 16): admissions that matched a
     # cached prefix, pages borrowed copy-on-write, prompt tokens whose
     # prefill was skipped, and least-recently-matched evictions
@@ -804,6 +812,9 @@ def generate_stats(reset=False):
             - snap["decode_seconds"]) / snap["decode_steps"] * 1e3
         snap["decode_after_prefill_share"] = (
             snap["decode_steps_after_prefill"] / snap["decode_steps"])
+    if snap["decode_kv_pages_spanned"]:
+        snap["decode_kv_read_share"] = (
+            snap["decode_kv_pages_read"] / snap["decode_kv_pages_spanned"])
     return snap
 
 

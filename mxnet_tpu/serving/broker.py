@@ -1040,8 +1040,11 @@ class GenerateServer:
                 logits = pred.decode(self._tokens, self._positions,
                                      self._block_tables, self._active)
             self._positions[active] += 1
-            self._step_counts(len(active), len(active),
-                              time.perf_counter() - t0)
+            self._step_counts(
+                len(active), len(active), time.perf_counter() - t0,
+                decode_kv_pages_read=int(np.sum(
+                    -(-self._positions[active] // pred.page_size))),
+                decode_kv_pages_spanned=pred.slots * pred.max_pages_per_slot)
             with profiler.span("mx.serve.decode.sample"):
                 for slot in active:
                     r = self._slot_req[slot]

@@ -18,9 +18,12 @@ them from ``serving/broker.py``:
   decode: a ladder of prefill programs (prompt padded to page-aligned
   power-of-two buckets, the PR 6 ladder idea) that fill per-layer K/V
   pages, plus ONE decode program (``slots`` queries, 1 token each)
-  that attends against the pages named by each slot's block table.
+  that writes each token's K/V row into the pool in place and attends
+  the pages named by each slot's block table where they lie (on a TPU
+  the ``kernels/paged_decode.py`` kernel; see ``models/transformer.py``).
   The big cache buffer is donated to every call on accelerators (the
-  PR 6 donation rule: skipped on CPU where it only warns), compiled
+  PR 6 donation rule: skipped on CPU where it only warns), so the
+  decode program holds one pool; compiled
   programs share the serving tier's :class:`ExecutableCache`, and the
   decode attention's ``block_k`` is consulted from the PR 10 schedule
   table at trace time (``tools/tune_kernels.py`` sweeps the
@@ -382,7 +385,8 @@ class GenerativePredictor:
         Per-slot context bound (prompt + generated), default
         ``config.max_len``; rounded down to a whole page count.
     block_k : int, optional
-        Decode attention chunk override; default consults the schedule
+        Decode attention's key columns per online-softmax turn (whole
+        pages in the TPU kernel); default consults the schedule
         table at :func:`models.transformer.decode_schedule_shape`.
     cache : ExecutableCache, optional
         Shared compiled-program LRU (the serving tier's); private
@@ -391,10 +395,11 @@ class GenerativePredictor:
         Bind the model SHARDED across a replica group (ISSUE 20):
         weights placed per ``models.transformer.param_specs`` (megatron
         column/row over the mesh's ``mp``/``tp`` axis) and the paged KV
-        cache sharded over its heads axis (``kv_cache_spec``) so every
-        chip holds 1/mp of every page. Mutually exclusive with
-        ``device``; the pure-jnp prefill/decode/extend programs are
-        GSPMD-partitioned automatically.
+        cache sharded over its head-major lane axis (``kv_cache_spec``)
+        so every chip holds 1/mp of every page. Mutually exclusive with
+        ``device``; the programs are GSPMD-partitioned automatically,
+        their Pallas kernels (prefill's flash, decode's paged attention)
+        under ``shard_map`` over the heads each chip holds.
     """
 
     def __init__(self, config_, params, *, slots=None, page_size=None,
@@ -541,7 +546,7 @@ class GenerativePredictor:
         return self._exec_cache.get_or_build(
             key, lambda: self._jit(tfm.make_decode_fn(
                 self.config, self.slots, self.max_pages_per_slot,
-                self.page_size, block_k=self.block_k)))
+                self.page_size, block_k=self.block_k, mesh=self._mesh)))
 
     def _extend_exec(self, batch, steps):
         from ..models import transformer as tfm

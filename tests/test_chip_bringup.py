@@ -243,6 +243,47 @@ def test_sharded_prefill_cross_lowers_for_tpu(monkeypatch):
             lowering_platforms=("tpu",))
 
 
+def test_sharded_decode_cross_lowers_for_tpu(monkeypatch):
+    """The decode program's paged kernel under an mp mesh: ``shard_map``
+    over the lanes of the heads each chip holds, as prefill does for
+    flash; without the mesh the TPU lowering refuses to partition it."""
+    import jax
+    import jax.numpy as jnp
+    from jax.sharding import NamedSharding
+
+    import mxnet_tpu.kernels  # noqa: F401  (loads kernels.flash_attention)
+    from mxnet_tpu.models import transformer as tfm
+    from mxnet_tpu.parallel.mesh import train_mesh
+
+    for name in ("mxnet_tpu.models.transformer",
+                 "mxnet_tpu.kernels.flash_attention"):
+        monkeypatch.setattr(sys.modules[name], "kernel_platform",
+                            lambda: "tpu")
+    cfg = tfm.TransformerConfig(vocab=256, d_model=128, n_heads=4,
+                                n_layers=2, d_ff=256, max_len=64)
+    mesh = train_mesh(devices=jax.devices()[:2], mp=2)
+    specs = tfm.param_specs(cfg, mesh)
+    params = {k: jax.ShapeDtypeStruct(v.shape, v.dtype,
+                                      sharding=NamedSharding(mesh, specs[k]))
+              for k, v in jax.eval_shape(
+                  lambda: tfm.init_params(cfg)).items()}
+    cache = jax.eval_shape(lambda: tfm.init_kv_cache(cfg, 8, 16))
+    cache = jax.ShapeDtypeStruct(
+        cache.shape, cache.dtype,
+        sharding=NamedSharding(mesh, tfm.kv_cache_spec(mesh)))
+    args = (params, cache, jax.ShapeDtypeStruct((2,), jnp.int32),
+            jax.ShapeDtypeStruct((2,), jnp.int32),
+            jax.ShapeDtypeStruct((2, 4), jnp.int32),
+            jax.ShapeDtypeStruct((2,), jnp.bool_))
+    lowered = jax.jit(tfm.make_decode_fn(cfg, 2, 4, 16, mesh=mesh)).trace(
+        *args).lower(lowering_platforms=("tpu",))
+    text = lowered.as_text()
+    assert "tpu_custom_call" in text and "mx_paged_decode" in text
+    with pytest.raises(NotImplementedError, match="shard_map"):
+        jax.jit(tfm.make_decode_fn(cfg, 2, 4, 16)).trace(*args).lower(
+            lowering_platforms=("tpu",))
+
+
 @pytest.mark.slow
 def test_chip_smoke_dry_run_cpu_end_to_end():
     proc = subprocess.run([sys.executable, SMOKE, "--dry-run-cpu"],

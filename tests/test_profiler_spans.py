@@ -375,6 +375,25 @@ def test_generate_programs_carry_the_scope_names(model):
         assert scope in text, scope
 
 
+def test_decode_through_the_kernel_names_it_and_gathers_nothing(model, monkeypatch):
+    """On a TPU the decode program attends the pool in place: the scope
+    ``mx.gen.gather_kv`` stays only where a gather does."""
+    import jax
+    import jax.numpy as jnp
+
+    monkeypatch.setattr(tfm, "kernel_platform", lambda: "tpu")
+    cfg, params = model
+    cache = tfm.init_kv_cache(cfg, num_pages=8, page_size=8)
+    decode = jax.jit(tfm.make_decode_fn(cfg, 2, 4, 8))
+    text = _op_names(decode.lower(
+        params, cache, jnp.zeros((2,), jnp.int32), jnp.zeros((2,), jnp.int32),
+        jnp.zeros((2, 4), jnp.int32), jnp.ones((2,), bool)))
+    for scope in ("mx.gen.pool_write", "mx.gen.attn", "mx_paged_decode",
+                  "mx.lm.ffn"):
+        assert scope in text, scope
+    assert "mx.gen.gather_kv" not in text
+
+
 def test_symbolic_train_step_tells_forward_backward_and_update_apart():
     import jax
 
