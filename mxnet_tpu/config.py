@@ -342,7 +342,7 @@ KNOBS = {
     "MXNET_IR_PASSES": (
         "fusion", "honored",
         "default pass pipeline for ir.apply_passes(passes=None): a "
-        "comma list of registered pass names (fusion|residual|layout|"
+        "comma list of registered pass names (fusion|residual|"
         "quantize); unknown names raise naming this knob "
         "(ir/passes.py)"),
     "MXNET_IR_FUSE": (
@@ -362,25 +362,11 @@ KNOBS = {
         "max calibration batches the int8 quantization pass consumes "
         "from the provided calibration data; integer >= 1 "
         "(ir/quantize.py)"),
-    # --- training-graph passes (ISSUE 19) ---
-    "MXNET_IR_TRAIN_PASSES": (
-        "", "honored",
-        "default pass pipeline rewriting the TRAINING graph when "
-        "TrainStep(train_passes=None): a comma list of registered "
-        "pass names (fusion|residual|layout), empty = no rewrite; "
-        "unknown names raise (parallel/spmd.py, ir/passes.py)"),
     "MXNET_TPU_REMAT": (
         "0", "honored",
         "default rematerialization mode when TrainStep(remat=None): "
         "0|off = none, 1 = full recompute, conv = save MXU-primitive "
-        "outputs, pass = the per-site IR plan (ir/remat.py) via named "
-        "checkpointing; anything else raises (parallel/spmd.py)"),
-    "MXNET_IR_LAYOUT": (
-        "1", "honored",
-        "kill switch for the whole-graph layout-selection pass: 1 "
-        "runs the transpose compose/sink/cancel rules, 0 makes the "
-        "'layout' pass a no-op (ir/passes.py, ir/layout.py); 0|1, "
-        "anything else raises"),
+        "outputs; anything else raises (parallel/spmd.py)"),
     # --- serving fleet (ISSUE 11) ---
     "MXNET_FLEET_RETRIES": (
         "2", "honored",

@@ -58,8 +58,7 @@ def eval_node(node, ins, key, salt, is_train):
     return out if isinstance(out, tuple) else (out,)
 
 
-def _graph_closure(symbol: Symbol, is_train: bool, placement=None,
-                   remat_names=None):
+def _graph_closure(symbol: Symbol, is_train: bool, placement=None):
     """Build a pure function evaluating the symbol graph.
 
     Returns fn(values: dict[str, jax.Array], key) -> (outputs, aux_updates)
@@ -72,19 +71,11 @@ def _graph_closure(symbol: Symbol, is_train: bool, placement=None,
     the traced program; XLA inserts the cross-device transfers that the
     reference realized as explicit ``_CrossDeviceCopy`` nodes, in both the
     forward and (through the transpose of device_put) the gradient graph.
-
-    ``remat_names`` (ISSUE 19) is the selective-remat save set: outputs
-    of nodes named here are tagged with ``checkpoint_name`` so a
-    ``jax.checkpoint`` under ``save_only_these_names`` keeps exactly
-    them and recomputes everything else in backward (the per-SITE
-    policy ``ir/remat.py`` plans). None/empty builds the tag-free
-    closure — bit-identical to the pre-ISSUE-19 behavior.
     """
     nodes = symbol._topo()
     entries = symbol._entries
     node_ids = {id(n): i for i, n in enumerate(nodes)}
     placement = placement or {}
-    remat_names = frozenset(remat_names or ())
 
     def _place(node, out):
         dev = placement.get(node.attr_dict.get("ctx_group"))
@@ -103,10 +94,6 @@ def _graph_closure(symbol: Symbol, is_train: bool, placement=None,
                 continue
             ins = [results[node_ids[id(inp)]][idx] for inp, idx in node.inputs]
             out = _place(node, eval_node(node, ins, key, i, is_train))
-            if node.name in remat_names:
-                from jax.ad_checkpoint import checkpoint_name
-
-                out = tuple(checkpoint_name(o, node.name) for o in out)
             results[i] = out
             # generic aux-state contract: op declares which outputs
             # replace which aux inputs each training step (fused blocks)

@@ -17,13 +17,6 @@ living as a builder branch, a bespoke predictor split, and nothing.
   C-predict ABI.
 - :mod:`.quantize` — int8 PTQ for the serving path
   (``quantize_for_serving``, ``CalibrationError``).
-- :mod:`.remat` — the selective-rematerialization plan over the
-  TRAINING graph (ISSUE 19): save MXU-op outputs, recompute cheap
-  elementwise tails, lowered per-site via ``checkpoint_name`` +
-  ``save_only_these_names`` in ``TrainStep(remat="pass")``.
-- :mod:`.layout` — whole-graph NCHW<->NHWC layout selection (the
-  ``layout`` pass): transposes sink below layout-oblivious ops and
-  compose/cancel at region boundaries.
 
 Every pass records per-rule hits / nodes rewritten / folded and
 quantized counts plus calibration gauges into
@@ -48,9 +41,6 @@ from .rules import (  # noqa: F401
     registered_kernels,
     residual_rules,
 )
-from .passes import LayoutPass  # noqa: F401
-from .remat import SAVE_OPS, RematPlan, plan_remat, policy_for  # noqa: F401
-from .layout import layout_rules  # noqa: F401
 from .fold import FoldPlan  # noqa: F401
 from .quantize import (  # noqa: F401
     QUANTIZABLE_OPS,

@@ -218,30 +218,6 @@ class RulePass(Pass):
         return symbol, prov
 
 
-class LayoutPass(RulePass):
-    """The ``layout`` rule pass + its transposes-cancelled gauge: the
-    before/after transpose-node delta rides the provenance and
-    ``profiler.pass_stats`` (ISSUE 19 — the whole-graph generalization
-    of the per-unit bracket cancellation)."""
-
-    @staticmethod
-    def _n_transposes(symbol):
-        return sum(1 for n in symbol._topo()
-                   if not n.is_variable() and n.op.name == "transpose")
-
-    def apply(self, symbol):
-        from .. import profiler
-
-        before = self._n_transposes(symbol)
-        symbol, prov = RulePass.apply(self, symbol)
-        cancelled = max(before - self._n_transposes(symbol), 0)
-        prov["transposes_cancelled"] = cancelled
-        if cancelled:
-            profiler.pass_record(self.name,
-                                 transposes_cancelled=cancelled)
-        return symbol, prov
-
-
 # ---------------------------------------------------------------------------
 # registry + pipeline
 # ---------------------------------------------------------------------------
@@ -249,16 +225,6 @@ def _make_fusion():
     from .rules import fusion_rules
 
     return RulePass("fusion", fusion_rules())
-
-
-def _make_layout():
-    from .layout import layout_rules
-
-    if not config.get_strict_bool("MXNET_IR_LAYOUT"):
-        # kill switch: the pass runs with no rules — provenance shows
-        # 0 rewrites, the graph is returned unchanged
-        return LayoutPass("layout", [])
-    return LayoutPass("layout", layout_rules())
 
 
 def _make_residual():
@@ -287,7 +253,6 @@ def _make_quantize(**kwargs):
 PASSES = {
     "fusion": _make_fusion,
     "residual": _make_residual,
-    "layout": _make_layout,
     "quantize": _make_quantize,
 }
 

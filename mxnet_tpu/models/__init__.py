@@ -7,13 +7,11 @@ Module training scripts consume. The Gluon model zoo lives separately in
 long-context flagship) in ``transformer.py``.
 """
 from . import (  # noqa: F401
-    alexnet, bench_transformer, inception, lenet, mlp, mobilenet, resnet,
-    resnext, ssd, vgg,
+    alexnet, inception, lenet, mlp, mobilenet, resnet, resnext, ssd, vgg,
 )
 
 _BUILDERS = {
     "mlp": mlp,
-    "bench-transformer": bench_transformer,
     "lenet": lenet,
     "resnet": resnet,
     "resnext": resnext,

@@ -400,29 +400,10 @@ class TrainStep:
                  data_names=("data",), compute_dtype=None, loss_fn=None,
                  zero=None, remat=None, normalize_grads=True,
                  return_outputs=False, metric_stats=False, zero_wire=None,
-                 zero_min_size=None, sentinel=None, train_passes=None):
+                 zero_min_size=None, sentinel=None):
         from .. import config
         from ..executor import _graph_closure
 
-        # ISSUE 19: training-graph pass pipeline — explicit arg wins,
-        # None consults MXNET_IR_TRAIN_PASSES; names are validated
-        # against the ir.PASSES registry by apply_passes. The rewritten
-        # symbol IS self.symbol: shapes/params/remat plan all follow it.
-        if train_passes is None:
-            raw = config.get("MXNET_IR_TRAIN_PASSES")
-            train_passes = tuple(
-                p.strip() for p in str(raw).split(",") if p.strip())
-        elif isinstance(train_passes, str):
-            train_passes = tuple(
-                p.strip() for p in train_passes.split(",") if p.strip())
-        else:
-            train_passes = tuple(str(p).strip() for p in train_passes
-                                 if str(p).strip())
-        self.train_passes = train_passes
-        if train_passes:
-            from ..ir import apply_passes
-
-            symbol = apply_passes(symbol, list(train_passes))
         self.symbol = symbol
         self.mesh = mesh
         self.data_axes = tuple(data_axes)
@@ -461,17 +442,16 @@ class TrainStep:
         self.data_names = tuple(data_names)
         self.compute_dtype = compute_dtype
         self.loss_fn = loss_fn or cross_entropy_loss
-        # ISSUE 19: remat — explicit arg wins; None consults the
-        # strictly-validated MXNET_TPU_REMAT knob. False/off: no remat;
-        # True: full recompute; "conv": prim-name policy; "pass": the
-        # per-site IR plan (ir/remat.py) via named checkpointing.
+        # remat — explicit arg wins; None consults the strictly-validated
+        # MXNET_TPU_REMAT knob. False/off: no remat; True: full
+        # recompute; "conv": prim-name policy.
         if remat is None:
             raw = config.get_choice("MXNET_TPU_REMAT",
-                                    ("0", "1", "off", "conv", "pass"))
+                                    ("0", "1", "off", "conv"))
             remat = {"0": False, "off": False, "1": True}.get(raw, raw)
-        elif remat not in (False, True, "conv", "pass"):
+        elif remat not in (False, True, "conv"):
             raise MXNetError(
-                "TrainStep: remat=%r must be False|True|'conv'|'pass'"
+                "TrainStep: remat=%r must be False|True|'conv'"
                 % (remat,))
         self.remat = remat
         self.normalize_grads = normalize_grads
@@ -487,19 +467,7 @@ class TrainStep:
             n for n in arg_names if n not in self.data_names and n not in self.label_names
         ]
         self.aux_names = symbol.list_auxiliary_states()
-        # ISSUE 19: remat="pass" plans save/recompute per NODE and the
-        # closure tags each to-save node's outputs with checkpoint_name;
-        # every other mode builds the tag-free closure (bit-identical
-        # graphs to the pre-pass behavior).
-        self._remat_plan = None
-        remat_names = None
-        if self.remat == "pass":
-            from ..ir.remat import plan_remat
-
-            self._remat_plan = plan_remat(symbol)
-            remat_names = frozenset(self._remat_plan.save)
-        self._graph = _graph_closure(symbol, is_train=True,
-                                     remat_names=remat_names)
+        self._graph = _graph_closure(symbol, is_train=True)
         self._step_fn = None
         self._jit_fn = None
 
@@ -751,15 +719,7 @@ class TrainStep:
             # recompute the cheap elementwise tail (BN apply, ReLU, pad)
             # inside backward — on a bandwidth-bound graph this trades
             # spare MXU FLOPs for HBM traffic (ROADMAP S8).
-            # remat="pass": the per-SITE IR plan (ir/remat.py) — saved
-            # node outputs carry checkpoint_name tags from the graph
-            # closure and the policy keeps exactly those names.
-            if self.remat == "pass":
-                from ..ir.remat import policy_for
-
-                loss_of = jax.checkpoint(
-                    loss_of, policy=policy_for(self._remat_plan))
-            elif self.remat == "conv":
+            if self.remat == "conv":
                 def _policy(prim, *_, **__):
                     return prim.name in _SAVEABLE_PRIMS
 
@@ -1044,7 +1004,7 @@ class TrainStep:
         scratch (activations + workspace — the number selective remat
         exists to cut), ``peak_bytes`` adds the non-aliased I/O the
         program holds live. ``flops``/``bytes_accessed`` come from
-        ``cost_analysis`` and feed the pipeline ranker's features."""
+        ``cost_analysis``."""
         if key is None:
             from .. import random as _rnd
 

@@ -832,8 +832,7 @@ def generate_reset():
 # names raise (the fleet_record rule).
 # ---------------------------------------------------------------------------
 _PASS_LOCK = threading.Lock()
-_PASS_COUNTERS = ("hits", "rewritten", "folded", "quantized",
-                  "remat_saved", "remat_recomputed", "transposes_cancelled")
+_PASS_COUNTERS = ("hits", "rewritten", "folded", "quantized")
 _PASS = {}
 _PASS_CALIB = {}
 
@@ -881,9 +880,6 @@ def pass_stats(reset=False):
         passes[name] = {
             "hits": s["hits"], "nodes_rewritten": s["rewritten"],
             "folded_nodes": s["folded"], "quantized_ops": s["quantized"],
-            "remat_saved": s["remat_saved"],
-            "remat_recomputed": s["remat_recomputed"],
-            "transposes_cancelled": s["transposes_cancelled"],
             "rules": s["rules"]}
     out = {"passes": passes}
     if calib:

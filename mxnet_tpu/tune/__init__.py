@@ -58,11 +58,6 @@ from .model import (CostModel, CostModelError, MODEL_VERSION,
                     fit_cost_model, get_model, plan_for)
 from .model import reset as _reset_model
 from .background import BackgroundTuner
-from .pipeline import (HAND_DEFAULT, LAYOUT_CODES, PIPELINE_KERNEL,
-                       REMAT_CODES, build_train_step, candidate_pipelines,
-                       choice_of, graph_fingerprint, pipeline_for,
-                       pipeline_table_shape, schedule_of,
-                       sweep_train_pipelines)
 
 
 def reset():
@@ -107,8 +102,4 @@ __all__ = [
     "BackgroundTuner", "CostModel", "CostModelError", "MODEL_VERSION",
     "default_model_path", "features_from_plan", "fit_cost_model",
     "get_model", "plan_for",
-    "PIPELINE_KERNEL", "HAND_DEFAULT", "REMAT_CODES", "LAYOUT_CODES",
-    "candidate_pipelines", "schedule_of", "choice_of",
-    "graph_fingerprint", "pipeline_table_shape", "build_train_step",
-    "sweep_train_pipelines", "pipeline_for",
 ]

@@ -38,10 +38,7 @@ TABLE_VERSION = 1
 # schedule knobs a record may carry, per kernel family; anything else
 # in a loaded schedule is rejected (the entry falls back to defaults)
 _KNOWN_KNOBS = frozenset(
-    ("row_tile", "chan_block", "batch_fold", "block_q", "block_k",
-     # ISSUE 19 training-pipeline choices ride the same table; values
-     # are small positive codes (tune/pipeline.py REMAT/LAYOUT_CODES)
-     "remat", "layout"))
+    ("row_tile", "chan_block", "batch_fold", "block_q", "block_k"))
 
 
 def default_table_path():

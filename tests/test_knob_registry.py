@@ -90,14 +90,13 @@ def test_autoscale_and_qos_knobs_are_registered():
 
 
 def test_train_pass_knobs_are_registered():
-    """The ISSUE 19 knob surface, by name: the training-graph pass
-    pipeline (remat mode, layout kill switch, pass list) is
-    operator-facing — a rename that forgets the registry entry must
-    fail here, not in a job."""
-    for name in ("MXNET_IR_TRAIN_PASSES", "MXNET_TPU_REMAT",
-                 "MXNET_IR_LAYOUT"):
-        assert name in config.KNOBS, name
-        assert config.KNOBS[name][1] == "honored", name
+    """The remat mode is operator-facing — a rename that forgets the
+    registry entry must fail here, not in a job. The pass-list knob and
+    the layout kill switch went with the training-graph passes (PR 32):
+    a TrainStep compiles the graph it is given."""
+    assert config.KNOBS["MXNET_TPU_REMAT"][1] == "honored"
+    for name in ("MXNET_IR_TRAIN_PASSES", "MXNET_IR_LAYOUT"):
+        assert name not in config.KNOBS, name
 
 
 def test_new_self_healing_knobs_are_registered():

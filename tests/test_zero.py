@@ -100,9 +100,11 @@ def test_zero_matches_replicated_bf16():
     _, c_zero, l_zero = _run_steps(kw, zero=True, compute_dtype="bfloat16")
     np.testing.assert_allclose(l_rep, l_zero, rtol=1e-4)
     p_rep, p_zero = jax.device_get((c_rep[0], c_zero[0]))
+    # atol: bfloat16 gradients summed in another order move an update of
+    # ~1e-2 by up to its 2**-8 = 4e-5 (observed 1.7e-5); a broken shard 1e-2
     for k in p_rep:
         np.testing.assert_allclose(p_rep[k], p_zero[k],
-                                   rtol=1e-4, atol=1e-5, err_msg=k)
+                                   rtol=1e-4, atol=5e-5, err_msg=k)
 
 
 def test_zero_opt_state_bytes_scale_1_over_n(tmp_path):

@@ -137,11 +137,6 @@ def main():
                         "(ISSUE 9, MXNET_TPU_SENTINEL=skip) and report "
                         "its img/s next to the sentinel-off rate — the "
                         "tracked overhead number (acceptance <= 2%%)")
-    p.add_argument("--passes", action="store_true",
-                   help="also measure the training-graph pass pipeline "
-                        "(ISSUE 19, remat='pass' + layout) and report "
-                        "its img/s, compiled peak bytes, and backward "
-                        "residual bytes next to the passes-off step")
     p.add_argument("--fit-loop", action="store_true",
                    help="also run Module.fit() behind the async input "
                         "pipeline (DeviceQueueIter + device metrics) and "
@@ -221,55 +216,6 @@ def main():
         compiled_mem = ts.compiled_memory_stats(carry, syn, key)
     except Exception:
         compiled_mem = None
-
-    # -- pass-pipeline variant (ISSUE 19): remat='pass' + layout ---------
-    passes_rec = None
-    if args.passes:
-        ts_p = TrainStep(
-            sym, functional_optimizer("sgd", learning_rate=0.1,
-                                      momentum=0.9),
-            mesh=make_mesh({"dp": n_dev}), remat="pass",
-            train_passes=("layout",),
-            compute_dtype=compute_dtype,
-        )
-        p_p, s_p, a_p = ts_p.init_params(
-            {"data": (batch, 3, ds, ds), "softmax_label": (batch,)},
-            initializer=mx.initializer.Xavier())
-        carry_p = ts_p.place(p_p, s_p, a_p)
-        carry_p, loss_p = ts_p(carry_p, syn, key)   # compile
-        jax.block_until_ready(loss_p)
-        t0 = time.perf_counter()
-        for _ in range(n_syn):
-            carry_p, loss_p = ts_p(carry_p, syn, key)
-        jax.block_until_ready(loss_p)
-        passes_img_s = batch * n_syn / (time.perf_counter() - t0)
-        passes_rec = {
-            "img_s": round(passes_img_s, 2),
-            "vs_off": round(passes_img_s / synthetic_img_s, 3),
-            "remat_saved": ts_p._remat_plan.n_save,
-            "remat_recomputed": ts_p._remat_plan.n_recompute,
-        }
-        try:
-            mem_p = ts_p.compiled_memory_stats(carry_p, syn, key)
-            passes_rec["peak_bytes"] = mem_p["peak_bytes"]
-            if compiled_mem is not None:
-                passes_rec["peak_vs_off"] = round(
-                    mem_p["peak_bytes"]
-                    / max(compiled_mem["peak_bytes"], 1), 4)
-        except Exception:
-            pass
-        try:
-            # AD-level residual bytes: the backend-independent remat
-            # metric (CPU XLA strips the barriers)
-            res_p = ts_p.residual_stats(p_p, a_p, syn, key)
-            res_0 = ts.residual_stats(p_p, a_p, syn, key)
-            passes_rec["residual_bytes"] = res_p["residual_bytes"]
-            passes_rec["residual_vs_off"] = round(
-                res_p["residual_bytes"] / max(res_0["residual_bytes"], 1),
-                4)
-        except Exception:
-            pass
-        del carry_p
 
     # -- ZeRO variant (ISSUE 7): same graph, weight-update sharded -------
     zero_rec = None
@@ -404,8 +350,6 @@ def main():
     if compiled_mem is not None:
         rec["peak_bytes"] = compiled_mem["peak_bytes"]
         rec["temp_bytes"] = compiled_mem["temp_bytes"]
-    if passes_rec is not None:
-        rec["passes"] = passes_rec
     if fit_img_s is not None:
         rec["fit_img_s"] = round(fit_img_s, 2)
         rec["fit_host_syncs"] = fit_pipe.get("host_syncs", 0)

@@ -11,14 +11,6 @@ convention) so tooling can diff pass behavior across rounds.
     python tools/dump_graph.py --model resnet-basic --tiny --passes residual
     python tools/dump_graph.py --model mlp --passes fusion,residual --json
 
-``--train`` (ISSUE 19) switches to the training pipeline view: the
-pass list defaults to the layout pass, each entry reports transposes
-cancelled, and the record carries the selective remat plan for the
-final graph — how many sites the policy saves (MXU-op outputs) vs
-recomputes in the backward:
-
-    python tools/dump_graph.py --model bench-transformer --train
-
 ``--shapes data:2,3,64,64`` arms the PassManager's output-shape guard
 (a rewrite that changes output shapes fails loudly with PassError).
 """
@@ -57,14 +49,6 @@ def build_symbol(args):
 
         sym, _ = build_model(128, 256, 4, args.classes)
         return sym
-    if args.model == "bench-transformer":
-        from mxnet_tpu.models import bench_transformer
-
-        if args.tiny:
-            return bench_transformer.get_symbol(
-                num_classes=args.classes, seq_len=16, d_model=32,
-                n_heads=2, n_layers=1, d_ff=64)
-        return bench_transformer.get_symbol(num_classes=args.classes)
     raise SystemExit("unknown --model %r" % args.model)
 
 
@@ -87,14 +71,7 @@ def parse_shapes(spec):
 def main(argv=None):
     ap = argparse.ArgumentParser(description=__doc__)
     ap.add_argument("--model", default="resnet",
-                    choices=("resnet", "resnet-basic", "mlp",
-                             "bench-transformer"))
-    ap.add_argument("--train", action="store_true",
-                    help="training-pipeline view (ISSUE 19): default "
-                         "passes become the layout pass, entries report "
-                         "transposes cancelled, and the record carries "
-                         "the selective remat plan (save/recompute "
-                         "site counts) for the final graph")
+                    choices=("resnet", "resnet-basic", "mlp"))
     ap.add_argument("--layers", type=int, default=50)
     ap.add_argument("--classes", type=int, default=10)
     ap.add_argument("--image-shape", type=int, nargs=3,
@@ -114,12 +91,9 @@ def main(argv=None):
 
     symbol = build_symbol(args)
     names = args.passes.split(",") if args.passes else None
-    if names is None and args.train:
-        names = ("layout",)
     manager = ir.PassManager(names, data_shapes=parse_shapes(args.shapes))
 
-    record = {"model": args.model, "passes": [], "tiny": args.tiny,
-              "train": bool(args.train)}
+    record = {"model": args.model, "passes": [], "tiny": args.tiny}
     for name in manager.names:
         before = op_histogram(symbol)
         single = ir.PassManager((name,),
@@ -136,25 +110,12 @@ def main(argv=None):
             print("== pass %-12s nodes %d -> %d, %d rewrites"
                   % (name, prov["nodes_before"], prov["nodes_after"],
                      prov["rewrites"]))
-            if "transposes_cancelled" in prov:
-                print("   transposes cancelled     %d"
-                      % prov["transposes_cancelled"])
             for op, d in sorted(delta.items()):
                 print("   %-24s %+d" % (op, d))
             applied = Counter(prov["applied"])
             for rule, count in sorted(applied.items()):
                 print("   rule %-28s x%d" % (rule, count))
     record["final_ops"] = dict(op_histogram(symbol))
-    if args.train:
-        from mxnet_tpu.ir.remat import plan_remat
-
-        plan = plan_remat(symbol, record=False)
-        record["remat"] = plan.to_dict()
-        if not args.json:
-            print("== remat plan: save %d sites, recompute %d"
-                  % (plan.n_save, plan.n_recompute))
-            for nm in plan.save:
-                print("   save %s" % nm)
     print(json.dumps(record))
     return 0
 

@@ -9,8 +9,7 @@ attached to the kernel that caused it.
 Run:  python tools/tpu_kernel_smoke.py [--quick]
 Writes a timestamped record to stdout; exit 0 iff everything compiled
 and matched. This process holds the chip, so it starts no other: run
-tools/bench_kernel.py, tune_kernels.py and tune_pipeline.py as their
-own commands.
+tools/bench_kernel.py and tune_kernels.py as their own commands.
 """
 import argparse
 import os
