@@ -76,6 +76,16 @@ class NDArray:
             return value
         return self._jax
 
+    def _view_source(self):
+        """``(value, index)`` with ``value[index]`` this array's contents
+        (``value`` alone when ``index`` is None): a view's root buffer and
+        its index into it, no slice dispatched, else what ``_data()``
+        gives. ``DeviceQueueIter`` copies a host view's rows from there
+        into its staging buffer without realizing the view."""
+        if self._base is not None:
+            return self._base._data(), self._index
+        return self._data(), None
+
     def _rebind(self, new_value):
         """Point this handle at a new device buffer (in-place op semantics).
 

@@ -15,6 +15,14 @@ The placement function (:func:`place_batch_array`) is shared with
 ``FusedSPMDGroup`` so the pipelined path is bit-identical to the
 synchronous one — single-chip ``device_put`` and multi-process
 ``make_array_from_process_local_data`` both included.
+
+Where the bytes wait before that ``device_put`` is the worker's own
+business: a host batch array bound for an accelerator is copied into a
+reused staging buffer first (:class:`_StagingRing`), because the runtime
+copies from host memory it has seen before several times faster than from
+a fresh array every batch. No setting chooses it; the worker looks at
+where the array and the mesh are (:meth:`DeviceQueueIter._new_ring`,
+:func:`_host_rows`).
 """
 from __future__ import annotations
 
@@ -59,6 +67,10 @@ def place_batch_array(mesh, data_axes, distributed, name, value,
     process-local shard of the global batch in distributed mode. Records
     bytes/latency into the profiler's pipeline counters. ``value`` may be
     numpy or a single-device jax array; pre-placed arrays short-circuit.
+    It copies from whatever ``value`` is and stages nothing itself: the
+    ``DeviceQueueIter`` worker hands it a staging slot's buffer where it
+    staged the batch, ``FusedSPMDGroup``'s unqueued call the batch as it
+    came.
     """
     import jax
 
@@ -95,6 +107,72 @@ def place_batch_array(mesh, data_axes, distributed, name, value,
     return out
 
 
+def _host_rows(arr):
+    """``arr``'s contents as a numpy array over the memory they already
+    lie in, or None when they are not on the host (a device array, placed
+    or not, goes to :func:`place_batch_array` as it is). A numpy array is
+    itself; a one-device array of the host backend is read in place; an
+    ``NDArray`` view over such an array (``NDArrayIter``'s batches) is
+    its slice of the root's memory, so no intermediate array is made."""
+    value, index = arr._view_source() if isinstance(arr, NDArray) \
+        else (arr, None)
+    if index is not None and not isinstance(index, slice):
+        value, index = arr._data(), None   # no numpy view of it: realize
+    if not isinstance(value, np.ndarray):
+        devices = getattr(value, "devices", None)
+        if devices is None:
+            return None
+        devices = devices()
+        if len(devices) != 1 or next(iter(devices)).platform != "cpu":
+            return None
+        value = np.asarray(value)          # zero-copy on the host backend
+    return value if index is None else value[index]
+
+
+class _Slot:
+    __slots__ = ("buffer", "sent")
+
+    def __init__(self):
+        self.buffer = None  # host array, made at the first fill
+        self.sent = None    # device array made from buffer's last contents
+
+
+class _StagingRing:
+    """Reused host buffers between a batch's rows and ``device_put``, owned
+    by one ``DeviceQueueIter`` worker: two slots per ``(shape, dtype)``,
+    each made when a batch array of that shape first needs it, so a tail
+    batch of another shape never writes into a full batch's slot. The one
+    rule: a slot is not written again before the device array made from
+    its last contents is ready, because until then the runtime may still
+    be reading the buffer. Two slots are what that rule needs to keep the
+    worker moving: it fills one while the copy out of the other is in
+    flight."""
+
+    def __init__(self):
+        self._slots = {}
+
+    def fill(self, rows):
+        """Copy ``rows`` into the next slot of their shape and return the
+        slot; the caller sets ``slot.sent`` to the device array it makes
+        from ``slot.buffer``. Time spent waiting for the slot's last
+        transfer is counted (``stage_wait_seconds``)."""
+        key = (rows.shape, rows.dtype)
+        slots = self._slots.get(key)
+        if slots is None:
+            slots = self._slots[key] = [_Slot(), _Slot()]
+        slot = slots[0]
+        slots.reverse()
+        if slot.buffer is None:
+            slot.buffer = np.empty(rows.shape, rows.dtype)
+        if slot.sent is not None:
+            t0 = time.perf_counter()
+            slot.sent.block_until_ready()
+            profiler.h2d_record(stage_wait=time.perf_counter() - t0)
+            slot.sent = None
+        np.copyto(slot.buffer, rows)
+        return slot
+
+
 _END = object()    # inner iterator exhausted
 _ABORT = object()  # worker thread died; see self._exc
 
@@ -105,6 +183,17 @@ class DeviceQueueIter(DataIter):
     computes (ISSUE 5 tentpole). ``Module.fit`` on a fused kvstore puts
     one around the iterator it is given; wrap by hand only in a loop that
     calls ``forward_backward`` itself.
+
+    A batch array is staged — copied into a reused host buffer of the
+    worker's and sent from there — when it lies on the host (numpy, or an
+    array or ``NDArray`` view of the host backend) and the mesh is on an
+    accelerator. It goes as it came when it is already placed, when it
+    lies on an accelerator, when the mesh is the host backend
+    (``device_put`` may alias its source there, so a reused buffer would
+    be unsafe and buys nothing) and in the multi-process branch. The
+    bytes that reach the step are the same either way; no argument or
+    environment variable chooses. ``profiler.pipeline_stats()`` counts
+    ``staged`` beside ``puts`` and ``preplaced``.
 
     Parameters
     ----------
@@ -160,6 +249,7 @@ class DeviceQueueIter(DataIter):
         self._closed = False
         self._thread = None
         self._q = None
+        self._ring = None         # the running worker's _StagingRing
         self._exc = None
         self._stop = threading.Event()
         self._current_batch = None
@@ -209,16 +299,30 @@ class DeviceQueueIter(DataIter):
         return self.data_iter.provide_label
 
     # -- worker --------------------------------------------------------------
+    def _new_ring(self):
+        """A staging ring for the worker about to start, or None where
+        every batch goes to ``device_put`` as it came: on a mesh of the
+        host backend, and in the multi-process branch. It holds no
+        buffer before the first host batch array."""
+        import jax
+
+        if self.mesh.devices.flat[0].platform == "cpu":
+            return None
+        if self.distributed and jax.process_count() > 1:
+            return None
+        return _StagingRing()
+
     def _start(self):
         self._q = queue.Queue(maxsize=self.depth)
         self._stop = threading.Event()
+        self._ring = self._new_ring()
         self._exc = None
-        # the worker binds THIS generation's queue/stop-event as locals:
-        # a reset() that times out joining a wedged worker replaces both,
-        # and the abandoned thread must never be able to inject a stale
-        # pre-reset batch into the new epoch's queue
+        # the worker binds THIS generation's queue/stop-event/ring as
+        # locals: a reset() that times out joining a wedged worker
+        # replaces them, and the abandoned thread must never be able to
+        # inject a stale pre-reset batch into the new epoch's queue
         t = threading.Thread(target=self._worker,
-                             args=(self._q, self._stop),
+                             args=(self._q, self._stop, self._ring),
                              name="DeviceQueueIter", daemon=True)
         self._thread = t
         t.start()
@@ -234,17 +338,28 @@ class DeviceQueueIter(DataIter):
                 continue
         return False
 
-    def _place_batch(self, batch):
+    def _place_batch(self, batch, ring):
         rows = None
 
         def place(name, arr):
-            value = arr._data() if isinstance(arr, NDArray) else arr
             nonlocal rows
+            slot = None
+            host = _host_rows(arr) if ring is not None else None
+            if host is not None:
+                with profiler.span("mx.fit.stage", name=name,
+                                   nbytes=host.nbytes):
+                    slot = ring.fill(host)
+                value = slot.buffer
+            else:
+                value = arr._data() if isinstance(arr, NDArray) else arr
             if rows is None and not is_preplaced(value, self._sharding):
                 rows = int(value.shape[0])
             placed = place_batch_array(
                 self.mesh, self.data_axes, self.distributed, name, value,
                 sharding=self._sharding)
+            if slot is not None:
+                slot.sent = placed
+                profiler.h2d_record(staged=1)
             return NDArray(placed)
 
         names_d = [d[0] if isinstance(d, tuple) else d.name
@@ -269,7 +384,7 @@ class DeviceQueueIter(DataIter):
                         provide_label=batch.provide_label)
         return out
 
-    def _worker(self, q, stop):
+    def _worker(self, q, stop, ring):
         try:
             while not stop.is_set():
                 try:
@@ -277,7 +392,7 @@ class DeviceQueueIter(DataIter):
                 except StopIteration:
                     self._put(q, stop, _END)
                     return
-                placed = self._place_batch(batch)
+                placed = self._place_batch(batch, ring)
                 profiler.h2d_record(batches=1, queue_depth=q.qsize())
                 if not self._put(q, stop, placed):
                     return
@@ -373,13 +488,15 @@ class DeviceQueueIter(DataIter):
                     pass
                 t.join(timeout=0.05)
         self._thread = None
+        self._ring = None  # the staging buffers go with the worker
 
     def reset(self):
-        """Stop the worker and reset the source iterator — valid after
-        StopIteration AND after abandoning an epoch mid-stream. The
-        worker starts again at the next ``next()``, as it first did: a
-        reset nobody reads after (``fit``'s last) pulls nothing from the
-        source, which is left as the bare loop would leave it."""
+        """Stop the worker, drop its staging buffers and reset the source
+        iterator — valid after StopIteration AND after abandoning an
+        epoch mid-stream. The worker starts again at the next ``next()``,
+        as it first did: a reset nobody reads after (``fit``'s last) pulls
+        nothing from the source, which is left as the bare loop would
+        leave it."""
         if self._closed:
             raise MXNetError("DeviceQueueIter: iterator is closed")
         self._shutdown()
@@ -387,9 +504,9 @@ class DeviceQueueIter(DataIter):
         self._current_batch = None
 
     def close(self):
-        """Stop the worker, drop queued device batches, close the source
-        iterator if it supports close() (unless built with
-        ``close_source=False``). Idempotent."""
+        """Stop the worker, drop queued device batches and the staging
+        buffers, close the source iterator if it supports close() (unless
+        built with ``close_source=False``). Idempotent."""
         if self._closed:
             return
         self._closed = True

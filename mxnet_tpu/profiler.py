@@ -25,6 +25,9 @@ Names are fixed strings ``mx.<layer>.<what>``; arguments carry identity
 ``mx.nd.operator``          one imperative operator, ``mode="all"`` only (op)
 ``mx.fit.batch``            one turn of ``fit``'s batch loop (epoch, nbatch)
 ``mx.fit.forward_backward`` ``forward_backward(data_batch)``
+``mx.fit.stage``            ``DeviceQueueIter``'s worker waiting for a staging
+                            slot and copying one host batch array's rows
+                            into it (name, nbytes)
 ``mx.fit.h2d``              one batch array really copied to the mesh
                             (name, nbytes)
 ``mx.fit.dispatch``         the call into the compiled train step (step)
@@ -274,6 +277,10 @@ def comm_reset():
 # (`EvalMetric.get` from a callback: a loop can block in one every batch
 # while `host_syncs` stays 0), and `sync_seconds` is the host time spent
 # blocked in both kinds of read, on the clock `put_seconds` is on.
+# `staged` counts the puts whose bytes the DeviceQueueIter worker first
+# copied into a reused host staging slot (`staged_share` = staged / puts),
+# and `stage_wait_seconds` the time it waited for a slot's last transfer
+# before writing the slot again (ISSUE 34).
 # ---------------------------------------------------------------------------
 _PIPE_LOCK = threading.Lock()
 _PIPE_ZERO = {
@@ -282,6 +289,7 @@ _PIPE_ZERO = {
     "stall_compute_seconds": 0.0, "host_syncs": 0,
     "metric_drains": 0, "sync_seconds": 0.0,
     "max_queue_depth": 0, "max_inflight": 0,
+    "staged": 0, "stage_wait_seconds": 0.0,
 }
 _PIPE = dict(_PIPE_ZERO)
 
@@ -289,7 +297,7 @@ _PIPE = dict(_PIPE_ZERO)
 def h2d_record(nbytes=0, puts=0, preplaced=0, batches=0, steps=0,
                seconds=0.0, stall_feed=0.0, stall_compute=0.0,
                queue_depth=None, inflight=None, host_syncs=0,
-               metric_drains=0, sync_seconds=0.0):
+               metric_drains=0, sync_seconds=0.0, staged=0, stage_wait=0.0):
     """Accumulate input-pipeline counters (thread-safe; cheap enough to
     run unconditionally, like comm_record)."""
     with _PIPE_LOCK:
@@ -305,6 +313,8 @@ def h2d_record(nbytes=0, puts=0, preplaced=0, batches=0, steps=0,
         s["host_syncs"] += host_syncs
         s["metric_drains"] += metric_drains
         s["sync_seconds"] += sync_seconds
+        s["staged"] += staged
+        s["stage_wait_seconds"] += stage_wait
         if queue_depth is not None and queue_depth > s["max_queue_depth"]:
             s["max_queue_depth"] = queue_depth
         if inflight is not None and inflight > s["max_inflight"]:
@@ -327,6 +337,7 @@ def pipeline_stats(reset=False):
         if snap["put_seconds"] > 0:
             snap["put_MBps"] = round(
                 snap["nbytes"] / snap["put_seconds"] / 1e6, 1)
+        snap["staged_share"] = snap["staged"] / snap["puts"]
     if snap["batches"]:
         snap["avg_stall_feed_ms"] = round(
             snap["stall_feed_seconds"] / snap["batches"] * 1e3, 3)

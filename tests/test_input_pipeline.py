@@ -593,6 +593,279 @@ def test_module_fit_does_not_wrap_a_queue_twice(monkeypatch):
     assert _no_feed_thread()
 
 
+# ---------------------------------------------------------------------------
+# ISSUE 34: the worker stages host batches in reused host buffers
+# ---------------------------------------------------------------------------
+class _StagedQueue(DeviceQueueIter):
+    """The queue as it runs beside an accelerator: its worker gets a ring.
+    On this suite's host-backend mesh the real one never does."""
+
+    def _new_ring(self):
+        from mxnet_tpu.parallel.feed import _StagingRing
+
+        return _StagingRing()
+
+
+@pytest.fixture
+def copying_put(monkeypatch):
+    """``jax.device_put`` as an accelerator's: the device array is a copy.
+    The host backend may alias an aligned numpy source, which is why the
+    real queue stages nothing on it."""
+    import jax
+
+    real = jax.device_put
+
+    def put(x, *args, **kwargs):
+        if isinstance(x, np.ndarray):
+            x = np.array(x)
+        return real(x, *args, **kwargs)
+
+    monkeypatch.setattr(jax, "device_put", put)
+
+
+class _Transfer:
+    """Stands for the device array made from a slot: ready when told."""
+
+    def __init__(self):
+        self.done = threading.Event()
+
+    def block_until_ready(self):
+        assert self.done.wait(10.0)
+        return self
+
+
+def test_staging_ring_does_not_rewrite_a_slot_before_its_transfer_is_ready():
+    from mxnet_tpu.parallel.feed import _StagingRing
+
+    ring = _StagingRing()
+    batches = [np.full((8, 4), i, np.float32) for i in range(3)]
+    first = ring.fill(batches[0])
+    first.sent = _Transfer()
+    second = ring.fill(batches[1])          # the other slot: no wait
+    second.sent = _Transfer()
+    assert second is not first
+    assert not np.shares_memory(first.buffer, second.buffer)
+    profiler.pipeline_reset()
+    profiler.h2d_record(batches=1)          # so the snapshot is not empty
+    got = []
+    t = threading.Thread(target=lambda: got.append(ring.fill(batches[2])))
+    t.start()
+    time.sleep(0.3)                         # the test holds the transfer back
+    assert t.is_alive() and not got
+    np.testing.assert_array_equal(first.buffer, batches[0])  # untouched
+    first.sent.done.set()
+    t.join(10.0)
+    assert not t.is_alive() and got == [first]
+    np.testing.assert_array_equal(first.buffer, batches[2])
+    np.testing.assert_array_equal(second.buffer, batches[1])
+    assert first.sent is None               # the caller's to set again
+    assert profiler.pipeline_stats()["stage_wait_seconds"] >= 0.25
+    # a slot whose array is ready costs no wait worth counting
+    profiler.pipeline_reset()
+    profiler.h2d_record(batches=1)
+    second.sent.done.set()
+    assert ring.fill(batches[0]) is second
+    assert profiler.pipeline_stats()["stage_wait_seconds"] < 0.1
+
+
+def test_staging_ring_keeps_shapes_and_dtypes_apart():
+    from mxnet_tpu.parallel.feed import _StagingRing
+
+    ring = _StagingRing()
+    full = np.arange(32, dtype=np.float32).reshape(8, 4)
+    slot = ring.fill(full)
+    tail = ring.fill(full[:4] + 100)        # a tail batch: its own slots
+    ints = ring.fill(full.astype(np.int32) + 200)
+    assert tail.buffer.shape == (4, 4) and ints.buffer.dtype == np.int32
+    np.testing.assert_array_equal(slot.buffer, full)    # nobody wrote here
+    assert len({id(s.buffer) for s in (slot, tail, ints)}) == 3
+    # the ring made one buffer a fill, and the second slot of a shape only
+    # when a second batch of that shape came
+    made = [s.buffer for pair in ring._slots.values() for s in pair]
+    assert sum(b is not None for b in made) == 3 and len(made) == 6
+
+
+def test_host_rows_reads_views_and_host_arrays_in_place():
+    import jax
+
+    from mxnet_tpu.parallel.feed import _host_rows
+
+    X, y = _data(n=128)
+    it = mx.io.NDArrayIter(X, y, batch_size=32)
+    it.next()
+    view = it.next().data[0]                # rows 32:64 of the source
+    base = np.asarray(it.data[0][1]._data())
+    rows = _host_rows(view)
+    np.testing.assert_array_equal(rows, X[32:64])
+    assert np.shares_memory(rows, base)
+    assert view._view_cache is None         # the view was never realized
+    whole = nd.array(X[:32])
+    assert np.shares_memory(_host_rows(whole), np.asarray(whole._data()))
+    assert _host_rows(X) is X
+    # a view no numpy slice stands for is realized, as before
+    row = nd.array(X)[3]
+    np.testing.assert_array_equal(_host_rows(row), X[3])
+    # arrays laid out over several devices are not the worker's to read
+    mesh = make_mesh({"dp": 8})
+    placed = jax.device_put(X[:32], expected_sharding(mesh, ("dp",)))
+    assert _host_rows(placed) is None and _host_rows(nd.NDArray(placed)) is None
+
+
+class _BatchesIter(mx.io.DataIter):
+    """Full batches of 64 rows and a tail of 32, each batch as ``kind``
+    says: ``view`` (NDArray views of one host array, the tail a whole
+    array), ``numpy`` (a fresh numpy array a batch) or ``placed`` (device
+    arrays already laid out as the step wants them)."""
+
+    def __init__(self, X, y, kind, sharding=None, tail=32):
+        super().__init__(64)
+        self.X, self.y, self.kind, self.sharding = X, y, kind, sharding
+        self.ndX, self.ndy = nd.array(X), nd.array(y)
+        self.cuts = [(i, i + 64) for i in range(0, len(X) - tail, 64)]
+        if tail:
+            self.cuts.append((len(X) - tail, len(X)))
+        self.at = 0
+
+    @property
+    def provide_data(self):
+        return [mx.io.DataDesc("data", (64,) + self.X.shape[1:])]
+
+    @property
+    def provide_label(self):
+        return [mx.io.DataDesc("softmax_label", (64,))]
+
+    def reset(self):
+        self.at = 0
+
+    def next(self):
+        import jax
+
+        if self.at == len(self.cuts):
+            raise StopIteration
+        lo, hi = self.cuts[self.at]
+        self.at += 1
+        data, label = self.X[lo:hi].copy(), self.y[lo:hi].copy()
+        if self.kind == "placed":
+            data, label = (nd.NDArray(jax.device_put(a, self.sharding))
+                           for a in (data, label))
+        elif self.kind == "view" and hi - lo == 64:
+            data, label = self.ndX[lo:hi], self.ndy[lo:hi]
+        elif self.kind == "view":           # the tail: a whole host array
+            data, label = nd.array(data), nd.array(label)
+        return mx.io.DataBatch([data], [label], pad=0)
+
+
+@pytest.mark.parametrize("kind", ["view", "numpy", "placed"])
+def test_staged_queue_counts_by_where_the_bytes_are(kind, copying_put):
+    X, y = _data(n=224)
+    mesh = make_mesh({"dp": 8})
+    sharding = expected_sharding(mesh, ("dp",))
+    profiler.pipeline_reset()
+    with _StagedQueue(_BatchesIter(X, y, kind, sharding), mesh=mesh) as dq:
+        for _ in range(2):
+            got = [(b.data[0].asnumpy(), b.label[0].asnumpy()) for b in dq]
+            np.testing.assert_array_equal(np.concatenate([g[0] for g in got]), X)
+            np.testing.assert_array_equal(np.concatenate([g[1] for g in got]), y)
+            assert [len(g[1]) for g in got] == [64, 64, 64, 32]
+            dq.reset()
+    stats = profiler.pipeline_stats()
+    arrays = 2 * 4 * 2                      # epochs x batches x (data, label)
+    assert stats["batches"] == 2 * 4, stats
+    if kind == "placed":
+        assert (stats["puts"], stats["staged"], stats["preplaced"]) \
+            == (0, 0, arrays), stats
+        assert "staged_share" not in stats
+    else:
+        assert (stats["puts"], stats["staged"], stats["preplaced"]) \
+            == (arrays, arrays, 0), stats
+        assert stats["staged_share"] == 1.0
+
+
+def test_module_fit_through_a_staging_queue_matches_host_batches_bitexact(
+        copying_put):
+    X, y = _data(n=224, seed=5)
+    probe, _ = _fused_module(X, y, seed=34)
+    arg0, aux0 = probe.get_params()
+    arg0 = {k: v.asnumpy() for k, v in arg0.items()}
+
+    def fit(queue_of):
+        mod = mx.mod.Module(_mlp(), context=[mx.cpu(i) for i in range(8)])
+        src = _BatchesIter(X, y, "view")
+        profiler.pipeline_reset()
+        with queue_of(src, mod) as dq:
+            mod.fit(dq, num_epoch=2, kvstore="tpu", optimizer="sgd",
+                    optimizer_params={"learning_rate": 0.1, "momentum": 0.9},
+                    arg_params={k: nd.array(v) for k, v in arg0.items()},
+                    aux_params=aux0)
+        return mod.get_params()[0], profiler.pipeline_stats()
+
+    want, plain = fit(lambda src, mod: DeviceQueueIter(src, module=mod))
+    got, staged = fit(lambda src, mod: _StagedQueue(src, module=mod))
+    # two epochs of three full batches (views) and a 32-row tail
+    assert plain["steps"] == staged["steps"] == 8
+    assert plain["puts"] == staged["puts"] == 16
+    assert plain["staged"] == 0 and staged["staged"] == 16, (plain, staged)
+    assert got.keys() == want.keys()
+    for k in want:
+        np.testing.assert_array_equal(got[k].asnumpy(), want[k].asnumpy(),
+                                      err_msg=k)
+        assert not np.array_equal(want[k].asnumpy(), arg0[k]), k
+    assert _no_feed_thread()
+
+
+def test_host_backend_mesh_stages_nothing():
+    X, y = _data(n=256)
+    profiler.pipeline_reset()
+    src = _ThreadLoggingIter(mx.io.NDArrayIter(X, y, batch_size=64))
+    mod = mx.mod.Module(_mlp(), context=[mx.cpu(i) for i in range(8)])
+    with DeviceQueueIter(src, module=mod) as dq:
+        mod.fit(dq, num_epoch=2, kvstore="tpu", optimizer="sgd",
+                optimizer_params={"learning_rate": 0.1},
+                initializer=mx.initializer.Xavier())
+        assert dq._new_ring() is None       # the mesh is the host backend
+    stats = profiler.pipeline_stats()
+    assert stats["puts"] == 16 and stats["batches"] == 8, stats
+    assert stats["staged"] == 0 and stats["staged_share"] == 0.0, stats
+    assert stats["stage_wait_seconds"] == 0.0, stats
+
+
+def test_staging_ring_goes_with_the_worker_on_reset_and_close(copying_put):
+    X, y = _data(n=256)
+    mesh = make_mesh({"dp": 8})
+    dq = _StagedQueue(mx.io.NDArrayIter(X, y, batch_size=32), mesh=mesh)
+    assert dq._ring is None and dq._thread is None   # nothing before next()
+    dq.next()
+    ring = dq._ring
+    assert ring is not None and ring._slots
+    dq.reset()                              # mid-epoch
+    assert dq._ring is None and dq._thread is None and _no_feed_thread()
+    assert sum(1 for _ in dq) == 8          # a new worker, a new ring
+    assert dq._ring is not None and dq._ring is not ring
+    dq.close()
+    assert dq._ring is None and dq._thread is None and _no_feed_thread()
+
+
+def test_importing_the_feed_allocates_nothing():
+    import subprocess
+    import sys
+
+    code = (
+        "import numpy as np\n"
+        "import mxnet_tpu\n"
+        "from mxnet_tpu import profiler\n"
+        "from mxnet_tpu.parallel import feed\n"
+        "assert profiler.pipeline_stats() == {}, profiler.pipeline_stats()\n"
+        "held = [k for k, v in vars(feed).items() if isinstance(\n"
+        "    v, (np.ndarray, feed._StagingRing, feed._Slot, dict, list))\n"
+        "    and not k.startswith('__')]\n"
+        "assert held == [], held\n"
+        "print('clean')\n")
+    env = dict(os.environ, JAX_PLATFORMS="cpu")
+    out = subprocess.run([sys.executable, "-c", code], env=env, timeout=120,
+                         capture_output=True, text=True)
+    assert out.returncode == 0 and out.stdout.strip() == "clean", out.stderr
+
+
 def test_feedforward_fit_uses_pipeline():
     """model.FeedForward.fit trains through the queue Module.fit puts in."""
     X, y = _data(n=256, seed=4)
