@@ -1,0 +1,644 @@
+"""Decoder LM with latent attention, a learned sparse-attention indexer and
+sigmoid top-k expert routing over a chip's share of the experts, for
+incremental decode through :class:`~mxnet_tpu.serving.GenerativePredictor`.
+
+The block (pre-norm, RMSNorm, residual in the compute type; ``h`` is the
+normed input of a half):
+
+- **latent attention** (DeepSeek-V2/V3's MLA).  ``c_q = RMSNorm(h W_qa)``,
+  ``[q_nope | q_rope] = c_q W_qb`` a head; ``[c_kv | k_rope] = h W_kva``,
+  ``c_kv = RMSNorm(c_kv)``; interleaved rotary on ``q_rope`` and on the one
+  ``k_rope`` all heads share; ``[k_nope | v] = c_kv W_kvb`` a head;
+  ``softmax((q_nope . k_nope + q_rope . k_rope) / sqrt(d_nope + d_rope))``
+  over the *allowed* keys.  The cache holds one latent row
+  ``[c_kv | k_rope]`` a token a layer.  Prefill runs the expanded form,
+  decode the absorbed one (``q' = q_nope W_kvb[k]^T`` scored against
+  ``c_kv`` itself, ``P c_kv`` up-projected by ``W_kvb[v]``).
+- **indexer** (DeepSeek-V3.2's lightning indexer), every layer.
+  ``q_I = c_q W_Iq`` (heads x dim), ``k_I = LayerNorm(h W_Ik)``, rotary on
+  the first ``index_rope_dim`` of both, ``w = h W_Iw`` scaled by
+  ``heads^-1/2 dim^-1/2``; ``I[t, s] = sum_h w[t, h] relu(q_I[t, h] . k_I[s])``
+  in float32; position ``t`` may attend the ``index_topk`` causal positions
+  of largest ``I`` (all of them while there are no more).  The cache holds
+  ``k_I`` beside the latent row, under the same block table.
+- **experts** (DeepSeek-V3's ``noaux_tc`` router without groups), layers
+  past the leading dense ones.  ``s = sigmoid(h W_r)`` in float32 over all
+  ``n_experts``; the ``experts_per_token`` largest of ``s + b`` are chosen
+  (``b`` moves the choice only); ``g = route_scale * s / sum(s chosen)``;
+  the layer is told which experts it holds (``held_experts``), computes
+  ``sum g_e SwiGLU_e(h)`` over the chosen *and held* ones, and adds the
+  shared expert.  What the absent experts would have added is left out:
+  the partial result goes on, as on one chip of an expert-parallel pool.
+  An expert no token of the step chose is skipped (``lax.cond``), so a
+  decode step reads the experts it touches.
+- **head**: RMSNorm, then an untied head over the rows of the vocabulary
+  held here; the embedding has the same rows.
+
+Entry points are those ``GenerativePredictor`` asks a model module for:
+``init_kv_cache``, ``kv_page_bytes``, ``make_prefill_fn``,
+``make_decode_fn`` and ``DECODE_COUNTERS``; ``make_forward_fn`` is the
+cache-free one-shot forward the tests hold both against.
+"""
+from __future__ import annotations
+
+import dataclasses
+import functools
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+from jax import lax
+
+__all__ = ["LatentMoEConfig", "init_params", "init_kv_cache", "kv_page_bytes",
+           "make_prefill_fn", "make_decode_fn", "make_forward_fn",
+           "DECODE_COUNTERS"]
+
+# what one decode step counts on the device, summed over its layers and
+# returned beside the logits (``profiler.generate_record`` names)
+DECODE_COUNTERS = ("moe_pairs_held", "moe_tokens", "moe_experts_touched",
+                   "moe_pairs_at_max_load", "dsa_keys_scanned", "dsa_keys_selected")
+
+
+@dataclasses.dataclass
+class LatentMoEConfig:
+    vocab: int = 19360              # rows of the vocabulary held here
+    d_model: int = 6144
+    n_heads: int = 64
+    n_layers: int = 5
+    n_dense_layers: int = 1         # leading layers with one dense SwiGLU
+    d_ff: int = 12288               # the dense layers' width
+    d_expert: int = 2048            # a routed or shared expert's width
+    n_experts: int = 256            # the router's width
+    experts_per_token: int = 8
+    held_experts: tuple = tuple(range(16))   # ids of the experts held here
+    route_scale: float = 2.5
+    q_rank: int = 2048
+    kv_rank: int = 512
+    d_nope: int = 192
+    d_rope: int = 64
+    d_v: int = 256
+    index_heads: int = 32
+    index_dim: int = 128
+    index_rope_dim: int = 64
+    index_topk: int = 2048
+    rope_theta: float = 1e6
+    norm_eps: float = 1e-5
+    index_norm_eps: float = 1e-6
+    max_len: int = 202752
+    dtype: str = "bfloat16"         # weights as given; cache and products
+    # the module whose programs serve this configuration
+    module: str = "mxnet_tpu.models.mla_moe"
+
+    def __post_init__(self):
+        self.held_experts = tuple(int(e) for e in self.held_experts)
+
+
+def param_shapes(config):
+    """name -> (shape, kind): ``normal`` matrices, ``ones`` gains, ``zeros``
+    offsets, ``bias`` the router's correction bias.  Attention and indexer
+    leaves are stacked over all layers, dense FFN leaves over the leading
+    dense layers, router and expert leaves over the layers that follow."""
+    c = config
+    d, L, H = c.d_model, c.n_layers, c.n_heads
+    Ld, Lm, Eh = c.n_dense_layers, c.n_layers - c.n_dense_layers, len(c.held_experts)
+    return {
+        "embed_weight": ((c.vocab, d), "normal"),
+        "head_weight": ((c.vocab, d), "normal"),
+        "final_norm": ((d,), "ones"),
+        "attn_norm": ((L, d), "ones"),
+        "ffn_norm": ((L, d), "ones"),
+        "q_a_weight": ((L, d, c.q_rank), "normal"),
+        "q_a_norm": ((L, c.q_rank), "ones"),
+        "q_b_weight": ((L, c.q_rank, H, c.d_nope + c.d_rope), "normal"),
+        "kv_a_weight": ((L, d, c.kv_rank + c.d_rope), "normal"),
+        "kv_a_norm": ((L, c.kv_rank), "ones"),
+        "kv_b_weight": ((L, c.kv_rank, H, c.d_nope + c.d_v), "normal"),
+        "o_weight": ((L, H, c.d_v, d), "normal"),
+        "index_q_weight": ((L, c.q_rank, c.index_heads, c.index_dim), "normal"),
+        "index_k_weight": ((L, d, c.index_dim), "normal"),
+        "index_k_norm_gamma": ((L, c.index_dim), "ones"),
+        "index_k_norm_beta": ((L, c.index_dim), "zeros"),
+        "index_w_weight": ((L, d, c.index_heads), "normal"),
+        "dense_gate_weight": ((Ld, d, c.d_ff), "normal"),
+        "dense_up_weight": ((Ld, d, c.d_ff), "normal"),
+        "dense_down_weight": ((Ld, c.d_ff, d), "normal"),
+        "router_weight": ((Lm, d, c.n_experts), "normal"),
+        "router_bias": ((Lm, c.n_experts), "bias"),
+        "expert_gate_weight": ((Lm, Eh, d, c.d_expert), "normal"),
+        "expert_up_weight": ((Lm, Eh, d, c.d_expert), "normal"),
+        "expert_down_weight": ((Lm, Eh, c.d_expert, d), "normal"),
+        "shared_gate_weight": ((Lm, d, c.d_expert), "normal"),
+        "shared_up_weight": ((Lm, d, c.d_expert), "normal"),
+        "shared_down_weight": ((Lm, c.d_expert, d), "normal"),
+    }
+
+
+def init_params(config, seed=0, scale=0.02, bias_scale=0.01):
+    """Seeded float32 parameters on the host (tests and examples; the
+    benchmark makes its own on the device)."""
+    rng = np.random.RandomState(seed)
+    out = {}
+    for name, (shape, kind) in sorted(param_shapes(config).items()):
+        if kind == "ones":
+            out[name] = np.ones(shape, np.float32)
+        elif kind == "zeros":
+            out[name] = np.zeros(shape, np.float32)
+        else:
+            out[name] = rng.normal(0.0, bias_scale if kind == "bias" else scale,
+                                   shape).astype(np.float32)
+    return {k: jnp.asarray(v) for k, v in out.items()}
+
+
+# -- the cache ---------------------------------------------------------------
+def _latent_width(config):
+    """Lanes of a cached latent row: ``kv_rank + d_rope`` rounded up to whole
+    128-lane tiles (576 -> 640), the tail zero.  A row that is not whole
+    tiles makes the TPU lay the pool out with the page axis minor-most (less
+    padding that way), and every gather and scatter of rows then pays two
+    transposing copies of the layer's pool a step."""
+    return -(-(config.kv_rank + config.d_rope) // 128) * 128
+
+
+def init_kv_cache(config, num_pages, page_size, dtype=None):
+    """Zeroed page pool, two arrays a layer under one block table:
+    ``latent[l]`` (pages + 1, page, :func:`_latent_width`) rows
+    ``[c_kv | k_rope | 0]`` and ``index[l]`` (pages + 1, page, index_dim)
+    rows ``k_I``.  A layer's arrays are its own, so that a step writes its
+    row into them in place and gathers from them without slicing a pool of
+    all layers first.  Page 0 is the scratch page, as in the transformer's
+    pool."""
+    c = config
+    cdt = jnp.dtype(dtype if dtype is not None else c.dtype)
+    lead = (int(num_pages) + 1, int(page_size))
+    return {"latent": [jnp.zeros(lead + (_latent_width(c),), cdt)
+                       for _ in range(c.n_layers)],
+            "index": [jnp.zeros(lead + (c.index_dim,), cdt)
+                      for _ in range(c.n_layers)]}
+
+
+def kv_page_bytes(config, page_size):
+    """Bytes one page holds over all layers and both arrays."""
+    c = config
+    return (c.n_layers * int(page_size) * (_latent_width(c) + c.index_dim)
+            * jnp.dtype(c.dtype).itemsize)
+
+
+# -- pieces ------------------------------------------------------------------
+def _rmsnorm(x, gamma, eps):
+    x = x.astype(jnp.float32)
+    y = x * lax.rsqrt(jnp.mean(jnp.square(x), axis=-1, keepdims=True) + eps)
+    return y * gamma.astype(jnp.float32)
+
+
+def _layernorm(x, gamma, beta, eps):
+    x = x.astype(jnp.float32)
+    mu = jnp.mean(x, axis=-1, keepdims=True)
+    var = jnp.mean(jnp.square(x - mu), axis=-1, keepdims=True)
+    return (x - mu) * lax.rsqrt(var + eps) * gamma.astype(jnp.float32) \
+        + beta.astype(jnp.float32)
+
+
+def _rope(x, positions, theta):
+    """Interleaved rotary over the last axis of ``x`` (..., T, [heads,] n):
+    the pairs ``(x[2i], x[2i+1])`` turn by ``positions * theta^(-2i/n)``.
+    ``positions`` (T,) lines up with the axis before the optional heads."""
+    n = x.shape[-1]
+    freq = jnp.exp(jnp.arange(0, n, 2, dtype=jnp.float32) * (-np.log(theta) / n))
+    ang = positions.astype(jnp.float32)[:, None] * freq          # (T, n/2)
+    if x.ndim == ang.ndim + 1:
+        ang = ang[:, None, :]                                    # heads axis
+    cos, sin = jnp.cos(ang), jnp.sin(ang)
+    xf = x.astype(jnp.float32).reshape(x.shape[:-1] + (n // 2, 2))
+    a, b = xf[..., 0], xf[..., 1]
+    return jnp.stack([a * cos - b * sin, a * sin + b * cos], axis=-1).reshape(x.shape)
+
+
+def _dot(a, b, spec, cdt):
+    """A product in the compute type, accumulated in float32."""
+    return jnp.einsum(spec, a.astype(cdt), b.astype(cdt),
+                      preferred_element_type=jnp.float32)
+
+
+def _swiglu(x, gate, up, down, cdt):
+    g = _dot(x, gate, "td,df->tf", cdt)
+    u = _dot(x, up, "td,df->tf", cdt)
+    return _dot((jax.nn.silu(g) * u).astype(cdt), down, "tf,fd->td", cdt)
+
+
+def _layer(params, i, config):
+    """Layer ``i``'s leaves: attention and indexer from the all-layer
+    stacks, the FFN's from the dense or the expert stacks."""
+    c = config
+    per_layer = ("attn_norm", "ffn_norm", "q_a_weight", "q_a_norm", "q_b_weight",
+                 "kv_a_weight", "kv_a_norm", "kv_b_weight", "o_weight",
+                 "index_q_weight", "index_k_weight", "index_k_norm_gamma",
+                 "index_k_norm_beta", "index_w_weight")
+    lp = {k: params[k][i] for k in per_layer}
+    if i < c.n_dense_layers:
+        group, j = ("dense_gate_weight", "dense_up_weight", "dense_down_weight"), i
+    else:
+        group = ("router_weight", "router_bias", "shared_gate_weight",
+                 "shared_up_weight", "shared_down_weight")
+        j = i - c.n_dense_layers
+        # the held experts' stacks go whole, with the layer's index: an
+        # expert's matrices are sliced where they are used, inside the branch
+        # that may skip them, and never copied out to be handed to it
+        lp["experts"] = (j, params["expert_gate_weight"],
+                         params["expert_up_weight"], params["expert_down_weight"])
+    lp.update({k: params[k][j] for k in group})
+    return lp
+
+
+def _project(h, positions, lp, c, cdt):
+    """The low-rank projections and the indexer's of rows ``h`` (T, d) at
+    ``positions`` (T,): ``c_q`` (T, q_rank), the latent row as it is cached
+    (T, :func:`_latent_width`), ``q_I`` (T, heads, dim), ``k_I`` (T, dim), all in the compute
+    type, and the indexer's head weights ``w`` (T, heads) in float32."""
+    with jax.named_scope("mx.gen.latent_proj"):
+        c_q = _rmsnorm(_dot(h, lp["q_a_weight"], "td,dr->tr", cdt),
+                       lp["q_a_norm"], c.norm_eps).astype(cdt)
+        kv = _dot(h, lp["kv_a_weight"], "td,dr->tr", cdt)
+        c_kv = _rmsnorm(kv[:, :c.kv_rank], lp["kv_a_norm"], c.norm_eps)
+        k_rope = _rope(kv[:, c.kv_rank:], positions, c.rope_theta)
+        pad = jnp.zeros((h.shape[0], _latent_width(c) - c.kv_rank - c.d_rope),
+                        jnp.float32)
+        latent = jnp.concatenate([c_kv, k_rope, pad], axis=-1).astype(cdt)
+    with jax.named_scope("mx.gen.index"):
+        r = c.index_rope_dim
+        q_i = _dot(c_q, lp["index_q_weight"], "tr,rhe->the", cdt)
+        q_i = jnp.concatenate([_rope(q_i[..., :r], positions, c.rope_theta),
+                               q_i[..., r:]], axis=-1).astype(cdt)
+        k_i = _layernorm(_dot(h, lp["index_k_weight"], "td,de->te", cdt),
+                         lp["index_k_norm_gamma"], lp["index_k_norm_beta"],
+                         c.index_norm_eps)
+        k_i = jnp.concatenate([_rope(k_i[:, :r], positions, c.rope_theta),
+                               k_i[:, r:]], axis=-1).astype(cdt)
+        w = _dot(h, lp["index_w_weight"], "td,dh->th", cdt) \
+            * (c.index_heads ** -0.5 * c.index_dim ** -0.5)
+    return c_q, latent, q_i, k_i, w
+
+
+def _queries(c_q, positions, lp, c, cdt):
+    """(T, H, d_nope) and rotated (T, H, d_rope) queries from ``c_q``."""
+    with jax.named_scope("mx.gen.latent_proj"):
+        q = _dot(c_q, lp["q_b_weight"], "tr,rhe->the", cdt)
+        q_rope = _rope(q[..., c.d_nope:], positions, c.rope_theta)
+        return q[..., :c.d_nope].astype(cdt), q_rope.astype(cdt)
+
+
+def _output(o, lp, cdt):
+    """Heads' values (T, H, d_v) through the output projection: (T, d)."""
+    with jax.named_scope("mx.gen.latent_proj"):
+        return _dot(o, lp["o_weight"], "the,hed->td", cdt)
+
+
+def _index_scores(q_i, w, k_i):
+    """``I`` (T, K) float32 of queries ``q_i`` (T, heads, dim) with head
+    weights ``w`` (T, heads) against keys ``k_i`` (K, dim) or, a query its
+    own keys, (T, K, dim)."""
+    spec = "the,tke->thk" if k_i.ndim == 3 else "the,ke->thk"
+    s = jnp.einsum(spec, q_i, k_i, preferred_element_type=jnp.float32)
+    return jnp.sum(jax.nn.relu(s) * w[:, :, None], axis=1)
+
+
+def _largest_k(scores, k):
+    """(T, K) bool: per row of ``scores`` (T, K) float32, its ``k`` largest
+    entries, equal ones taken from the lower index first, which is the set
+    ``lax.top_k`` returns, found without a sort.  The ``k``-th largest value
+    comes from a bisection over the 32 bits of an order-preserving unsigned
+    code; entries above it are in, and of those equal to it the first ones
+    that still fit.  A row of fewer than ``k`` entries above -inf takes those
+    and fills up with -inf ones, which the caller's own mask drops."""
+    bits = lax.bitcast_convert_type(scores, jnp.int32)
+    code = jnp.where(bits < 0, ~bits, bits ^ jnp.int32(-2 ** 31))
+    code = lax.bitcast_convert_type(code, jnp.uint32)
+
+    def step(b, key):
+        cand = key | (jnp.uint32(1) << (jnp.uint32(31) - b.astype(jnp.uint32)))
+        enough = jnp.sum(code >= cand[:, None], axis=-1) >= k
+        return jnp.where(enough, cand, key)
+
+    key = lax.fori_loop(0, 32, step, jnp.zeros(scores.shape[:1], jnp.uint32))
+    above, equal = code > key[:, None], code == key[:, None]
+    room = k - jnp.sum(above, axis=-1, keepdims=True)
+    return above | (equal & (jnp.cumsum(equal, axis=-1) <= room))
+
+
+def _route(h, lp, c, active):
+    """The router over all experts, in float32: per token the chosen ids
+    (T, k) and their gates (T, k); rows where ``active`` is false choose
+    nothing (id -1, gate 0)."""
+    with jax.named_scope("mx.lm.moe.route"):
+        logits = jnp.einsum("td,de->te", h, lp["router_weight"].astype(h.dtype),
+                            preferred_element_type=jnp.float32)
+        s = jax.nn.sigmoid(logits)
+        _top, ids = lax.top_k(s + lp["router_bias"].astype(jnp.float32),
+                              c.experts_per_token)
+        chosen = jnp.take_along_axis(s, ids, axis=-1)
+        gates = c.route_scale * chosen / jnp.sum(chosen, axis=-1, keepdims=True)
+        ids = jnp.where(active[:, None], ids, -1)
+        gates = jnp.where(active[:, None], gates, 0.0)
+    return ids, gates
+
+
+def _moe(h, lp, c, cdt, active):
+    """The expert layer's output for normed rows ``h`` (T, d), and what it
+    counted: pairs on held experts, held experts touched, and the pairs it
+    would hold were every held expert as full as the fullest."""
+    ids, gates = _route(h, lp, c, active)
+    with jax.named_scope("mx.lm.moe.shared"):
+        y = _swiglu(h, lp["shared_gate_weight"], lp["shared_up_weight"],
+                    lp["shared_down_weight"], cdt)
+    loads = []
+    layer, w_gate, w_up, w_down = lp["experts"]
+    with jax.named_scope("mx.lm.moe.experts"):
+        for j, e in enumerate(c.held_experts):
+            hit = ids == e                                       # (T, k)
+            gate = jnp.sum(jnp.where(hit, gates, 0.0), axis=-1)  # (T,)
+            loads.append(jnp.sum(hit))
+
+            def run(j=j, gate=gate):
+                out = _swiglu(h, w_gate[layer, j], w_up[layer, j],
+                              w_down[layer, j], cdt)
+                return out * gate[:, None]
+
+            y = y + lax.cond(loads[-1] > 0, run, lambda: jnp.zeros_like(y))
+    loads = jnp.stack(loads)
+    counts = {"moe_pairs_held": jnp.sum(loads),
+              "moe_tokens": jnp.sum(active),
+              "moe_experts_touched": jnp.sum(loads > 0),
+              "moe_pairs_at_max_load": jnp.max(loads) * len(c.held_experts)}
+    return y, counts
+
+
+def _ffn(x, lp, c, cdt, active):
+    """The FFN half of a block on residual rows ``x`` (T, d)."""
+    h = _rmsnorm(x, lp["ffn_norm"], c.norm_eps).astype(cdt)
+    if "router_weight" in lp:
+        y, counts = _moe(h, lp, c, cdt, active)
+    else:
+        with jax.named_scope("mx.lm.ffn"):
+            y = _swiglu(h, lp["dense_gate_weight"], lp["dense_up_weight"],
+                        lp["dense_down_weight"], cdt)
+        counts = {}
+    return x + y.astype(cdt), counts
+
+
+def _head(x, params, c, cdt):
+    h = _rmsnorm(x, params["final_norm"], c.norm_eps).astype(cdt)
+    return _dot(h, params["head_weight"], "td,vd->tv", cdt)
+
+
+def _row_blocks(fn, rows, block, *args):
+    """``fn`` over blocks of ``block`` rows of every array in ``args`` (each
+    (rows, ...)), one block at a time, results concatenated."""
+    nb = rows // block
+    split = [a.reshape((nb, block) + a.shape[1:]) for a in args]
+    out = lax.map(lambda xs: fn(*xs), tuple(split))
+    return jax.tree.map(lambda o: o.reshape((rows,) + o.shape[2:]), out)
+
+
+KEY_CHUNKS = 8       # key chunks a query block may skip when they lie ahead
+
+
+def _expanded_attention(c_q, q_i, w, positions, length, latent, k_i, lp, c, cdt):
+    """Causal sparse attention of a whole sequence in the expanded form:
+    keys and values of every head are made from the latent rows once; the
+    queries go through in row blocks and the keys in ``KEY_CHUNKS`` chunks
+    (online softmax), so that nothing of (rows x keys x heads) outlives its
+    chunk.  A chunk of keys that lies wholly ahead of a block's rows is
+    skipped, and so is a block of rows wholly past ``length`` (the padded
+    tail of a prompt).  Returns (T, d) in float32, through the output
+    projection."""
+    T = c_q.shape[0]
+    with jax.named_scope("mx.gen.attn"):
+        c_kv, kv_b = latent[:, :c.kv_rank], lp["kv_b_weight"]
+        k_nope = _dot(c_kv, kv_b[..., :c.d_nope], "sr,rhe->she", cdt).astype(cdt)
+        v = _dot(c_kv, kv_b[..., c.d_nope:], "sr,rhe->she", cdt).astype(cdt)
+        k_rope = latent[:, c.kv_rank:c.kv_rank + c.d_rope]
+    scale = (c.d_nope + c.d_rope) ** -0.5
+    key_pos = jnp.arange(T)
+    chunks = KEY_CHUNKS if T % KEY_CHUNKS == 0 else 1
+    span = T // chunks
+    cuts = [slice(j * span, (j + 1) * span) for j in range(chunks)]
+    rows = min(T, 256)
+    while T % rows:
+        rows -= 1
+    H = c.n_heads
+
+    def attend(c_q, q_i, w, pos):
+        last = pos[-1]
+        with jax.named_scope("mx.gen.index"):
+            causal = key_pos[None, :] <= pos[:, None]
+            parts = [lax.cond(cut.start <= last,
+                              lambda cut=cut: _index_scores(q_i, w, k_i[cut]),
+                              lambda: jnp.zeros((rows, span), jnp.float32))
+                     for cut in cuts]
+            scores = jnp.where(causal, jnp.concatenate(parts, axis=-1), -jnp.inf)
+            allowed = causal & _largest_k(scores, c.index_topk)
+        q_nope, q_rope = _queries(c_q, pos, lp, c, cdt)
+
+        def chunk(cut, top, norm, acc):
+            s = (jnp.einsum("the,she->hts", q_nope, k_nope[cut],
+                            preferred_element_type=jnp.float32)
+                 + jnp.einsum("the,se->hts", q_rope, k_rope[cut],
+                              preferred_element_type=jnp.float32)) * scale
+            s = jnp.where(allowed[None, :, cut], s, -jnp.inf)
+            new = jnp.maximum(top, jnp.max(s, axis=-1))
+            # a row that has met no allowed key yet keeps -inf: shift by 0
+            shift = jnp.where(new == -jnp.inf, 0.0, new)
+            keep = jnp.exp(top - shift)
+            p = jnp.exp(s - shift[..., None])
+            pv = jnp.einsum("hts,she->hte", p.astype(cdt), v[cut],
+                            preferred_element_type=jnp.float32)
+            return (new, norm * keep + jnp.sum(p, axis=-1),
+                    acc * keep[..., None] + pv)
+
+        with jax.named_scope("mx.gen.attn"):
+            state = (jnp.full((H, rows), -jnp.inf, jnp.float32),
+                     jnp.zeros((H, rows), jnp.float32),
+                     jnp.zeros((H, rows, c.d_v), jnp.float32))
+            for cut in cuts:
+                state = lax.cond(cut.start <= last,
+                                 lambda st, cut=cut: chunk(cut, *st),
+                                 lambda st: st, state)
+            _top, norm, acc = state
+            o = (acc / norm[..., None]).transpose(1, 0, 2)
+        return _output(o, lp, cdt)
+
+    def block(c_q, q_i, w, pos):
+        return lax.cond(pos[0] < length, lambda: attend(c_q, q_i, w, pos),
+                        lambda: jnp.zeros((rows, c.d_model), jnp.float32))
+
+    return _row_blocks(block, T, rows, c_q, q_i, w, positions)
+
+
+# -- programs ----------------------------------------------------------------
+def _sequence_layers(params, x, config, on_layer, length=None):
+    """All layers over one whole sequence ``x`` (T, d) at positions
+    0..T-1, expanded attention; ``on_layer(i, latent, k_i)`` sees what a
+    cache would hold.  Rows from ``length`` on (a prompt's padded tail) are
+    carried along, not computed."""
+    c = config
+    cdt = jnp.dtype(c.dtype)
+    T = x.shape[0]
+    positions = jnp.arange(T)
+    length = T if length is None else length
+    real = positions < length
+    ffn_rows = min(T, 1024)
+    while T % ffn_rows:
+        ffn_rows -= 1
+
+    def ffn(lp, xb, real):
+        return lax.cond(real[0], lambda: _ffn(xb, lp, c, cdt, real)[0], lambda: xb)
+
+    for i in range(c.n_layers):
+        lp = _layer(params, i, c)
+        h = _rmsnorm(x, lp["attn_norm"], c.norm_eps).astype(cdt)
+        c_q, latent, q_i, k_i, w = _project(h, positions, lp, c, cdt)
+        on_layer(i, latent, k_i)
+        o = _expanded_attention(c_q, q_i, w, positions, length, latent, k_i, lp,
+                                c, cdt)
+        x = x + o.astype(cdt)
+        x = _row_blocks(functools.partial(ffn, lp), T, ffn_rows, x, real)
+    return x
+
+
+def make_forward_fn(config):
+    """fn(params, tokens (T,) int32) -> logits (T, vocab) float32: the
+    one-shot forward in the expanded form, no cache."""
+    c = config
+    cdt = jnp.dtype(c.dtype)
+
+    def forward(params, tokens):
+        x = jnp.take(params["embed_weight"], tokens, axis=0).astype(cdt)
+        x = _sequence_layers(params, x, c, lambda *_: None)
+        return _head(x, params, c, cdt)
+
+    return jax.jit(forward)
+
+
+def make_prefill_fn(config, page_size, mesh=None):
+    """fn(params, cache, tokens (1, S_pad) int32, length () int32,
+    pages (S_pad // page_size,) int32) -> (cache', logits (vocab,) float32).
+
+    One whole prompt, padded to its bucket, through the expanded form with
+    the selection as a mask; every layer's latent rows and index keys are
+    written to the pages named (the padded tail's to the scratch page or to
+    slots a later token overwrites before they are read, as the
+    transformer's prefill leaves them).  Long prompts fit because the two
+    things that grow with rows x keys, the index scores and the attention
+    scores, are made a block of query rows and a chunk of keys at a time,
+    and the FFN goes in row blocks too; blocks of the padded tail and chunks
+    of keys ahead of a block's rows are skipped."""
+    c = config
+    cdt = jnp.dtype(c.dtype)
+    page_size = int(page_size)
+    if mesh is not None:
+        raise NotImplementedError("mla_moe: no sharded bind; one chip holds "
+                                  "its share of the experts")
+
+    def prefill(params, cache, tokens, length, pages):
+        n_pages = tokens.shape[1] // page_size
+        emb = params["embed_weight"]
+        x = jnp.take(emb, jnp.clip(tokens[0], 0, emb.shape[0] - 1), axis=0).astype(cdt)
+        pools = {name: list(layers) for name, layers in cache.items()}
+
+        def write(i, latent, k_i):
+            with jax.named_scope("mx.gen.pool_write"):
+                for name, rows in (("latent", latent), ("index", k_i)):
+                    paged = rows.reshape(n_pages, page_size, -1)
+                    pools[name][i] = pools[name][i].at[pages].set(
+                        paged.astype(pools[name][i].dtype))
+
+        x = _sequence_layers(params, x, c, write, length)
+        last = lax.dynamic_index_in_dim(x, length - 1, axis=0, keepdims=True)
+        return pools, _head(last, params, c, cdt)[0]
+
+    return prefill
+
+
+def make_decode_fn(config, slots, max_pages_per_slot, page_size,
+                   block_k=None, mesh=None):
+    """fn(params, cache, tokens (S,), positions (S,), block_tables
+    (S, max_pages_per_slot), active (S,)) -> (cache', (logits (S, vocab)
+    float32, counters (len(DECODE_COUNTERS),) int32)).
+
+    One token a slot: its latent row and index key are written in place at
+    ``block_tables[b, positions[b] // page_size]``; the slot's cached index
+    keys are scored, the ``index_topk`` best causal positions kept
+    (``lax.top_k``), their latent rows gathered through the block table, and
+    attended in the absorbed form.  Inactive slots write to the scratch
+    page, attend nothing that counts and get zero logits."""
+    c = config
+    cdt = jnp.dtype(c.dtype)
+    page_size = int(page_size)
+    max_ctx = int(max_pages_per_slot) * page_size
+    topk = min(c.index_topk, max_ctx)
+    scale = (c.d_nope + c.d_rope) ** -0.5
+    if mesh is not None:
+        raise NotImplementedError("mla_moe: no sharded bind; one chip holds "
+                                  "its share of the experts")
+
+    def attend(cache, i, c_q, q_i, w, positions, lengths, block_tables, lp):
+        S = c_q.shape[0]
+        with jax.named_scope("mx.gen.index"):
+            keys = cache["index"][i][block_tables].reshape(S, max_ctx, -1)
+            valid = jnp.arange(max_ctx)[None, :] < lengths[:, None]
+            scores = jnp.where(valid, _index_scores(q_i, w, keys), -jnp.inf)
+            top, chosen = lax.top_k(scores, topk)                # (S, topk)
+            kept = top > -jnp.inf
+        q_nope, q_rope = _queries(c_q, positions, lp, c, cdt)
+        with jax.named_scope("mx.gen.attn"):
+            page = jnp.take_along_axis(block_tables, chosen // page_size, axis=1)
+            rows = cache["latent"][i].reshape(-1, _latent_width(c))[
+                page * page_size + chosen % page_size]           # (S, topk, w)
+            c_kv = rows[..., :c.kv_rank]
+            k_rope = rows[..., c.kv_rank:c.kv_rank + c.d_rope]
+            kv_b = lp["kv_b_weight"].astype(cdt)
+            q_lat = jnp.einsum("she,rhe->shr", q_nope, kv_b[..., :c.d_nope],
+                               preferred_element_type=jnp.float32).astype(cdt)
+            s = (jnp.einsum("shr,skr->shk", q_lat, c_kv,
+                            preferred_element_type=jnp.float32)
+                 + jnp.einsum("she,ske->shk", q_rope, k_rope,
+                              preferred_element_type=jnp.float32)) * scale
+            s = jnp.where(kept[:, None, :], s, -jnp.inf)
+            # an inactive slot keeps nothing: its row is all -inf
+            p = jnp.where(kept[:, None, :], jax.nn.softmax(s, axis=-1), 0.0)
+            o_lat = jnp.einsum("shk,skr->shr", p.astype(cdt), c_kv,
+                               preferred_element_type=jnp.float32).astype(cdt)
+            o = jnp.einsum("shr,rhe->she", o_lat, kv_b[..., c.d_nope:],
+                           preferred_element_type=jnp.float32)
+        return _output(o, lp, cdt), jnp.sum(kept)
+
+    def decode(params, cache, tokens, positions, block_tables, active):
+        emb = params["embed_weight"]
+        x = jnp.take(emb, jnp.clip(tokens, 0, emb.shape[0] - 1), axis=0).astype(cdt)
+        page = jnp.take_along_axis(block_tables, (positions // page_size)[:, None],
+                                   axis=1)[:, 0]
+        page = jnp.where(active, page, 0)        # inactive slots write to scratch
+        offset = positions % page_size
+        lengths = jnp.where(active, positions + 1, 0)
+        pools = {name: list(layers) for name, layers in cache.items()}
+        total = {k: jnp.int32(0) for k in DECODE_COUNTERS}
+        for i in range(c.n_layers):
+            lp = _layer(params, i, c)
+            h = _rmsnorm(x, lp["attn_norm"], c.norm_eps).astype(cdt)
+            c_q, latent, q_i, k_i, w = _project(h, positions, lp, c, cdt)
+            with jax.named_scope("mx.gen.pool_write"):
+                pools["latent"][i] = pools["latent"][i].at[page, offset].set(
+                    latent.astype(pools["latent"][i].dtype))
+                pools["index"][i] = pools["index"][i].at[page, offset].set(
+                    k_i.astype(pools["index"][i].dtype))
+            o, selected = attend(pools, i, c_q, q_i, w, positions, lengths,
+                                 block_tables, lp)
+            x, counts = _ffn(x + o.astype(cdt), lp, c, cdt, active)
+            counts["dsa_keys_scanned"] = jnp.sum(lengths)
+            counts["dsa_keys_selected"] = selected
+            for k, v in counts.items():
+                total[k] = total[k] + v.astype(jnp.int32)
+        logits = jnp.where(active[:, None], _head(x, params, c, cdt), 0.0)
+        counters = jnp.stack([total[k] for k in DECODE_COUNTERS])
+        return pools, (logits, counters)
+
+    return decode

@@ -48,7 +48,7 @@ __all__ = ["TransformerConfig", "init_params", "param_specs", "make_loss_fn",
            "make_train_step", "make_forward_fn", "init_kv_cache",
            "make_prefill_fn", "make_decode_fn", "make_extend_fn",
            "draft_from_layers", "decode_schedule_shape",
-           "block_collective_counts", "kv_cache_spec"]
+           "block_collective_counts", "kv_cache_spec", "kv_page_bytes"]
 
 
 def _mp_axis(axes):
@@ -964,3 +964,11 @@ def draft_from_layers(config, params, n_layers):
               "final_ln_gamma", "final_ln_beta")
     dparams = {k: (v if k in shared else v[:n]) for k, v in params.items()}
     return dataclasses.replace(config, n_layers=n), dparams
+
+
+def kv_page_bytes(config, page_size):
+    """Bytes one page of :func:`init_kv_cache`'s pool holds over all
+    layers: keys and values of every head, in the compute type."""
+    c = config
+    return (c.n_layers * 2 * int(page_size) * c.n_heads
+            * (c.d_model // c.n_heads) * jnp.dtype(c.dtype).itemsize)

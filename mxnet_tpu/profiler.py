@@ -65,7 +65,12 @@ Inside the compiled programs the same naming is ``jax.named_scope`` metadata
 ``mx.gen.pool_write``, ``mx.gen.attn``, and ``mx.gen.gather_kv`` where a
 program still gathers a slot's pages: extend, and decode off the TPU) and the
 kernels' own names (``mx_flash_fwd``, ``mx_flash_dq``, ``mx_flash_dkv``,
-``mx_paged_decode``).
+``mx_paged_decode``).  The latent-attention expert model
+(``models/mla_moe.py``) adds ``mx.gen.latent_proj`` (the low-rank query and
+key-value projections), ``mx.gen.index`` (the indexer: its projections, the
+index scores and the top-k), ``mx.lm.moe.route``, ``mx.lm.moe.experts`` and
+``mx.lm.moe.shared``; its ``mx.gen.attn`` is the sparse absorbed attention
+with the gather of the selected latent rows.
 """
 from __future__ import annotations
 
@@ -732,6 +737,17 @@ _GEN_ZERO = {
     # tokens (their ratio rides generate_stats as acceptance_rate) and
     # verify rounds run
     "draft_proposed": 0, "draft_accepted": 0, "spec_rounds": 0,
+    # counted on the device by a decode program that routes tokens to the
+    # experts this chip holds and selects keys (``DECODE_COUNTERS`` of the
+    # model module), summed over the step's layers: token-expert pairs that
+    # fell on held experts, token-layers routed, held experts with at least
+    # one pair, the pairs there would be were every held expert as full as
+    # the fullest (over ``moe_pairs_held`` it rides generate_stats as
+    # moe_expert_load_max_over_mean); cached index keys scored and keys
+    # kept for attention
+    "moe_pairs_held": 0, "moe_tokens": 0, "moe_experts_touched": 0,
+    "moe_pairs_at_max_load": 0,
+    "dsa_keys_scanned": 0, "dsa_keys_selected": 0,
 }
 _GEN_FLOATS = ("prefill_seconds", "decode_seconds", "loop_seconds",
                "stream_seconds")
@@ -826,6 +842,9 @@ def generate_stats(reset=False):
     if snap["decode_kv_pages_spanned"]:
         snap["decode_kv_read_share"] = (
             snap["decode_kv_pages_read"] / snap["decode_kv_pages_spanned"])
+    if snap["moe_pairs_held"]:
+        snap["moe_expert_load_max_over_mean"] = (
+            snap["moe_pairs_at_max_load"] / snap["moe_pairs_held"])
     return snap
 
 

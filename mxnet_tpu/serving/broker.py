@@ -999,16 +999,16 @@ class GenerateServer:
             upto = min(int(self._positions[slot]) + headroom,
                        pred.max_ctx - 1)
             try:
-                for pidx in range(upto // pred.page_size + 1):
-                    if self._block_tables[slot, pidx] != 0:
-                        continue
+                # a slot's table is filled from entry 0 in the order of its
+                # page list, so the first entry to look at is the list's
+                # length: a long context is not walked again every step
+                for pidx in range(len(r.pages), upto // pred.page_size + 1):
                     page, = self._alloc_pages(1)
                     r.pages.append(page)
                     self._block_tables[slot, pidx] = page
                 if self._draft is not None:
-                    for pidx in range(upto // pred.page_size + 1):
-                        if self._draft_bt[slot, pidx] != 0:
-                            continue
+                    for pidx in range(len(r.draft_pages),
+                                      upto // pred.page_size + 1):
                         page, = self._draft.pool.alloc(1)
                         r.draft_pages.append(page)
                         self._draft_bt[slot, pidx] = page
@@ -1044,7 +1044,8 @@ class GenerateServer:
                 len(active), len(active), time.perf_counter() - t0,
                 decode_kv_pages_read=int(np.sum(
                     -(-self._positions[active] // pred.page_size))),
-                decode_kv_pages_spanned=pred.slots * pred.max_pages_per_slot)
+                decode_kv_pages_spanned=pred.slots * pred.max_pages_per_slot,
+                **pred.step_counters)
             with profiler.span("mx.serve.decode.sample"):
                 for slot in active:
                     r = self._slot_req[slot]

@@ -359,16 +359,39 @@ class PrefixIndex:
                     "max_pages": self.max_pages}
 
 
+def _model_module(config_):
+    """The module that offers a configuration's cache and programs:
+    the one its ``module`` attribute names, else the transformer.  A model
+    module offers ``init_kv_cache`` (any pytree of page pools under one
+    block table), ``kv_page_bytes``, ``make_prefill_fn`` and
+    ``make_decode_fn``; optionally ``make_extend_fn`` (prefix tails,
+    speculation), ``_decode_block_k`` and ``DECODE_COUNTERS``, the names
+    of what its decode program counts on the device and returns beside the
+    logits."""
+    name = getattr(config_, "module", None)
+    if name is None:
+        from ..models import transformer
+
+        return transformer
+    import importlib
+
+    return importlib.import_module(name)
+
+
 class GenerativePredictor:
-    """One transformer bound for prefill + single-token decode.
+    """One model bound for prefill + single-token decode.
 
     Parameters
     ----------
-    config_ : models.transformer.TransformerConfig
+    config_ : models.transformer.TransformerConfig, or any configuration
+        whose ``module`` attribute names its model module
+        (:func:`_model_module`, e.g. ``models.mla_moe.LatentMoEConfig``)
         The model architecture (``dtype`` is the cache/compute dtype).
     params : dict
         ``init_params``-layout arrays (numpy or jax); frozen onto the
-        device once.
+        device once.  A ``jax.Array`` is bound as it is given, in its
+        own dtype and without a copy through the host (bfloat16 weights
+        stay bfloat16); anything else is copied to the device.
     slots : int, optional
         Batch-slot count of the decode program
         (``MXNET_GENERATE_SLOTS``).
@@ -408,8 +431,7 @@ class GenerativePredictor:
         import jax
         import jax.numpy as jnp
 
-        from ..models import transformer as tfm
-
+        tfm = self._model = _model_module(config_)
         self.config = config_
         self.slots = _env_positive_int("MXNET_GENERATE_SLOTS") \
             if slots is None else int(slots)
@@ -431,10 +453,8 @@ class GenerativePredictor:
         self.max_ctx = self.max_pages_per_slot * self.page_size
 
         c = config_
-        dh = c.d_model // c.n_heads
         cdt = jnp.dtype(c.dtype)
-        self.page_bytes = (c.n_layers * 2 * self.page_size * c.n_heads * dh
-                           * cdt.itemsize)
+        self.page_bytes = int(tfm.kv_page_bytes(c, self.page_size))
         if pool_bytes is None:
             pool_bytes = _env_nonneg_int("MXNET_GENERATE_POOL_BYTES")
         pool_bytes = int(pool_bytes or 0)
@@ -490,13 +510,21 @@ class GenerativePredictor:
                            tfm.kv_cache_spec(mesh))
         else:
             def put(a):
-                a = jnp.asarray(np.asarray(a))
+                if not isinstance(a, jax.Array):
+                    a = jnp.asarray(np.asarray(a))
                 return jax.device_put(a, device) if device is not None else a
 
             self._params = {k: put(v) for k, v in params.items()}
-            self._kv = put(tfm.init_kv_cache(c, num_pages, self.page_size))
-        self.block_k = int(block_k) if block_k is not None \
-            else tfm._decode_block_k(c, self.slots, self.max_ctx)
+            self._kv = jax.tree.map(
+                put, tfm.init_kv_cache(c, num_pages, self.page_size))
+        if block_k is None:
+            pick = getattr(tfm, "_decode_block_k", None)
+            block_k = pick(c, self.slots, self.max_ctx) if pick else 0
+        self.block_k = int(block_k)
+        # what the decode program counts on the device, read with the
+        # logits: the names, and the last step's values for the broker
+        self._counter_names = tuple(getattr(tfm, "DECODE_COUNTERS", ()))
+        self.step_counters = {}
 
         # prefill bucket ladder: page-aligned powers of two up to the
         # context bound (the PR 6 ladder idea at page granularity)
@@ -530,8 +558,7 @@ class GenerativePredictor:
                 self.page_size, self.max_pages_per_slot, self.block_k, mesh)
 
     def _prefill_exec(self, bucket):
-        from ..models import transformer as tfm
-
+        tfm = self._model
         key = (self._cache_key, ("prefill", bucket),
                self._config_fingerprint(), self._dtype_name)
         return self._exec_cache.get_or_build(
@@ -539,8 +566,7 @@ class GenerativePredictor:
                 self.config, self.page_size, mesh=self._mesh)))
 
     def _decode_exec(self):
-        from ..models import transformer as tfm
-
+        tfm = self._model
         key = (self._cache_key, ("decode", self.slots),
                self._config_fingerprint(), self._dtype_name)
         return self._exec_cache.get_or_build(
@@ -549,8 +575,11 @@ class GenerativePredictor:
                 self.page_size, block_k=self.block_k, mesh=self._mesh)))
 
     def _extend_exec(self, batch, steps):
-        from ..models import transformer as tfm
-
+        tfm = self._model
+        if not hasattr(tfm, "make_extend_fn"):
+            raise GenerateError(
+                "%s offers no extend program (prefix tails and speculation "
+                "need one)" % tfm.__name__)
         key = (self._cache_key, ("extend", batch, steps),
                self._config_fingerprint(), self._dtype_name)
         return self._exec_cache.get_or_build(
@@ -606,6 +635,10 @@ class GenerativePredictor:
                 np.asarray(positions, np.int32),
                 np.asarray(block_tables, np.int32),
                 np.asarray(active, bool))
+        if self._counter_names:
+            logits, counts = logits
+            self.step_counters = dict(zip(self._counter_names,
+                                          np.asarray(counts).tolist()))
         return np.asarray(logits)
 
     def extend(self, tokens, positions, block_tables, valid):

@@ -394,6 +394,50 @@ def test_decode_through_the_kernel_names_it_and_gathers_nothing(model, monkeypat
     assert "mx.gen.gather_kv" not in text
 
 
+def test_latent_expert_programs_carry_their_scope_names():
+    """ISSUE 36: the scopes the decode-pool cell's per-layer metrics read."""
+    import jax
+    import jax.numpy as jnp
+
+    from mxnet_tpu.models import mla_moe
+
+    cfg = mla_moe.LatentMoEConfig(
+        vocab=32, d_model=32, n_heads=2, n_layers=2, n_dense_layers=1, d_ff=48,
+        d_expert=16, n_experts=8, experts_per_token=2, held_experts=(0, 1),
+        q_rank=16, kv_rank=8, d_nope=4, d_rope=4, d_v=8, index_heads=2, index_dim=8,
+        index_rope_dim=4, index_topk=4, max_len=32, dtype="float32")
+    params = mla_moe.init_params(cfg)
+    cache = mla_moe.init_kv_cache(cfg, num_pages=8, page_size=4)
+    decode = jax.jit(mla_moe.make_decode_fn(cfg, 2, 4, 4))
+    text = _op_names(decode.lower(
+        params, cache, jnp.zeros((2,), jnp.int32), jnp.zeros((2,), jnp.int32),
+        jnp.zeros((2, 4), jnp.int32), jnp.ones((2,), bool)))
+    scopes = ("mx.gen.latent_proj", "mx.gen.index", "mx.gen.attn", "mx.gen.pool_write",
+              "mx.lm.moe.route", "mx.lm.moe.experts", "mx.lm.moe.shared", "mx.lm.ffn")
+    for scope in scopes:
+        assert scope in text, scope
+    assert "mx.gen.gather_kv" not in text
+    prefill = jax.jit(mla_moe.make_prefill_fn(cfg, 4))
+    text = _op_names(prefill.lower(
+        params, cache, jnp.zeros((1, 8), jnp.int32), jnp.int32(5),
+        jnp.zeros((2,), jnp.int32)))
+    for scope in scopes:
+        assert scope in text, scope
+
+
+def test_device_counters_of_a_decode_step_ride_generate_stats():
+    from mxnet_tpu.models import mla_moe
+
+    profiler.generate_record(**{k: 2 for k in mla_moe.DECODE_COUNTERS})
+    profiler.generate_record(moe_pairs_held=6, moe_pairs_at_max_load=10)
+    st = profiler.generate_stats(reset=True)
+    assert all(st[k] >= 2 for k in mla_moe.DECODE_COUNTERS)
+    # 8 pairs on held experts; 12 were every one as full as the fullest
+    assert st["moe_expert_load_max_over_mean"] == pytest.approx(12 / 8)
+    profiler.generate_record(decode_steps=1)
+    assert "moe_expert_load_max_over_mean" not in profiler.generate_stats(reset=True)
+
+
 def test_symbolic_train_step_tells_forward_backward_and_update_apart():
     import jax
 
