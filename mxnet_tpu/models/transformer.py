@@ -76,8 +76,8 @@ class TransformerConfig:
     remat: bool = False
     # flash-attention schedule parameters (ISSUE 10): None consults the
     # on-disk schedule table at trace time (tune.schedule_for, keyed by
-    # this model's attention shape/dtype/backend) and falls back to the
-    # MXU-native 128; an explicit value pins the block
+    # this model's attention shape/dtype/backend) and falls back to a
+    # block derived from the sequence; an explicit value pins the block
     attn_block_q: int | None = None
     attn_block_k: int | None = None
 
@@ -159,8 +159,8 @@ def _attention(q, k, v, *, axes, causal=True, attn="auto", blocks=None):
     if attn == "auto":
         attn = "ring" if has_sp else "flash"
     if not has_sp:
-        # flash_attention pads the head dim to the 128-lane tile internally,
-        # so common head dims (64, 80, ...) all take the O(S)-memory kernel.
+        # flash_attention runs the head dim as it is (a whole last dim is a
+        # legal block), so head dims 64, 80, ... all take the O(S) kernel.
         # The materialized-scores reference is the cpu test path only:
         # kernel_platform() raises on any backend that is neither.
         if attn == "flash" and kernel_platform() == "tpu":

@@ -93,3 +93,95 @@ def test_flash_attention_uneven_q_and_bf16():
     ref = full_attention(qb, kb, vb, causal=True)
     assert np.abs(np.asarray(out.astype(jnp.float32))
                   - np.asarray(ref.astype(jnp.float32))).max() < 0.05
+
+
+def _flash_module():
+    """The kernels' module (``mxnet_tpu.kernels`` exports a function of the
+    same name over it)."""
+    import sys
+
+    import mxnet_tpu.kernels  # noqa: F401
+    return sys.modules["mxnet_tpu.kernels.flash_attention"]
+
+
+def _flash_and_grads(attend, q, k, v, do):
+    out, vjp = jax.vjp(attend, q, k, v)
+    return (out,) + vjp(do.astype(out.dtype))
+
+
+# bfloat16 keeps 8 bits: one rounding moves a number by at most 2**-8 of
+# itself, and out / p / ds are each rounded once on top of the inputs'
+_BF16_TOL = 2 * float(jnp.finfo(jnp.bfloat16).eps)
+
+
+@pytest.mark.parametrize("d", [32, 64])
+@pytest.mark.parametrize("seq", [(1024, 1024), (512, 512), (200, 256),
+                                 (32, 32)],
+                         ids=["several_blocks", "512", "uneven", "one_block"])
+@pytest.mark.parametrize("causal", [False, True])
+def test_flash_attention_bf16_matches_float32_reference(causal, seq, d):
+    """bfloat16 operands into every product, float32 statistics: forward
+    and the three gradients against ``full_attention`` in float32 on the
+    same rounded inputs, under the blocks derived from the shape."""
+    sq, sk = seq
+    rng = np.random.RandomState(sq + d)
+    q, k, v, do = (
+        jnp.asarray(rng.randn(1, 2, s, d).astype(np.float32)
+                    ).astype(jnp.bfloat16)
+        for s in (sq, sk, sk, sq))
+    got = _flash_and_grads(
+        lambda *a: flash_attention(*a, causal=causal), q, k, v, do)
+    want = _flash_and_grads(
+        lambda *a: full_attention(*a, causal=causal),
+        *(x.astype(jnp.float32) for x in (q, k, v)), do)
+    for name, g, w in zip(("out", "dq", "dk", "dv"), got, want):
+        assert g.dtype == jnp.bfloat16 and g.shape == w.shape, name
+        w = np.asarray(w)
+        err = np.abs(np.asarray(g.astype(jnp.float32)) - w).max()
+        assert err <= _BF16_TOL * np.abs(w).max(), (name, err)
+
+
+@pytest.mark.parametrize("blocks", [(32, 16), (16, 32), (32, 32)])
+@pytest.mark.parametrize("causal", [False, True])
+def test_flash_attention_masks_across_small_blocks(causal, blocks):
+    """100 queries and keys in blocks of 16 / 32: blocks below the diagonal,
+    on it and skipped above it, and a last key block that holds padding
+    (``kv_len`` < ``seq_k``). Forward and the three gradients."""
+    q, k, v = _qkv(B=1, H=2, S=100, D=16)
+    do = _qkv(B=1, H=2, S=100, D=16, seed=1)[0]
+    got = _flash_and_grads(
+        lambda *a: flash_attention(*a, causal=causal, block_q=blocks[0],
+                                   block_k=blocks[1]), q, k, v, do)
+    want = _flash_and_grads(lambda *a: full_attention(*a, causal=causal),
+                            q, k, v, do)
+    for g, w in zip(got, want):
+        np.testing.assert_allclose(np.asarray(g), np.asarray(w),
+                                   atol=5e-5, rtol=5e-5)
+
+
+@pytest.mark.parametrize("causal", [False, True])
+def test_flash_key_blocks_skip_only_hidden_blocks(causal):
+    """``_key_blocks`` against the mask itself: every block it leaves out
+    is wholly hidden, and under the causal mask the last one it visits is
+    not."""
+    fa = _flash_module()
+    seq_q, seq_k = 96, 128
+    rows = np.arange(seq_q)[:, None]
+    cols = np.arange(seq_k)[None, :]
+    ok = np.broadcast_to((rows >= cols) if causal else True, (seq_q, seq_k))
+    for bq, bk in [(16, 32), (32, 16), (32, 32), (48, 64)]:
+        for iq in range(seq_q // bq):
+            n_kb = int(fa._key_blocks(iq, bq, bk, seq_k, causal))
+            assert 1 <= n_kb <= seq_k // bk
+            visible = ok[iq * bq:(iq + 1) * bq]
+            assert not visible[:, n_kb * bk:].any()
+            assert visible[:, (n_kb - 1) * bk:n_kb * bk].any()
+
+
+@pytest.mark.parametrize("seq, block, padded", [
+    (1, 1, 1), (32, 32, 32), (200, 200, 200), (512, 512, 512),
+    (600, 128, 640), (1024, 512, 1024), (1536, 512, 1536),
+    (1280, 256, 1280), (2048, 512, 2048), (2050, 128, 2176)])
+def test_flash_derived_block_tiles_the_padded_sequence(seq, block, padded):
+    assert _flash_module()._derived_block(seq) == block
+    assert -(-seq // block) * block == padded and padded - seq < 128

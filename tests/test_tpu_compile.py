@@ -102,3 +102,59 @@ def test_opt_1_3b_decode_holds_one_pool_and_reads_it_in_place(one_chip, on_tpu):
                          % shape, text)
     # nor one of the gathered keys' and values' size
     assert "bf16[16,32,1216,64]" not in text
+
+
+def test_lm_cell_attention_feeds_three_flash_kernels_head_dim_64(one_chip,
+                                                                 on_tpu):
+    # benchmark/configs/opt-1.3b-train.json at the cell's batch: what
+    # make_train_step's _block hands _attention, forward and backward
+    shape = jax.ShapeDtypeStruct((2, 32, 2048, 64), jnp.bfloat16,
+                                 sharding=one_chip)
+
+    def attend(q, k, v):
+        return tfm._attention(q, k, v, axes=(), causal=True)
+
+    def step(q, k, v, do):
+        out, vjp = jax.vjp(attend, q, k, v)
+        return (out,) + vjp(do)
+
+    compiled = jax.jit(step).lower(shape, shape, shape, shape).compile()
+    text = compiled.as_text()
+    calls = [line for line in text.splitlines()
+             if 'custom_call_target="tpu_custom_call"' in line]
+    # one call each, named by pl.pallas_call(name=): the benchmark's
+    # flash_fwd / flash_dq / flash_dkv_device_ms_per_step match on these
+    for name in ("mx_flash_fwd", "mx_flash_dq", "mx_flash_dkv"):
+        assert sum(name in line for line in calls) == 1, name
+    assert len(calls) == 3
+    for line in calls:
+        operands = line.split("operand_layout_constraints={")[1].split("}, ")[0]
+        # q, k, v, do as they arrive: head dim 64, no pad to 128 lanes
+        assert "bf16[64,2048,64]" in operands
+        assert "bf16[64,2048,128]" not in line
+    assert not re.search(r"bf16\[[\d,]*,128\]\S* pad\(", text)
+    # lse and delta have the sequence minor: a trailing dim of 1 would be
+    # padded to 128 lanes (67 MB a layer where 0.5 MB is data)
+    assert "f32[64,1,2048]" in text and "f32[64,2048,1]" not in text
+    # every block fits the default scoped VMEM (compile() raised otherwise),
+    # and nothing of the size of the scores is held between the kernels
+    assert compiled.memory_analysis().temp_size_in_bytes < 2 * 64 * 2048 * 2048
+
+
+@pytest.mark.parametrize("dtype", [jnp.bfloat16, jnp.float32])
+def test_flash_kernels_compile_under_an_ambient_highest_precision(
+        one_chip, on_tpu, dtype):
+    # chip_smoke's parity check runs the kernels inside
+    # jax.default_matmul_precision("highest"): Mosaic refuses an fp32
+    # contraction of bfloat16 operands ("Bad lhs type"), so the kernels'
+    # bfloat16 products must not inherit it; float32 operands may
+    shape = jax.ShapeDtypeStruct((1, 8, 1024, 64), dtype, sharding=one_chip)
+
+    def step(q, k, v, do):
+        out, vjp = jax.vjp(
+            lambda *a: tfm._attention(*a, axes=(), causal=True), q, k, v)
+        return (out,) + vjp(do)
+
+    with jax.default_matmul_precision("highest"):
+        text = jax.jit(step).lower(shape, shape, shape, shape).compile().as_text()
+    assert text.count('custom_call_target="tpu_custom_call"') == 3
