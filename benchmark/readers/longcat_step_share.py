@@ -1,0 +1,125 @@
+"""Shares of the chip's peaks for the LongCat-Flash decode step and its
+distinctive parts, from counts of the work the mathematics needs (whatever
+implements it) over device time in the traced segment.  ``args.of``:
+
+- ``step_flops``   the whole step's operations over the bf16 peak and the
+  device time of the programs matching ``args.match``;
+- ``step_bytes``   the least bytes any build must read a step over the
+  memory's peak and the same device time;
+- ``attn``         the dense absorbed attention's roofline share (the larger
+  of its operations over the bf16 peak and its bytes over the memory's peak)
+  over the device self time under the named scope ``args.scope``;
+- ``experts``      the held routed experts' roofline share, likewise.
+
+The counts come from the configuration's ``program`` group and from what the
+program counted over the segment (``profiler.generate_stats``, through the
+runner): slot-steps that held a stream, decode steps, token-expert pairs that
+fell on held experts, held experts touched, cached latent rows attended
+(summed over the sublayers).  Percent, never clipped; nothing where the
+trace, the scope or a counter is absent, or the configuration is not of this
+kind (no identity experts in its ``program``).
+"""
+from benchmark.readers.glm5_step_share import scope_seconds
+from benchmark.trace_reduce import matching
+
+NEEDED = ("active_slot_steps", "decode_steps", "moe_pairs_held", "moe_experts_touched",
+          "attn_rows_read")
+
+
+# -- what the mathematics needs, from shapes alone ---------------------------
+def expert_params(m):
+    """One routed expert: gate, up and down."""
+    return 3 * m["d_model"] * m["d_expert"]
+
+
+def kv_b_params(m):
+    """The key-value up-projection of one attention, which the absorbed form
+    applies to the query (its key half) and to the attended latent (its
+    value half)."""
+    return m["kv_rank"] * m["n_heads"] * (m["d_nope"] + m["d_v"])
+
+
+def sublayer_matrix_params(m):
+    """One latent attention (low-rank and output projections) with the dense
+    FFN after it."""
+    d, h = m["d_model"], m["n_heads"]
+    attn = (d * m["q_rank"] + m["q_rank"] * h * (m["d_nope"] + m["d_rope"])
+            + d * (m["kv_rank"] + m["d_rope"]) + kv_b_params(m) + h * m["d_v"] * d)
+    return attn + 3 * d * m["d_ff"]
+
+
+def token_matrix_params(m):
+    """Matrix parameters a token meets over all double layers and the head,
+    its pairs on routed experts apart: two sublayers and the router a layer."""
+    router = m["d_model"] * (m["n_experts"] + m["n_zero_experts"])
+    return (m["n_layers"] * (2 * sublayer_matrix_params(m) + router)
+            + m["vocab"] * m["d_model"])
+
+
+def attn_core_flops(m, rows):
+    """Scores against the latent row and the shared rotary key, then P c_kv,
+    a head a cached row."""
+    return 2 * m["n_heads"] * (2 * m["kv_rank"] + m["d_rope"]) * rows
+
+
+def row_bytes(m, width=2):
+    """A cached row as the mathematics needs it: c_kv and the rotary key."""
+    return width * (m["kv_rank"] + m["d_rope"])
+
+
+def step_flops(m, w):
+    return (2 * token_matrix_params(m) * w["active_slot_steps"]
+            + 2 * expert_params(m) * w["moe_pairs_held"]
+            + attn_core_flops(m, w["attn_rows_read"]))
+
+
+def step_bytes(m, w, width=2):
+    """Every matrix outside the routed experts once a decode step, each
+    touched held expert once, every attended row once a sublayer."""
+    return (width * (token_matrix_params(m) * w["decode_steps"]
+                     + expert_params(m) * w["moe_experts_touched"])
+            + row_bytes(m, width) * w["attn_rows_read"])
+
+
+def attn_cost(m, w, width=2):
+    """The dense absorbed attention of all ``2 n_layers`` sublayers: the
+    absorbing products with ``W_kvb`` a token a sublayer and the core a cached
+    row; bytes are ``W_kvb`` once a sublayer a step and the attended rows."""
+    sublayers = 2 * m["n_layers"]
+    flops = (2 * kv_b_params(m) * sublayers * w["active_slot_steps"]
+             + attn_core_flops(m, w["attn_rows_read"]))
+    bytes_ = (width * kv_b_params(m) * sublayers * w["decode_steps"]
+              + row_bytes(m, width) * w["attn_rows_read"])
+    return flops, bytes_
+
+
+def experts_cost(m, w, width=2):
+    """The routed experts held here: a pair's three products, a touched
+    expert's three matrices."""
+    return (2 * expert_params(m) * w["moe_pairs_held"],
+            width * expert_params(m) * w["moe_experts_touched"])
+
+
+# -- the reader --------------------------------------------------------------
+def read(ctx, args):
+    trace, seg, peaks = ctx["trace"], ctx["segment"], ctx["peaks"]
+    if not trace or not seg or not peaks:
+        return None
+    m = ctx["cell"].config.get("program", {})
+    w = seg["work"]
+    if any(k not in w for k in NEEDED) or "n_zero_experts" not in m \
+            or w["decode_steps"] <= 0:
+        return None
+    flop_s, byte_s = peaks["bf16_flops_per_s"], peaks["hbm_bytes_per_s"]
+    if args["of"] in ("step_flops", "step_bytes"):
+        seconds, runs = matching(trace["modules"], args["match"])
+        if runs == 0 or seconds <= 0:
+            return None
+        least = step_flops(m, w) / flop_s if args["of"] == "step_flops" \
+            else step_bytes(m, w) / byte_s
+        return 100.0 * least / (seconds * ctx["cell"].chips)
+    seconds = scope_seconds(ctx, args["scope"])
+    if not seconds:
+        return None
+    flops, bytes_ = attn_cost(m, w) if args["of"] == "attn" else experts_cost(m, w)
+    return 100.0 * max(flops / flop_s, bytes_ / byte_s) / seconds

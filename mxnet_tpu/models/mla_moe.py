@@ -249,20 +249,29 @@ def _layer(params, i, config):
     return lp
 
 
-def _project(h, positions, lp, c, cdt):
-    """The low-rank projections and the indexer's of rows ``h`` (T, d) at
-    ``positions`` (T,): ``c_q`` (T, q_rank), the latent row as it is cached
-    (T, :func:`_latent_width`), ``q_I`` (T, heads, dim), ``k_I`` (T, dim), all in the compute
-    type, and the indexer's head weights ``w`` (T, heads) in float32."""
+def _latent_project(h, positions, lp, c, cdt, kv_scale=None):
+    """The low-rank projections of rows ``h`` (T, d) at ``positions`` (T,):
+    ``c_q`` (T, q_rank) and the latent row as it is cached
+    (T, :func:`_latent_width`), both in the compute type.  ``kv_scale``
+    multiplies the normed ``c_kv`` (not the rotary key) before the cast."""
     with jax.named_scope("mx.gen.latent_proj"):
         c_q = _rmsnorm(_dot(h, lp["q_a_weight"], "td,dr->tr", cdt),
                        lp["q_a_norm"], c.norm_eps).astype(cdt)
         kv = _dot(h, lp["kv_a_weight"], "td,dr->tr", cdt)
         c_kv = _rmsnorm(kv[:, :c.kv_rank], lp["kv_a_norm"], c.norm_eps)
+        if kv_scale is not None:
+            c_kv = c_kv * kv_scale
         k_rope = _rope(kv[:, c.kv_rank:], positions, c.rope_theta)
         pad = jnp.zeros((h.shape[0], _latent_width(c) - c.kv_rank - c.d_rope),
                         jnp.float32)
         latent = jnp.concatenate([c_kv, k_rope, pad], axis=-1).astype(cdt)
+    return c_q, latent
+
+
+def _index_project(h, c_q, positions, lp, c, cdt):
+    """The indexer's projections of rows ``h`` (T, d) with their ``c_q``:
+    ``q_I`` (T, heads, dim) and ``k_I`` (T, dim) in the compute type, and the
+    head weights ``w`` (T, heads) in float32."""
     with jax.named_scope("mx.gen.index"):
         r = c.index_rope_dim
         q_i = _dot(c_q, lp["index_q_weight"], "tr,rhe->the", cdt)
@@ -275,15 +284,37 @@ def _project(h, positions, lp, c, cdt):
                                k_i[:, r:]], axis=-1).astype(cdt)
         w = _dot(h, lp["index_w_weight"], "td,dh->th", cdt) \
             * (c.index_heads ** -0.5 * c.index_dim ** -0.5)
-    return c_q, latent, q_i, k_i, w
+    return q_i, k_i, w
 
 
-def _queries(c_q, positions, lp, c, cdt):
-    """(T, H, d_nope) and rotated (T, H, d_rope) queries from ``c_q``."""
+def _project(h, positions, lp, c, cdt):
+    """:func:`_latent_project` and :func:`_index_project` of rows ``h``:
+    ``c_q``, the latent row, ``q_I``, ``k_I`` and ``w``."""
+    c_q, latent = _latent_project(h, positions, lp, c, cdt)
+    return (c_q, latent) + _index_project(h, c_q, positions, lp, c, cdt)
+
+
+def _queries(c_q, positions, lp, c, cdt, scale=None):
+    """(T, H, d_nope) and rotated (T, H, d_rope) queries from ``c_q``, both
+    times ``scale`` where one is given."""
     with jax.named_scope("mx.gen.latent_proj"):
         q = _dot(c_q, lp["q_b_weight"], "tr,rhe->the", cdt)
+        if scale is not None:
+            q = q * scale
         q_rope = _rope(q[..., c.d_nope:], positions, c.rope_theta)
         return q[..., :c.d_nope].astype(cdt), q_rope.astype(cdt)
+
+
+def _k_b(lp, c):
+    """The key half of the up-projection, (kv_rank, H, d_nope): a leaf of
+    its own where the layer keeps the halves apart, else the head of
+    ``kv_b_weight``."""
+    return lp["k_b_weight"] if "k_b_weight" in lp else lp["kv_b_weight"][..., :c.d_nope]
+
+
+def _v_b(lp, c):
+    """The value half, (kv_rank, H, d_v), likewise."""
+    return lp["v_b_weight"] if "v_b_weight" in lp else lp["kv_b_weight"][..., c.d_nope:]
 
 
 def _output(o, lp, cdt):
@@ -341,16 +372,14 @@ def _route(h, lp, c, active):
     return ids, gates
 
 
-def _moe(h, lp, c, cdt, active):
-    """The expert layer's output for normed rows ``h`` (T, d), and what it
-    counted: pairs on held experts, held experts touched, and the pairs it
-    would hold were every held expert as full as the fullest."""
-    ids, gates = _route(h, lp, c, active)
-    with jax.named_scope("mx.lm.moe.shared"):
-        y = _swiglu(h, lp["shared_gate_weight"], lp["shared_up_weight"],
-                    lp["shared_down_weight"], cdt)
+def _held_experts(h, ids, gates, experts, c, cdt, y):
+    """``y`` plus what the held experts give rows ``h`` (T, d) under the
+    router's choice ``ids`` / ``gates`` (T, k), and the pairs each held
+    expert took (held,).  ``experts`` is the layer's index with the three
+    stacks (layers, held, ...), or None with one layer's own (held, ...); an
+    expert no row chose is skipped."""
     loads = []
-    layer, w_gate, w_up, w_down = lp["experts"]
+    layer, *stacks = experts
     with jax.named_scope("mx.lm.moe.experts"):
         for j, e in enumerate(c.held_experts):
             hit = ids == e                                       # (T, k)
@@ -358,17 +387,33 @@ def _moe(h, lp, c, cdt, active):
             loads.append(jnp.sum(hit))
 
             def run(j=j, gate=gate):
-                out = _swiglu(h, w_gate[layer, j], w_up[layer, j],
-                              w_down[layer, j], cdt)
+                at = j if layer is None else (layer, j)
+                out = _swiglu(h, *(w[at] for w in stacks), cdt)
                 return out * gate[:, None]
 
             y = y + lax.cond(loads[-1] > 0, run, lambda: jnp.zeros_like(y))
-    loads = jnp.stack(loads)
-    counts = {"moe_pairs_held": jnp.sum(loads),
-              "moe_tokens": jnp.sum(active),
-              "moe_experts_touched": jnp.sum(loads > 0),
-              "moe_pairs_at_max_load": jnp.max(loads) * len(c.held_experts)}
-    return y, counts
+    return y, jnp.stack(loads)
+
+
+def _moe_counts(loads, active, c):
+    """What an expert layer counted: pairs on held experts, rows routed, held
+    experts touched, and the pairs it would hold were every held expert as
+    full as the fullest."""
+    return {"moe_pairs_held": jnp.sum(loads),
+            "moe_tokens": jnp.sum(active),
+            "moe_experts_touched": jnp.sum(loads > 0),
+            "moe_pairs_at_max_load": jnp.max(loads) * len(c.held_experts)}
+
+
+def _moe(h, lp, c, cdt, active):
+    """The expert layer's output for normed rows ``h`` (T, d), the shared
+    expert's with the held experts' on top, and what it counted."""
+    ids, gates = _route(h, lp, c, active)
+    with jax.named_scope("mx.lm.moe.shared"):
+        y = _swiglu(h, lp["shared_gate_weight"], lp["shared_up_weight"],
+                    lp["shared_down_weight"], cdt)
+    y, loads = _held_experts(h, ids, gates, lp["experts"], c, cdt, y)
+    return y, _moe_counts(loads, active, c)
 
 
 def _ffn(x, lp, c, cdt, active):
@@ -401,20 +446,23 @@ def _row_blocks(fn, rows, block, *args):
 KEY_CHUNKS = 8       # key chunks a query block may skip when they lie ahead
 
 
-def _expanded_attention(c_q, q_i, w, positions, length, latent, k_i, lp, c, cdt):
-    """Causal sparse attention of a whole sequence in the expanded form:
-    keys and values of every head are made from the latent rows once; the
-    queries go through in row blocks and the keys in ``KEY_CHUNKS`` chunks
-    (online softmax), so that nothing of (rows x keys x heads) outlives its
-    chunk.  A chunk of keys that lies wholly ahead of a block's rows is
-    skipped, and so is a block of rows wholly past ``length`` (the padded
-    tail of a prompt).  Returns (T, d) in float32, through the output
-    projection."""
+def _expanded_attention(c_q, positions, length, latent, lp, c, cdt, index=None,
+                        q_scale=None):
+    """Causal attention of a whole sequence in the expanded form: keys and
+    values of every head are made from the latent rows once; the queries go
+    through in row blocks and the keys in ``KEY_CHUNKS`` chunks (online
+    softmax), so that nothing of (rows x keys x heads) outlives its chunk.
+    A chunk of keys that lies wholly ahead of a block's rows is skipped, and
+    so is a block of rows wholly past ``length`` (the padded tail of a
+    prompt).  With ``index`` = (``q_I``, ``w``, ``k_I``) a row attends the
+    ``index_topk`` causal keys of largest index score, without it every
+    causal key; ``q_scale`` goes to :func:`_queries`.  Returns (T, d) in
+    float32, through the output projection."""
     T = c_q.shape[0]
     with jax.named_scope("mx.gen.attn"):
-        c_kv, kv_b = latent[:, :c.kv_rank], lp["kv_b_weight"]
-        k_nope = _dot(c_kv, kv_b[..., :c.d_nope], "sr,rhe->she", cdt).astype(cdt)
-        v = _dot(c_kv, kv_b[..., c.d_nope:], "sr,rhe->she", cdt).astype(cdt)
+        c_kv = latent[:, :c.kv_rank]
+        k_nope = _dot(c_kv, _k_b(lp, c), "sr,rhe->she", cdt).astype(cdt)
+        v = _dot(c_kv, _v_b(lp, c), "sr,rhe->she", cdt).astype(cdt)
         k_rope = latent[:, c.kv_rank:c.kv_rank + c.d_rope]
     scale = (c.d_nope + c.d_rope) ** -0.5
     key_pos = jnp.arange(T)
@@ -426,17 +474,21 @@ def _expanded_attention(c_q, q_i, w, positions, length, latent, k_i, lp, c, cdt)
         rows -= 1
     H = c.n_heads
 
-    def attend(c_q, q_i, w, pos):
-        last = pos[-1]
+    def select(q_i, w, pos, last):
         with jax.named_scope("mx.gen.index"):
             causal = key_pos[None, :] <= pos[:, None]
             parts = [lax.cond(cut.start <= last,
-                              lambda cut=cut: _index_scores(q_i, w, k_i[cut]),
+                              lambda cut=cut: _index_scores(q_i, w, index[2][cut]),
                               lambda: jnp.zeros((rows, span), jnp.float32))
                      for cut in cuts]
             scores = jnp.where(causal, jnp.concatenate(parts, axis=-1), -jnp.inf)
-            allowed = causal & _largest_k(scores, c.index_topk)
-        q_nope, q_rope = _queries(c_q, pos, lp, c, cdt)
+            return causal & _largest_k(scores, c.index_topk)
+
+    def attend(c_q, selectors, pos):
+        last = pos[-1]
+        allowed = select(*selectors, pos, last) if index is not None \
+            else key_pos[None, :] <= pos[:, None]
+        q_nope, q_rope = _queries(c_q, pos, lp, c, cdt, q_scale)
 
         def chunk(cut, top, norm, acc):
             s = (jnp.einsum("the,she->hts", q_nope, k_nope[cut],
@@ -466,11 +518,13 @@ def _expanded_attention(c_q, q_i, w, positions, length, latent, k_i, lp, c, cdt)
             o = (acc / norm[..., None]).transpose(1, 0, 2)
         return _output(o, lp, cdt)
 
-    def block(c_q, q_i, w, pos):
-        return lax.cond(pos[0] < length, lambda: attend(c_q, q_i, w, pos),
+    def block(c_q, *rest):
+        *selectors, pos = rest         # the indexer's rows, where there is one
+        return lax.cond(pos[0] < length, lambda: attend(c_q, selectors, pos),
                         lambda: jnp.zeros((rows, c.d_model), jnp.float32))
 
-    return _row_blocks(block, T, rows, c_q, q_i, w, positions)
+    return _row_blocks(block, T, rows, c_q, *(index[:2] if index is not None else ()),
+                       positions)
 
 
 # -- programs ----------------------------------------------------------------
@@ -497,8 +551,8 @@ def _sequence_layers(params, x, config, on_layer, length=None):
         h = _rmsnorm(x, lp["attn_norm"], c.norm_eps).astype(cdt)
         c_q, latent, q_i, k_i, w = _project(h, positions, lp, c, cdt)
         on_layer(i, latent, k_i)
-        o = _expanded_attention(c_q, q_i, w, positions, length, latent, k_i, lp,
-                                c, cdt)
+        o = _expanded_attention(c_q, positions, length, latent, lp, c, cdt,
+                                index=(q_i, w, k_i))
         x = x + o.astype(cdt)
         x = _row_blocks(functools.partial(ffn, lp), T, ffn_rows, x, real)
     return x

@@ -425,6 +425,46 @@ def test_latent_expert_programs_carry_their_scope_names():
         assert scope in text, scope
 
 
+def test_double_block_programs_carry_their_scope_names():
+    """ISSUE 38: the scopes ``longcat-flash.decode-pool-12k``'s metrics read."""
+    import jax
+    import jax.numpy as jnp
+
+    from mxnet_tpu.models import scmoe
+
+    cfg = scmoe.ShortcutMoEConfig(
+        vocab=32, d_model=32, n_heads=2, n_layers=1, d_ff=48, d_expert=16, n_experts=8,
+        n_zero_experts=4, experts_per_token=2, held_experts=(0, 1), q_rank=16, kv_rank=8,
+        d_nope=4, d_rope=4, d_v=4, max_len=32, dtype="float32")
+    params = scmoe.init_params(cfg)
+    cache = scmoe.init_kv_cache(cfg, num_pages=8, page_size=4)
+    decode = jax.jit(scmoe.make_decode_fn(cfg, 2, 4, 4))
+    text = _op_names(decode.lower(
+        params, cache, jnp.zeros((2,), jnp.int32), jnp.zeros((2,), jnp.int32),
+        jnp.zeros((2, 4), jnp.int32), jnp.ones((2,), bool)))
+    scopes = ("mx.gen.latent_proj", "mx.gen.attn", "mx.gen.pool_write", "mx.lm.ffn",
+              "mx.lm.moe.route", "mx.lm.moe.experts", "mx.lm.moe.zero")
+    for scope in scopes:
+        assert scope in text, scope
+    assert "mx.gen.index" not in text and "mx.lm.moe.shared" not in text
+    prefill = jax.jit(scmoe.make_prefill_fn(cfg, 4))
+    text = _op_names(prefill.lower(
+        params, cache, jnp.zeros((1, 8), jnp.int32), jnp.int32(5),
+        jnp.zeros((2,), jnp.int32)))
+    for scope in scopes:
+        assert scope in text, scope
+
+
+def test_double_block_counters_ride_generate_stats():
+    from mxnet_tpu.models import scmoe
+
+    profiler.generate_record(**{k: 3 for k in scmoe.DECODE_COUNTERS})
+    st = profiler.generate_stats(reset=True)
+    assert st["moe_pairs_zero"] == 3 and st["attn_rows_read"] == 3
+    with pytest.raises(ValueError):
+        profiler.generate_record(moe_pairs_nought=1)
+
+
 def test_device_counters_of_a_decode_step_ride_generate_stats():
     from mxnet_tpu.models import mla_moe
 

@@ -158,3 +158,51 @@ def test_flash_kernels_compile_under_an_ambient_highest_precision(
     with jax.default_matmul_precision("highest"):
         text = jax.jit(step).lower(shape, shape, shape, shape).compile().as_text()
     assert text.count('custom_call_target="tpu_custom_call"') == 3
+
+
+def test_longcat_decode_reads_its_eight_latent_pools_in_place(one_chip, monkeypatch):
+    # benchmark/configs/longcat-flash-chat-ep32.json and
+    # benchmark/traffic/decode-pool-12k.json: the double-block decode step at
+    # the cell's size, 5.17 B bfloat16 parameters as shapes
+    import mxnet_tpu.kernels  # noqa: F401  (loads kernels.flash_attention)
+    from mxnet_tpu.models import scmoe
+
+    for name in ("mxnet_tpu.models.scmoe", "mxnet_tpu.kernels.flash_attention"):
+        monkeypatch.setattr(sys.modules[name], "kernel_platform", lambda: "tpu")
+    cfg = scmoe.ShortcutMoEConfig()
+    slots, page, per_slot = 16, 16, 15104 // 16
+
+    def described(x):
+        return jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=one_chip)
+
+    params = {k: jax.ShapeDtypeStruct(s, jnp.bfloat16, sharding=one_chip)
+              for k, (s, _kind) in scmoe.param_shapes(cfg).items()}
+    cache = jax.tree.map(described, jax.eval_shape(
+        lambda: scmoe.init_kv_cache(cfg, slots * per_slot, page)))
+    pools = cache["latent"]
+    pool_bytes = sum(2 * math.prod(p.shape) for p in pools)
+    assert len(pools) == 8 and pool_bytes == scmoe.kv_page_bytes(cfg, page) * (
+        slots * per_slot + 1)
+    fn = jax.jit(scmoe.make_decode_fn(cfg, slots, per_slot, page,
+                                      block_k=scmoe._decode_block_k(cfg, slots, 15104)),
+                 donate_argnums=(1,))
+    compiled = fn.lower(
+        params, cache,
+        jax.ShapeDtypeStruct((slots,), jnp.int32, sharding=one_chip),
+        jax.ShapeDtypeStruct((slots,), jnp.int32, sharding=one_chip),
+        jax.ShapeDtypeStruct((slots, per_slot), jnp.int32, sharding=one_chip),
+        jax.ShapeDtypeStruct((slots,), jnp.bool_, sharding=one_chip)).compile()
+    mem = compiled.memory_analysis()
+    text = compiled.as_text()
+    # one Mosaic call an attention, each over its pool where it lies
+    assert text.count('custom_call_target="tpu_custom_call"') == 8
+    assert "mx_mla_paged_decode" in text
+    # the donated pools come back as the outputs; the temporaries hold no
+    # second pool, no gathered rows (16 x 15104 x 640 x 2 B = 309 MB) and no
+    # copy of a dense FFN's matrix (151 MB)
+    assert mem.alias_size_in_bytes >= pool_bytes
+    assert mem.temp_size_in_bytes < 144 * 2 ** 20
+    shape = r"bf16\[%s\]" % ",".join(map(str, pools[0].shape))
+    assert re.search(shape + r"\{2,1,0:T\(8,128\)\(2,1\)\} parameter\(", text)
+    assert not re.search(r"= %s\S* (copy|pad|slice|dynamic-slice|transpose)\("
+                         % shape, text)
