@@ -52,10 +52,16 @@ Names are fixed strings ``mx.<layer>.<what>``; arguments carry identity
 ``mx.serve.prefill``        one request's prefill (rid, slot, prompt_tokens,
                             bucket, prefix_len, queue_wait_ms, active)
 ``mx.serve.prefill.device`` the predictor's prefill call, dispatch to logits
-``mx.serve.grow_pages``     page growth before a decode step
-``mx.serve.decode_step``    one decode or speculative step (step, active)
-``mx.serve.decode.device``  the predictor's decode call, dispatch to logits
-``mx.serve.decode.sample``  argmax, append, stream and finish, all slots
+``mx.serve.grow_pages``     page growth before a decode step is dispatched
+``mx.serve.decode_step``    one turn of the decode loop, which keeps a step
+                            in flight: step ``step`` dispatched for ``active``
+                            slots (0: none), the step before read; or one
+                            speculative round (step, active)
+``mx.serve.decode.device``  the dispatch of that step and the wait for the
+                            ids the step before chose on the device (a
+                            speculative round: dispatch to logits)
+``mx.serve.decode.sample``  the read step's ids appended, streamed, finished,
+                            all slots (a speculative round: argmax too)
 ``mx.serve.finish``         a request leaves its slot (rid, reason, tokens)
 ==========================  ==================================================
 
@@ -715,9 +721,10 @@ _GEN_ZERO = {
     "decode_steps": 0, "tokens": 0, "finished": 0, "eos": 0, "length": 0,
     "deadline": 0, "exhausted": 0, "errors": 0, "shed": 0,
     "slot_steps": 0, "active_slot_steps": 0, "max_queue_depth": 0,
-    # host seconds (floats): prefill and decode are the two predictor
-    # calls from dispatch to logits on the host (``busy_seconds`` in the
-    # snapshot is their sum); loop = the worker's whole working time
+    # host seconds (floats): prefill is the predictor's call from dispatch
+    # to logits on the host, decode the dispatch of a step and the wait for
+    # the ids of the one before (``busy_seconds`` in the snapshot is their
+    # sum); loop = the worker's whole working time
     # outside the condition wait, so loop - busy is the host loop's own;
     # stream = inside users' stream_fn callbacks
     "prefill_seconds": 0.0, "decode_seconds": 0.0,
@@ -725,6 +732,12 @@ _GEN_ZERO = {
     # decode steps that had at least one prefill since the step before:
     # how often a gap between two tokens holds a prefill
     "decode_steps_after_prefill": 0,
+    # the plain decode loop keeps one step in flight: steps dispatched
+    # while the one before was still unread (over ``decode_steps`` they ride
+    # generate_stats as decode_ahead_share), and ids a step chose for a slot
+    # whose request had left on the token before (``eos``, a deadline, a
+    # failure: seen one step late), dropped unstreamed and uncounted
+    "decode_steps_ahead": 0, "decode_tokens_discarded": 0,
     # KV pages of the pool, over all decode steps: ``read`` holds a valid
     # column of an active slot (ceil((position + 1) / page_size), what the
     # paged decode kernel copies a layer), ``spanned`` is slots x pages per
@@ -844,6 +857,8 @@ def generate_stats(reset=False):
             - snap["decode_seconds"]) / snap["decode_steps"] * 1e3
         snap["decode_after_prefill_share"] = (
             snap["decode_steps_after_prefill"] / snap["decode_steps"])
+        snap["decode_ahead_share"] = (
+            snap["decode_steps_ahead"] / snap["decode_steps"])
     if snap["decode_kv_pages_spanned"]:
         snap["decode_kv_read_share"] = (
             snap["decode_kv_pages_read"] / snap["decode_kv_pages_spanned"])

@@ -539,6 +539,17 @@ class _GenRequest:
         self.draft_pos = 0           # next position the draft cache needs
 
 
+class _Step:
+    """A decode step that was dispatched and not yet read."""
+    __slots__ = ("chosen", "slots", "reqs", "counts")
+
+    def __init__(self, chosen, slots, reqs, counts):
+        self.chosen = chosen         # on the device: ids, then counters
+        self.slots = slots           # the slots it ran for ...
+        self.reqs = reqs             # ... and whose they were at dispatch
+        self.counts = counts         # what the host counted at dispatch
+
+
 class GenerateServer:
     """Continuous-batching autoregressive decode server (ISSUE 12).
 
@@ -550,9 +561,26 @@ class GenerateServer:
     admits new requests into vacated batch slots EVERY decode step
     (continuous batching): admit (shedding deadline-expired requests
     at dequeue, the PR 9 rule) → prefill admitted prompts into freshly
-    allocated KV pages → one decode step over all active slots →
-    sample, stream, finish, recycle pages. ``admit_policy="drain"``
-    keeps the old drain-whole-batch behavior for the bench comparison.
+    allocated KV pages → dispatch the next decode step over all active
+    slots → read the ids the step before chose → stream, finish,
+    recycle pages. ``admit_policy="drain"`` keeps the old
+    drain-whole-batch behavior for the bench comparison.
+
+    The loop keeps one decode step in flight (ISSUE 39): the decode
+    program chooses each slot's token itself and the next step takes it
+    from there on the device, so step n + 1 is dispatched before the
+    host has read step n, and the host's read of ``slots`` ids, its
+    stream callbacks and its bookkeeping run while the device does the
+    next step. Positions, block tables and the active mask are the
+    host's own; a slot whose answer the step in flight completes is
+    left out of the step ahead. An ``eos`` or a deadline is seen one
+    step late: the stray step's id is dropped (``decode_tokens_discarded``),
+    never streamed, and its cache row went to a page the request still
+    owned. Before a prefill the step in flight is read and streamed, and
+    when the last slot leaves the loop drains it; a program that fails
+    on the device fails the loop one step later. Speculative decoding
+    (``spec_k``) reads logits on the host by nature and keeps its own
+    synchronous round.
 
     Memory is paged (:class:`~.generate.PagePool`): each slot holds a
     block table naming its pages; completion returns the pages
@@ -648,6 +676,13 @@ class GenerateServer:
         self._positions = np.zeros((S,), np.int32)
         self._tokens = np.zeros((S,), np.int32)
         self._active = np.zeros((S,), bool)
+        # the plain loop keeps one decode step in flight: a slot's pending
+        # token is the host's (its prefill chose it) or the device's (the
+        # step before chose it, read or not); ``_left`` counts the steps a
+        # slot may still be dispatched into before its answer is full
+        self._from_host = np.zeros((S,), bool)
+        self._left = np.zeros((S,), np.int64)
+        self._inflight = None        # the _Step dispatched and not yet read
         # the draft model's own block tables (its pool is auto-sized to
         # slots x max-context pages, so draft growth can never exhaust)
         self._draft_bt = np.zeros((S, MP), np.int32) \
@@ -657,7 +692,7 @@ class GenerateServer:
         self._q = deque()
         self._stopped = False
         self._error = None
-        self._decode_steps = 0       # this server's decode/spec steps so far
+        self._decode_steps = 0       # decode/spec steps dispatched so far
         self._prefilled = False      # a prefill ran since the last decode step
         self._stream_s = 0.0         # seconds in stream_fn callbacks this turn
         self._step_hook = None       # test seam: called before each decode
@@ -845,6 +880,8 @@ class GenerateServer:
             self._block_tables[slot, :] = 0
             self._positions[slot] = 0
             self._tokens[slot] = 0
+            self._from_host[slot] = False
+            self._left[slot] = 0
             if self._draft_bt is not None:
                 self._draft_bt[slot, :] = 0
             self._cond.notify_all()
@@ -976,6 +1013,8 @@ class GenerateServer:
         self._block_tables[slot, :len(r.pages)] = r.pages
         self._positions[slot] = n_prompt
         self._tokens[slot] = tok
+        self._from_host[slot] = True
+        self._left[slot] = r.max_new - 1
         if self._draft is not None:
             self._draft_bt[slot, :len(r.draft_pages)] = r.draft_pages
             r.draft_pos = n_prompt
@@ -988,13 +1027,13 @@ class GenerateServer:
             self._grow(headroom)
 
     def _grow(self, headroom):
-        """Before a decode step, make sure every active slot owns the
-        page(s) its next write positions land in — up to ``headroom``
-        extra positions past the pending one for a speculative round's
-        verify writes; a pool that cannot grow a mid-flight request
-        fails it typed (never a silent stall)."""
+        """Before a decode step is dispatched, make sure every slot it
+        runs for owns the page(s) its next write positions land in — up
+        to ``headroom`` extra positions past the pending one for a
+        speculative round's verify writes; a pool that cannot grow a
+        mid-flight request fails it typed (never a silent stall)."""
         pred = self.predictor
-        for slot in np.flatnonzero(self._active):
+        for slot in self._next_slots():
             r = self._slot_req[slot]
             upto = min(int(self._positions[slot]) + headroom,
                        pred.max_ctx - 1)
@@ -1023,38 +1062,79 @@ class GenerateServer:
         """One decode or speculative step's counters, in one record."""
         profiler.generate_record(
             decode_steps=1, tokens=tokens, slot_steps=self.predictor.slots,
-            active_slot_steps=active, decode_seconds=seconds,
-            decode_steps_after_prefill=int(self._prefilled), **more)
+            active_slot_steps=active, decode_seconds=seconds, **more)
+
+    def _next_slots(self):
+        """The slots the next decode step runs for: active, and short of a
+        full answer by more than the token a step in flight will bring."""
+        return np.flatnonzero(self._active & (self._left > 0))
+
+    def _dispatch(self, slots, ahead):
+        """Dispatch one decode step for ``slots`` and read nothing: each
+        takes its prefill's token from the host or the id the step before
+        chose on the device, and advances by one position."""
+        pred = self.predictor
+        running = np.zeros((pred.slots,), bool)
+        running[slots] = True
+        chosen = pred.decode_ahead(self._tokens, self._from_host,
+                                   self._positions, self._block_tables,
+                                   running)
+        self._from_host[slots] = False
+        self._positions[slots] += 1
+        self._left[slots] -= 1
+        counts = dict(
+            decode_steps_ahead=int(ahead),
+            decode_steps_after_prefill=int(self._prefilled),
+            decode_kv_pages_read=int(np.sum(
+                -(-self._positions[slots] // pred.page_size))),
+            decode_kv_pages_spanned=pred.slots * pred.max_pages_per_slot)
         self._prefilled = False
         self._decode_steps += 1
+        return _Step(chosen, slots, [self._slot_req[s] for s in slots],
+                     counts)
 
-    def _decode_step(self):
+    def _decode_step(self, dispatch=True):
+        """One turn of the plain decode loop, which keeps a step in flight:
+        dispatch the next step for the slots that want one (none with
+        ``dispatch`` false), then read the ids the step before chose, append,
+        stream, and check ``eos`` / length / deadline.  A length finish is
+        foreseen (``_left``) and its slot left out of the step ahead; an
+        ``eos`` or a deadline is seen once the next step is dispatched, and
+        that step's id for the slot is dropped when its turn to be read
+        comes: its one cache row went to a page the request still owned,
+        and whatever reuses the page is dispatched after it."""
         pred = self.predictor
         if self._step_hook is not None:
             self._step_hook()
-        active = np.flatnonzero(self._active)
+        slots = self._next_slots() if dispatch else ()
+        before, self._inflight = self._inflight, None
         with profiler.span("mx.serve.decode_step", step=self._decode_steps,
-                           active=len(active)):
+                           active=len(slots)):
             t0 = time.perf_counter()
             with profiler.span("mx.serve.decode.device"):
-                logits = pred.decode(self._tokens, self._positions,
-                                     self._block_tables, self._active)
-            self._positions[active] += 1
-            self._step_counts(
-                len(active), len(active), time.perf_counter() - t0,
-                decode_kv_pages_read=int(np.sum(
-                    -(-self._positions[active] // pred.page_size))),
-                decode_kv_pages_spanned=pred.slots * pred.max_pages_per_slot,
-                **pred.step_counters)
+                if len(slots):
+                    self._inflight = self._dispatch(slots, before is not None)
+                if before is not None:
+                    ids, counters = pred.read_step(before.chosen)
+            seconds = time.perf_counter() - t0
+            if before is None:
+                profiler.generate_record(decode_seconds=seconds)
+                return
+            kept = 0
             with profiler.span("mx.serve.decode.sample"):
-                for slot in active:
-                    r = self._slot_req[slot]
-                    tok = int(np.argmax(logits[slot]))
+                for slot, r in zip(before.slots, before.reqs):
+                    if self._slot_req[slot] is not r:
+                        continue     # it left on the token before this one
+                    kept += 1
+                    tok = int(ids[slot])
                     r.out.append(tok)
                     r.unflushed.append(tok)
-                    self._tokens[slot] = tok
                     self._flush_stream(r)
                     self._check_done(r, tok)
+            self._step_counts(
+                kept, kept, seconds,
+                decode_tokens_discarded=len(before.slots) - kept,
+                **before.counts, **counters)
 
     def _spec_step(self):
         """One speculative-decoding round (ISSUE 16), replacing one
@@ -1095,7 +1175,10 @@ class GenerateServer:
             with profiler.span("mx.serve.decode.sample"):
                 emitted = self._spec_accept(active, chain_len, k_i, props,
                                             logits)
-            self._step_counts(len(active), emitted, seconds, spec_rounds=1)
+            self._step_counts(len(active), emitted, seconds, spec_rounds=1,
+                              decode_steps_after_prefill=int(self._prefilled))
+            self._prefilled = False
+            self._decode_steps += 1
 
     def _spec_propose_verify(self, active):
         """Draft and verify phases of one round: per-slot chain lengths,
@@ -1196,13 +1279,14 @@ class GenerateServer:
         """Block until there is something to do; False once stopped."""
         with self._cond:
             while (not self._q and not self._active_count()
-                   and not self._stopped):
+                   and self._inflight is None and not self._stopped):
                 self._cond.wait()
             return not self._stopped
 
     def _turn(self):
-        """One turn of the loop: admit, prefill what was admitted, one
-        decode step over the active slots. False once stopped."""
+        """One turn of the loop: admit, prefill what was admitted (after
+        reading the step in flight, if any), one decode step: the next one
+        dispatched, the one before read and streamed. False once stopped."""
         with profiler.span("mx.serve.admit") as admit:
             with self._cond:
                 if self._stopped:
@@ -1226,9 +1310,13 @@ class GenerateServer:
                     "admitted — pool empty with no requests in "
                     "flight to recycle from"
                     % starved.tokens.shape[0]), counter="exhausted")
+        if admitted and self._inflight is not None:
+            # what is in flight is read and streamed before the loop waits
+            # for a prefill's logits
+            self._decode_step(dispatch=False)
         for r in admitted:
             self._prefill_one(r)
-        if not self._active_count():
+        if not self._active_count() and self._inflight is None:
             return True
         if self._draft is not None:
             # speculative round: verify writes up to spec_k
@@ -1238,7 +1326,7 @@ class GenerateServer:
                 self._spec_step()
         else:
             self._grow_pages()
-            if self._active_count():
+            if self._active_count() or self._inflight is not None:
                 self._decode_step()
         return True
 
@@ -1257,6 +1345,7 @@ class GenerateServer:
                 if not alive:
                     return
         except BaseException as e:   # loop death: sticky, fail everything
+            self._inflight = None
             with self._cond:
                 self._error = e
                 self._stopped = True

@@ -21,6 +21,9 @@ them from ``serving/broker.py``:
   that writes each token's K/V row into the pool in place and attends
   the pages named by each slot's block table where they lie (on a TPU
   the ``kernels/paged_decode.py`` kernel; see ``models/transformer.py``).
+  The program chooses the next token itself (:func:`_decode_program`) and
+  can take it from the step before on the device, so the broker keeps one
+  step in flight (``decode_ahead`` / ``read_step``).
   The big cache buffer is donated to every call on accelerators (the
   PR 6 donation rule: skipped on CPU where it only warns), so the
   decode program holds one pool; compiled
@@ -378,6 +381,49 @@ def _model_module(config_):
     return importlib.import_module(name)
 
 
+def _decode_program(model, config_, slots, max_pages_per_slot, page_size,
+                    block_k, mesh=None):
+    """The decode step every model module is served through: the module's own
+    ``make_decode_fn``, with the next token chosen on the device.
+
+    fn(params, cache, last (slots + n,) int32, host_ids (slots,) int32,
+    from_host (slots,) bool, positions, block_tables, active) →
+    (cache', (logits (slots, V), chosen (slots + n,) int32)), n the length
+    of the module's ``DECODE_COUNTERS``.  A slot's token is ``host_ids`` where
+    ``from_host`` (its prefill chose it, on the host) and the id the step
+    before chose, ``last[:slots]``, otherwise: a step can be dispatched
+    before the host has read the one before.  ``chosen`` holds
+    ``argmax(logits, -1)``, the first of equal maxima as ``np.argmax``
+    takes it, and behind it the module's counters: one small read a step.
+    The function is named ``decode``, so a trace shows ``jit_decode``."""
+    import jax
+    import jax.numpy as jnp
+    from jax.sharding import NamedSharding
+    from jax.sharding import PartitionSpec as P
+
+    step = model.make_decode_fn(config_, slots, max_pages_per_slot, page_size,
+                                block_k=block_k, mesh=mesh)
+    counted = bool(getattr(model, "DECODE_COUNTERS", ()))
+
+    def decode(params, cache, last, host_ids, from_host, positions,
+               block_tables, active):
+        tokens = jnp.where(from_host, host_ids, last[:slots])
+        cache, out = step(params, cache, tokens, positions, block_tables,
+                          active)
+        logits, counts = out if counted else (out, None)
+        chosen = jnp.argmax(logits, axis=-1).astype(jnp.int32)
+        if counted:
+            chosen = jnp.concatenate([chosen, counts.astype(jnp.int32)])
+        if mesh is not None:
+            # fed back as the next step's ``last``: keep it placed as the
+            # first one is, so the step stays one program
+            chosen = jax.lax.with_sharding_constraint(
+                chosen, NamedSharding(mesh, P()))
+        return cache, (logits, chosen)
+
+    return decode
+
+
 class GenerativePredictor:
     """One model bound for prefill + single-token decode.
 
@@ -521,10 +567,24 @@ class GenerativePredictor:
             pick = getattr(tfm, "_decode_block_k", None)
             block_k = pick(c, self.slots, self.max_ctx) if pick else 0
         self.block_k = int(block_k)
-        # what the decode program counts on the device, read with the
-        # logits: the names, and the last step's values for the broker
+        # what the decode program counts on the device, read with the ids
+        # it chose: the names, and the last step ``decode`` read
         self._counter_names = tuple(getattr(tfm, "DECODE_COUNTERS", ()))
         self.step_counters = {}
+        # the newest decode step's ``chosen`` (ids, then counters), on the
+        # device: the next step takes its tokens from it.  Placed as the
+        # program's outputs are, so feeding one back is the same call
+        last = jnp.zeros((self.slots + len(self._counter_names),), jnp.int32)
+        placed = [a for a in jax.tree.leaves((self._params, self._kv))
+                  if a.committed]
+        if placed:
+            from jax.sharding import NamedSharding
+            from jax.sharding import PartitionSpec as P
+
+            last = jax.device_put(
+                last, NamedSharding(mesh, P()) if mesh is not None
+                else placed[0].sharding)
+        self._last = last
 
         # prefill bucket ladder: page-aligned powers of two up to the
         # context bound (the PR 6 ladder idea at page granularity)
@@ -570,9 +630,9 @@ class GenerativePredictor:
         key = (self._cache_key, ("decode", self.slots),
                self._config_fingerprint(), self._dtype_name)
         return self._exec_cache.get_or_build(
-            key, lambda: self._jit(tfm.make_decode_fn(
-                self.config, self.slots, self.max_pages_per_slot,
-                self.page_size, block_k=self.block_k, mesh=self._mesh)))
+            key, lambda: self._jit(_decode_program(
+                tfm, self.config, self.slots, self.max_pages_per_slot,
+                self.page_size, self.block_k, self._mesh)))
 
     def _extend_exec(self, batch, steps):
         tfm = self._model
@@ -627,19 +687,42 @@ class GenerativePredictor:
         (slots, V). ``tokens[b]`` is written at ``positions[b]`` into
         the page its slot's ``block_tables`` row names; inactive slots
         write to scratch and return zero logits."""
+        logits, chosen = self._decode(
+            tokens, np.ones((self.slots,), bool), positions, block_tables,
+            active)
+        self.step_counters = self.read_step(chosen)[1]
+        return np.asarray(logits)
+
+    def decode_ahead(self, host_ids, from_host, positions, block_tables,
+                     active):
+        """Dispatch one decode step and read nothing: a slot takes
+        ``host_ids[b]`` where ``from_host[b]`` and the id the step before
+        chose for it otherwise, which the host need not have read yet.
+        Returns what :meth:`read_step` reads, on the device."""
+        return self._decode(host_ids, from_host, positions, block_tables,
+                            active)[1]
+
+    def read_step(self, chosen):
+        """Wait for a dispatched step: the ids it chose, numpy (slots,)
+        int32, and the module's counters of that step by name."""
+        chosen = np.asarray(chosen)
+        return chosen[:self.slots], dict(zip(
+            self._counter_names, chosen[self.slots:].tolist()))
+
+    def _decode(self, host_ids, from_host, positions, block_tables, active):
+        # the arguments are copied: the device may read them after this
+        # returns, and the broker's tables change under a step in flight
         fn = self._decode_exec()
         with self._lock:
-            self._kv, logits = fn(
-                self._params, self._kv,
-                np.asarray(tokens, np.int32),
-                np.asarray(positions, np.int32),
-                np.asarray(block_tables, np.int32),
-                np.asarray(active, bool))
-        if self._counter_names:
-            logits, counts = logits
-            self.step_counters = dict(zip(self._counter_names,
-                                          np.asarray(counts).tolist()))
-        return np.asarray(logits)
+            self._kv, (logits, chosen) = fn(
+                self._params, self._kv, self._last,
+                np.array(host_ids, np.int32),
+                np.array(from_host, bool),
+                np.array(positions, np.int32),
+                np.array(block_tables, np.int32),
+                np.array(active, bool))
+            self._last = chosen
+        return logits, chosen
 
     def extend(self, tokens, positions, block_tables, valid):
         """Multi-token append (ISSUE 16): run ``tokens`` (S, T) at

@@ -535,3 +535,323 @@ def test_pool_bytes_knob_sizes_the_pool(model, monkeypatch):
                        str(2 * pred0.page_bytes))
     with pytest.raises(GenerateError):
         GenerativePredictor(cfg, params, slots=2, page_size=8)
+
+
+# ---------------------------------------------------------------------------
+# ISSUE 39: the plain decode loop keeps one step in flight. The token is
+# chosen inside the decode program and fed to the next step on the device;
+# the host reads a step's ids while the next one runs. Every served chain is
+# the chain a synchronous loop of pred.decode + np.argmax serves.
+# ---------------------------------------------------------------------------
+def _transformer():
+    cfg = _cfg()
+    # larger matrices than init_params draws: the greedy chain of the
+    # default draw repeats one token, and an eos case needs a varied one
+    params = {k: v * (4.0 if k.endswith("weight") and "embed" not in k
+                      else 1.0)
+              for k, v in tfm.init_params(cfg, seed=2).items()}
+    return cfg, params
+
+
+def _latent_moe():
+    from mxnet_tpu.models import mla_moe
+
+    cfg = mla_moe.LatentMoEConfig(
+        vocab=64, d_model=64, n_heads=4, n_layers=3, n_dense_layers=1,
+        d_ff=96, d_expert=32, n_experts=32, experts_per_token=4,
+        held_experts=(0, 1), route_scale=2.5, q_rank=32, kv_rank=16,
+        d_nope=8, d_rope=8, d_v=16, index_heads=4, index_dim=16,
+        index_rope_dim=8, index_topk=8, rope_theta=1e4, norm_eps=1e-5,
+        index_norm_eps=1e-6, max_len=128, dtype="float32")
+    return cfg, mla_moe.init_params(cfg, seed=3, scale=0.15, bias_scale=0.05)
+
+
+def _shortcut_moe():
+    from mxnet_tpu.models import scmoe
+
+    cfg = scmoe.ShortcutMoEConfig(
+        vocab=64, d_model=64, n_heads=4, n_layers=2, d_ff=96, d_expert=32,
+        n_experts=16, n_zero_experts=8, experts_per_token=4,
+        held_experts=(0, 1, 2, 3), route_scale=6.0, q_rank=32, kv_rank=16,
+        d_nope=8, d_rope=8, d_v=8, scale_q_lora=True, scale_kv_lora=True,
+        rope_theta=1e4, norm_eps=1e-5, max_len=128, dtype="float32")
+    return cfg, scmoe.init_params(cfg, seed=3, scale=0.15, bias_scale=0.05)
+
+
+_MODULES = {"transformer": _transformer, "mla_moe": _latent_moe,
+            "scmoe": _shortcut_moe}
+_BUILT = {}
+
+
+@pytest.fixture(params=sorted(_MODULES))
+def any_model(request):
+    """(config, params) of each of the three model modules, tiny."""
+    if request.param not in _BUILT:
+        _BUILT[request.param] = _MODULES[request.param]()
+    return _BUILT[request.param]
+
+
+def _prompt(n, seed):
+    return np.random.RandomState(seed).randint(0, 64, n).astype(np.int32)
+
+
+def _host_chain(cfg, params, prompt, n, page_size=4, max_ctx=64):
+    """The chain a synchronous loop serves: prefill, then pred.decode and
+    np.argmax on the host, one step at a time."""
+    pred = GenerativePredictor(cfg, params, slots=2, page_size=page_size,
+                               max_ctx=max_ctx)
+    pages = pred.pool.alloc(pred.pages_needed(len(prompt) + n))
+    table = np.zeros((2, pred.max_pages_per_slot), np.int32)
+    table[1, :len(pages)] = pages
+    first = pred.pages_needed(len(prompt))
+    chain = [int(np.argmax(pred.prefill(prompt, pages[:first])))]
+    active = np.array([False, True])
+    for i in range(n - 1):
+        logits = pred.decode([0, chain[-1]], [0, len(prompt) + i], table,
+                             active)
+        assert logits.shape == (2, cfg.vocab)
+        assert not logits[0].any()           # the idle slot: zero logits
+        chain.append(int(np.argmax(logits[1])))
+    return chain
+
+
+def _serve(cfg, params, **kw):
+    kw = dict(dict(slots=2, page_size=4, max_ctx=64, max_steps=40,
+                   stream_flush=1, name="tahead"), **kw)
+    return GenerateServer(cfg, params, **kw)
+
+
+def test_served_chain_is_the_host_loops_chain(any_model):
+    cfg, params = any_model
+    prompt = _prompt(9, 1)
+    want = _host_chain(cfg, params, prompt, 24)
+    with _serve(cfg, params) as srv:
+        got = srv.generate(prompt, max_new_tokens=24)
+        # one program, whichever side a slot's token comes from
+        assert srv.predictor._decode_exec()._cache_size() == 1
+    assert got["tokens"] == want and got["finish_reason"] == "length"
+    st = profiler.generate_stats()
+    assert st["decode_steps"] == 23 == st["active_slot_steps"]
+    assert st["decode_steps_ahead"] == 22
+    assert st["decode_tokens_discarded"] == 0
+
+
+def _stats_once(**reached):
+    """generate_stats once its counters reach the given values: a future
+    resolves when its request finishes, and the loop reads the step it
+    had dispatched ahead a turn later."""
+    give_up = time.monotonic() + 30
+    while True:
+        st = profiler.generate_stats()
+        if all(st.get(k) == v for k, v in reached.items()) \
+                or time.monotonic() > give_up:
+            return st
+        time.sleep(0.002)
+
+
+def _late_eos(chain):
+    """A token of the chain first seen at index 2 or later, so an eos on it
+    arrives while the next step is in flight."""
+    for i, t in enumerate(chain):
+        if 2 <= i < len(chain) - 2 and t not in chain[:i]:
+            return i, t
+    raise AssertionError("no token to stop on in %r" % (chain,))
+
+
+def test_eos_with_the_next_step_in_flight_drops_the_stray_id(any_model):
+    cfg, params = any_model
+    prompt, other = _prompt(9, 1), _prompt(11, 5)
+    chain = _host_chain(cfg, params, prompt, 24)
+    others = _host_chain(cfg, params, other, 10)
+    at, eos = _late_eos(chain)
+    streamed = []
+    with _serve(cfg, params, slots=1) as srv:
+        pool = srv.predictor.pool
+        r = srv.generate(prompt, max_new_tokens=24, eos_id=eos,
+                         stream_fn=streamed.extend)
+        assert r["finish_reason"] == "eos"
+        assert r["tokens"] == chain[:at + 1] == streamed
+        # the step dispatched ahead of the eos ran for the slot once more:
+        # its id is dropped, neither streamed nor counted
+        st = _stats_once(decode_tokens_discarded=1)
+        assert st["decode_tokens_discarded"] == 1
+        assert st["decode_steps"] == at + 1 and st["active_slot_steps"] == at
+        assert st["tokens"] == at + 1
+        assert pool.in_use == 0 and pool.allocs == pool.frees
+        first = pool.allocs
+        # the one slot and its recycled pages serve the next request right
+        nxt = srv.generate(other, max_new_tokens=10)
+        assert nxt["tokens"] == others
+        assert pool.in_use == 0 and pool.allocs == pool.frees > first
+
+
+def test_length_finish_runs_no_stray_slot_step(model):
+    cfg, params = model
+    with _serve(cfg, params, page_size=8) as srv:
+        futures = [srv.submit(_prompt(6 + i, i), max_new_tokens=n)
+                   for i, n in enumerate((3, 17, 9))]
+        assert [len(f.result(timeout=60)["tokens"]) for f in futures] \
+            == [3, 17, 9]
+    st = profiler.generate_stats()
+    # a token a decode step but the prefill's, and no slot step beyond
+    assert st["active_slot_steps"] == 2 + 16 + 8
+    assert st["tokens"] == 3 + 17 + 9 and st["length"] == 3
+    assert st["decode_tokens_discarded"] == 0
+    assert st["pages_in_use"] == 0
+
+
+def test_admitted_with_a_step_in_flight_takes_its_prefills_token(any_model):
+    cfg, params = any_model
+    long_p, late_p = _prompt(9, 1), _prompt(13, 7)
+    want_long = _host_chain(cfg, params, long_p, 40)
+    want_late = _host_chain(cfg, params, late_p, 12)
+    seen = []
+    with _serve(cfg, params) as srv:
+        long = srv.submit(long_p, max_new_tokens=40, stream_fn=seen.extend)
+        while len(seen) < 5:            # decoding, a step in flight
+            time.sleep(0.001)
+        late = srv.submit(late_p, max_new_tokens=12)
+        assert late.result(timeout=60)["tokens"] == want_late
+        assert long.result(timeout=60)["tokens"] == want_long == seen
+    st = profiler.generate_stats()
+    assert st["decode_tokens_discarded"] == 0
+    assert st["active_slot_steps"] == 39 + 11
+
+
+def test_deadline_that_expires_mid_stream(model):
+    cfg, params = _transformer()
+    prompt = _prompt(9, 1)
+    chain = _host_chain(cfg, params, prompt, 50, page_size=8)
+    seen = []
+    with _serve(cfg, params, page_size=8, max_steps=60) as srv:
+        # compile first: the deadline is to pass between two tokens
+        assert srv.generate(prompt, max_new_tokens=6)["tokens"] == chain[:6]
+        _stats_once(decode_steps=5)
+        profiler.generate_reset()
+        srv._step_hook = lambda: time.sleep(0.01)
+        fut = srv.submit(prompt, max_new_tokens=50, deadline=0.2,
+                         stream_fn=seen.extend)
+        with pytest.raises(DeadlineExceeded):
+            fut.result(timeout=60)
+        srv._step_hook = None
+        st = srv.stats()
+        assert 0 < len(seen) < 50 and seen == chain[:len(seen)]
+        assert st["deadline"] == 1 and st["tokens"] == len(seen)
+        assert st["pages_in_use"] == 0
+        # the slot and its pages serve the next request
+        assert srv.generate(prompt, max_new_tokens=6)["tokens"] == chain[:6]
+    # the step in flight when the deadline passed was dropped, once read
+    assert profiler.generate_stats()["decode_tokens_discarded"] == 1
+
+
+def test_close_with_a_step_in_flight_fails_every_future(model):
+    cfg, params = model
+    seen = []
+    srv = _serve(cfg, params, page_size=8, max_steps=60, slots=1)
+    srv._step_hook = lambda: time.sleep(0.005)
+    running = srv.submit(_prompt(8, 0), max_new_tokens=50,
+                         stream_fn=seen.extend)
+    queued = srv.submit(_prompt(8, 1), max_new_tokens=4)
+    while len(seen) < 3:
+        time.sleep(0.001)
+    srv.close()
+    for fut in (running, queued):
+        with pytest.raises(ServerClosed):
+            fut.result(timeout=10)
+    assert not srv._thread.is_alive()
+    assert srv.predictor.pool.in_use == 0
+    assert 3 <= len(seen) < 50
+
+
+def test_failing_step_with_a_step_in_flight_fails_every_future(model):
+    cfg, params = model
+    calls = []
+
+    def hook():
+        calls.append(1)
+        if len(calls) == 4:          # steps 1-3 dispatched, step 3 unread
+            raise RuntimeError("step failed")
+
+    srv = _serve(cfg, params, page_size=8, slots=2)
+    srv._step_hook = hook
+    futures = [srv.submit(_prompt(8, i), max_new_tokens=20)
+               for i in range(3)]
+    for fut in futures:
+        with pytest.raises(RuntimeError, match="step failed"):
+            fut.result(timeout=30)
+    srv._thread.join(10)
+    assert not srv._thread.is_alive()
+    assert srv.predictor.pool.in_use == 0
+    with pytest.raises(GenerateError.__mro__[1], match="worker died"):
+        srv.submit(_prompt(8, 0))
+    srv.close()
+
+
+@pytest.mark.parametrize("flush", [1, 3])
+def test_stream_fn_sees_every_token_once_in_order(model, flush):
+    cfg, params = _transformer()
+    prompts = [_prompt(5 + 3 * i, 20 + i) for i in range(5)]
+    seen = [[] for _ in prompts]
+    with _serve(cfg, params, page_size=8, stream_flush=flush) as srv:
+        futures = [srv.submit(p, max_new_tokens=7 + 4 * i,
+                              stream_fn=seen[i].extend)
+                   for i, p in enumerate(prompts)]
+        results = [f.result(timeout=60) for f in futures]
+    for i, (p, r) in enumerate(zip(prompts, results)):
+        assert seen[i] == r["tokens"]
+        assert r["tokens"] == _host_chain(cfg, params, p, 7 + 4 * i,
+                                          page_size=8)
+    assert profiler.generate_stats()["tokens"] == sum(map(len, seen))
+
+
+@pytest.mark.parametrize("answer,ahead", [(1, 0), (2, 0), (40, 38)])
+def test_decode_ahead_share_by_answer_length(model, answer, ahead):
+    cfg, params = model
+    with _serve(cfg, params, page_size=8) as srv:
+        assert len(srv.generate(_prompt(8, 0),
+                                max_new_tokens=answer)["tokens"]) == answer
+    st = profiler.generate_stats()
+    assert st["decode_steps"] == answer - 1
+    assert st["decode_steps_ahead"] == ahead
+    # no step, no share; one step has nothing ahead of it; a long answer
+    # has all but its first
+    assert st.get("decode_ahead_share", 0) == \
+        (ahead / (answer - 1) if answer > 1 else 0)
+    assert (answer < 40) or st["decode_ahead_share"] > 0.97
+
+
+def test_drain_policy_keeps_a_step_in_flight_too(model):
+    cfg, params = _transformer()
+    prompts = [_prompt(7, 30), _prompt(9, 31), _prompt(11, 32)]
+    with _serve(cfg, params, page_size=8, admit_policy="drain") as srv:
+        futures = [srv.submit(p, max_new_tokens=12) for p in prompts]
+        got = [f.result(timeout=60)["tokens"] for f in futures]
+    assert got == [_host_chain(cfg, params, p, 12, page_size=8)
+                   for p in prompts]
+    st = profiler.generate_stats()
+    assert st["decode_ahead_share"] > 0.8 and st["pages_in_use"] == 0
+
+
+def test_decode_returns_logits_and_counters_as_before(any_model):
+    cfg, params = any_model
+    pred = GenerativePredictor(cfg, params, slots=2, page_size=4, max_ctx=64)
+    pages = pred.pool.alloc(3)
+    table = np.zeros((2, pred.max_pages_per_slot), np.int32)
+    table[0, :3] = pages
+    first = int(np.argmax(pred.prefill(_prompt(7, 3), pages[:2])))
+    logits = pred.decode([first, 0], [7, 0], table, [True, False])
+    assert isinstance(logits, np.ndarray) and logits.shape == (2, cfg.vocab)
+    assert set(pred.step_counters) == set(pred._counter_names)
+    # the second entry runs the same program and reads nothing; what it
+    # returns holds the ids the logits put first, and the counters
+    chosen = pred.decode_ahead([first, 0], [True, True], [7, 0], table,
+                               [True, False])
+    ids, counters = pred.read_step(chosen)
+    assert ids.dtype == np.int32 and ids.tolist() == [
+        int(np.argmax(logits[0])), 0]
+    assert counters == pred.step_counters
+    # and a token left on the device is the one the host would have sent
+    nxt = pred.decode_ahead([0, 0], [False, False], [8, 0], table,
+                            [True, False])
+    want = pred.decode([int(ids[0]), 0], [8, 0], table, [True, False])
+    assert pred.read_step(nxt)[0][0] == int(np.argmax(want[0]))

@@ -291,20 +291,31 @@ def test_broker_spans_and_counters_for_three_requests(model):
         out = next(r for r in results if r["rid"] == e["args"]["rid"])
         assert e["args"]["reason"] == out["finish_reason"] == "length"
         assert e["args"]["tokens"] == len(out["tokens"])
-    steps = sorted(by["mx.serve.decode_step"], key=lambda e: e["ts"])
+    # a turn of the decode loop dispatches a step (``active`` slots, numbered
+    # ``step``) and reads the one dispatched a turn before: every step run
+    # is dispatched in one span and read in one, the next or a later one
+    turns = sorted(by["mx.serve.decode_step"], key=lambda e: e["ts"])
+    steps = [e for e in turns if e["args"]["active"]]
     assert [e["args"]["step"] for e in steps] == list(range(len(steps)))
-    for e in steps:
-        assert 1 <= e["args"]["active"] <= 2
+    reads = 0
+    for e in turns:
+        assert 0 <= e["args"]["active"] <= 2
         device = [d for d in by["mx.serve.decode.device"] if _inside(d, e)]
         sample = [d for d in by["mx.serve.decode.sample"] if _inside(d, e)]
-        assert len(device) == 1 and len(sample) == 1
-        assert device[0]["ts"] <= sample[0]["ts"]
+        assert len(device) == 1 and len(sample) <= 1
+        assert e["args"]["active"] or sample
+        if sample:
+            assert device[0]["ts"] <= sample[0]["ts"]
+            reads += 1
         assert any(_inside(e, turn) for turn in by["mx.serve.loop"])
+    assert reads == len(steps)
     assert sum(e["args"]["admitted"] for e in by["mx.serve.admit"]) == 3
-    assert len(by["mx.serve.grow_pages"]) == len(steps)
+    assert len(steps) <= len(by["mx.serve.grow_pages"]) <= len(turns)
 
     st = profiler.generate_stats()
     assert st["decode_steps"] == len(steps) and st["prefills"] == 3
+    assert 0 < st["decode_steps_ahead"] < st["decode_steps"]
+    assert st["decode_tokens_discarded"] == 0
     assert st["prefill_seconds"] > 0 and st["decode_seconds"] > 0
     assert st["prefill_seconds"] + st["decode_seconds"] == st["busy_seconds"]
     assert st["loop_seconds"] >= st["busy_seconds"]

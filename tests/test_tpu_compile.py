@@ -206,3 +206,132 @@ def test_longcat_decode_reads_its_eight_latent_pools_in_place(one_chip, monkeypa
     assert re.search(shape + r"\{2,1,0:T\(8,128\)\(2,1\)\} parameter\(", text)
     assert not re.search(r"= %s\S* (copy|pad|slice|dynamic-slice|transpose)\("
                          % shape, text)
+
+
+# -- ISSUE 39: the decode step as it is served ---------------------------------
+# ``GenerativePredictor`` wraps each module's ``make_decode_fn`` once
+# (``serving.generate._decode_program``): the token is chosen on the device and
+# can be fed to the next step there. The modules' own programs stay what they
+# were: sha256 of the StableHLO text each lowers to for the TPU at its cell's
+# sizes, taken at the commit before (6c94e2d). A PR that means to change one
+# says so and replaces its digest:
+#   python -c "import tests.test_tpu_compile as t; print(t.decode_digests())"
+MODULE_DECODE_PROGRAMS = {
+    "opt-1.3b.serve-chat":
+        "72d700654c3a0e7db8c247bd392929f8df8da70ea0fe3e80be7a0c5a7d447728",
+    "glm-5.decode-pool-16k":
+        "f9292b53755e26a6d1ccd3f127041541bdc2fd0432c42c5f89203ea5787402a3",
+    "longcat-flash.decode-pool-12k":
+        "41949d1eb0a3197721cb27a6924453d2becf1b3a4035b2df17906a8e4db095c4",
+}
+
+
+def _served_cells():
+    """cell -> (module, config, parameter shapes, slots, pages a slot, page):
+    benchmark/configs/*.json and benchmark/traffic/*.json."""
+    from mxnet_tpu.models import mla_moe, scmoe
+
+    opt = tfm.TransformerConfig(vocab=50272, d_model=2048, n_heads=32,
+                                n_layers=24, d_ff=8192, max_len=2048,
+                                dtype="bfloat16")
+    glm, longcat = mla_moe.LatentMoEConfig(), scmoe.ShortcutMoEConfig()
+
+    def table(mod, cfg):
+        return {k: (s, jnp.dtype(cfg.dtype))
+                for k, (s, _kind) in mod.param_shapes(cfg).items()}
+
+    return {
+        "opt-1.3b.serve-chat": (
+            tfm, opt, {k: (s, jnp.float32)
+                       for k, s in _param_shapes(opt).items()}, 16, 1216 // 16, 16),
+        "glm-5.decode-pool-16k": (
+            mla_moe, glm, table(mla_moe, glm), 16, 18688 // 16, 16),
+        "longcat-flash.decode-pool-12k": (
+            scmoe, longcat, table(scmoe, longcat), 16, 15104 // 16, 16),
+    }
+
+
+def _decode_arguments(cell, sharding=None):
+    mod, cfg, table, slots, per_slot, page = _served_cells()[cell]
+
+    def shape(s, dtype):
+        return jax.ShapeDtypeStruct(tuple(s), dtype, sharding=sharding)
+
+    params = {k: shape(s, dtype) for k, (s, dtype) in table.items()}
+    cache = jax.tree.map(
+        lambda x: shape(x.shape, x.dtype),
+        jax.eval_shape(lambda: mod.init_kv_cache(cfg, slots * per_slot, page)))
+    steps = (shape((slots,), jnp.int32), shape((slots, per_slot), jnp.int32),
+             shape((slots,), jnp.bool_))
+    block_k = getattr(mod, "_decode_block_k", lambda *_: 0)(
+        cfg, slots, per_slot * page)
+    return mod, cfg, params, cache, steps, (slots, per_slot, page, block_k)
+
+
+def _as_on_tpu(monkeypatch):
+    import mxnet_tpu.kernels  # noqa: F401  (loads kernels.flash_attention)
+    from mxnet_tpu.models import scmoe  # noqa: F401
+
+    for name in ("mxnet_tpu.models.transformer", "mxnet_tpu.models.scmoe",
+                 "mxnet_tpu.kernels.flash_attention"):
+        monkeypatch.setattr(sys.modules[name], "kernel_platform", lambda: "tpu")
+
+
+def decode_digests():
+    import hashlib
+
+    out = {}
+    with pytest.MonkeyPatch.context() as patch:
+        _as_on_tpu(patch)
+        for cell in MODULE_DECODE_PROGRAMS:
+            mod, cfg, params, cache, (ids, tables, mask), (
+                slots, per_slot, page, block_k) = _decode_arguments(cell)
+            lowered = jax.jit(mod.make_decode_fn(
+                cfg, slots, per_slot, page, block_k=block_k)).trace(
+                params, cache, ids, ids, tables, mask).lower(
+                lowering_platforms=("tpu",))
+            # a Mosaic kernel's serialized body carries the paths of the
+            # files it was traced through, this one among them: left out
+            text = re.sub(r'\\22body\\22: \\22[^\\]*\\22', "", lowered.as_text())
+            out[cell] = hashlib.sha256(text.encode()).hexdigest()
+    return out
+
+
+_DECODE_DIGESTS = {}
+
+
+@pytest.mark.parametrize("cell", sorted(MODULE_DECODE_PROGRAMS))
+def test_module_decode_programs_lower_as_before(cell):
+    if not _DECODE_DIGESTS:
+        _DECODE_DIGESTS.update(decode_digests())
+    assert _DECODE_DIGESTS[cell] == MODULE_DECODE_PROGRAMS[cell]
+
+
+@pytest.mark.parametrize("cell", sorted(MODULE_DECODE_PROGRAMS))
+def test_served_decode_chooses_the_token_on_the_device(cell, one_chip,
+                                                       monkeypatch):
+    from mxnet_tpu.serving import generate
+
+    _as_on_tpu(monkeypatch)
+    mod, cfg, params, cache, (ids, tables, mask), (
+        slots, per_slot, page, block_k) = _decode_arguments(cell, one_chip)
+    counters = len(getattr(mod, "DECODE_COUNTERS", ()))
+    last = jax.ShapeDtypeStruct((slots + counters,), jnp.int32,
+                                sharding=one_chip)
+    fn = jax.jit(generate._decode_program(mod, cfg, slots, per_slot, page,
+                                          block_k), donate_argnums=(1,))
+    compiled = fn.lower(params, cache, last, ids, mask, ids, tables,
+                        mask).compile()
+    text = compiled.as_text()
+    # the trace's name for it, which the benchmark's readers match
+    assert re.match(r"HloModule jit_decode[,\s]", text)
+    # beside the logits, the ids and the module's counters in one array that
+    # the next step takes as it is
+    _cache, (logits, chosen) = compiled.out_info
+    assert logits.shape == (slots, cfg.vocab)
+    assert (chosen.shape, chosen.dtype) == ((slots + counters,), jnp.int32)
+    assert (chosen.shape, chosen.dtype) == (last.shape, last.dtype)
+    # and the pools are still the donated ones
+    pool_bytes = sum(math.prod(x.shape) * x.dtype.itemsize
+                     for x in jax.tree.leaves(cache))
+    assert compiled.memory_analysis().alias_size_in_bytes >= pool_bytes
