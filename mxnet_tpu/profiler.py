@@ -16,7 +16,7 @@ one test of ``_STATE["running"]`` and the annotation's own inactive path.
 
 Names are fixed strings ``mx.<layer>.<what>``; arguments carry identity
 (``epoch``, ``nbatch``, ``rid``, ``slot``, ``bucket``, ``tokens``, ``active``,
-``step``), never free text.  Every span of the program:
+``step``, ``generation``), never free text.  Every span of the program:
 
 ==========================  ==================================================
 ``mx.executor.forward``     ``Executor.forward``: one compiled program
@@ -53,16 +53,22 @@ Names are fixed strings ``mx.<layer>.<what>``; arguments carry identity
                             bucket, prefix_len, queue_wait_ms, active)
 ``mx.serve.prefill.device`` the predictor's prefill call, dispatch to logits
 ``mx.serve.grow_pages``     page growth before a decode step is dispatched
-``mx.serve.decode_step``    one turn of the decode loop, which keeps a step
-                            in flight: step ``step`` dispatched for ``active``
-                            slots (0: none), the step before read; or one
-                            speculative round (step, active)
-``mx.serve.decode.device``  the dispatch of that step and the wait for the
-                            ids the step before chose on the device (a
-                            speculative round: dispatch to logits)
+``mx.serve.decode.device``  a turn of the decode loop, which keeps a step in
+                            flight, up to the ids on the host: the next step
+                            dispatched, the step before read (a speculative
+                            round: dispatch to logits, step, active)
+``mx.serve.decode.dispatch``  step ``step`` dispatched for ``active`` slots
+``mx.serve.decode.read``    the wait for the ids step ``step`` chose on the
+                            device: every step run is in one dispatch span
+                            and one read span of the same ``step``
 ``mx.serve.decode.sample``  the read step's ids appended, streamed, finished,
                             all slots (a speculative round: argmax too)
 ``mx.serve.finish``         a request leaves its slot (rid, reason, tokens)
+``mx.serve.wait_work``      the broker idle, waiting for a request: one slice
+                            of at most 50 ms, a notify ends it at once
+``mx.host.gc``              one collection of Python's garbage collector,
+                            on whichever thread triggered it (generation,
+                            collected)
 ==========================  ==================================================
 
 Inside the compiled programs the same naming is ``jax.named_scope`` metadata
@@ -83,6 +89,7 @@ shared expert, ``mx.lm.moe.zero`` (the identity experts' term) and, under
 """
 from __future__ import annotations
 
+import gc
 import json
 import os
 import threading
@@ -97,7 +104,9 @@ _STATE = {
     "events": [],
     "jax_trace_dir": None,
 }
-_LOCK = threading.Lock()
+# reentrant: a collection can start inside any ``with _LOCK`` body, and its
+# span takes the lock on the same thread
+_LOCK = threading.RLock()
 
 
 def profiler_set_config(mode="symbolic", filename="profile.json"):
@@ -170,6 +179,23 @@ def span(name, /, **args):
     if _STATE["running"]:
         return _ChromeSpan(name, args)
     return _Annotation(name, **args)
+
+
+_GC_SPAN = [None]   # the collection open now: one runs at a time, GIL held
+
+
+def _on_gc(phase, info):
+    """``gc.callbacks``: each collection as the span ``mx.host.gc``."""
+    if phase == "start":
+        _GC_SPAN[0] = span("mx.host.gc", generation=info["generation"])
+        _GC_SPAN[0].__enter__()
+    elif _GC_SPAN[0] is not None:
+        s, _GC_SPAN[0] = _GC_SPAN[0], None
+        s.set_metadata(collected=info["collected"])
+        s.__exit__(None, None, None)
+
+
+gc.callbacks.append(_on_gc)
 
 
 def all_operators():

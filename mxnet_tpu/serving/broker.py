@@ -54,6 +54,9 @@ from .predictor import (
     env_positive_int,
 )
 
+# the generate broker waits for work in slices this long, each a span
+_WAIT_SLICE_S = 0.05
+
 
 class DeadlineExceeded(ServingError):
     """A request's deadline expired before it was dispatched: it was
@@ -541,13 +544,14 @@ class _GenRequest:
 
 class _Step:
     """A decode step that was dispatched and not yet read."""
-    __slots__ = ("chosen", "slots", "reqs", "counts")
+    __slots__ = ("chosen", "slots", "reqs", "counts", "step")
 
-    def __init__(self, chosen, slots, reqs, counts):
+    def __init__(self, chosen, slots, reqs, counts, step):
         self.chosen = chosen         # on the device: ids, then counters
         self.slots = slots           # the slots it ran for ...
         self.reqs = reqs             # ... and whose they were at dispatch
         self.counts = counts         # what the host counted at dispatch
+        self.step = step             # its number, on its dispatch and read spans
 
 
 class GenerateServer:
@@ -1091,7 +1095,7 @@ class GenerateServer:
         self._prefilled = False
         self._decode_steps += 1
         return _Step(chosen, slots, [self._slot_req[s] for s in slots],
-                     counts)
+                     counts, self._decode_steps - 1)
 
     def _decode_step(self, dispatch=True):
         """One turn of the plain decode loop, which keeps a step in flight:
@@ -1108,33 +1112,34 @@ class GenerateServer:
             self._step_hook()
         slots = self._next_slots() if dispatch else ()
         before, self._inflight = self._inflight, None
-        with profiler.span("mx.serve.decode_step", step=self._decode_steps,
-                           active=len(slots)):
-            t0 = time.perf_counter()
-            with profiler.span("mx.serve.decode.device"):
-                if len(slots):
+        t0 = time.perf_counter()
+        with profiler.span("mx.serve.decode.device"):
+            if len(slots):
+                with profiler.span("mx.serve.decode.dispatch",
+                                   step=self._decode_steps, active=len(slots)):
                     self._inflight = self._dispatch(slots, before is not None)
-                if before is not None:
+            if before is not None:
+                with profiler.span("mx.serve.decode.read", step=before.step):
                     ids, counters = pred.read_step(before.chosen)
-            seconds = time.perf_counter() - t0
-            if before is None:
-                profiler.generate_record(decode_seconds=seconds)
-                return
-            kept = 0
-            with profiler.span("mx.serve.decode.sample"):
-                for slot, r in zip(before.slots, before.reqs):
-                    if self._slot_req[slot] is not r:
-                        continue     # it left on the token before this one
-                    kept += 1
-                    tok = int(ids[slot])
-                    r.out.append(tok)
-                    r.unflushed.append(tok)
-                    self._flush_stream(r)
-                    self._check_done(r, tok)
-            self._step_counts(
-                kept, kept, seconds,
-                decode_tokens_discarded=len(before.slots) - kept,
-                **before.counts, **counters)
+        seconds = time.perf_counter() - t0
+        if before is None:
+            profiler.generate_record(decode_seconds=seconds)
+            return
+        kept = 0
+        with profiler.span("mx.serve.decode.sample"):
+            for slot, r in zip(before.slots, before.reqs):
+                if self._slot_req[slot] is not r:
+                    continue     # it left on the token before this one
+                kept += 1
+                tok = int(ids[slot])
+                r.out.append(tok)
+                r.unflushed.append(tok)
+                self._flush_stream(r)
+                self._check_done(r, tok)
+        self._step_counts(
+            kept, kept, seconds,
+            decode_tokens_discarded=len(before.slots) - kept,
+            **before.counts, **counters)
 
     def _spec_step(self):
         """One speculative-decoding round (ISSUE 16), replacing one
@@ -1165,20 +1170,17 @@ class GenerateServer:
         active = [int(s) for s in np.flatnonzero(self._active)]
         if not active:
             return
-        with profiler.span("mx.serve.decode_step", step=self._decode_steps,
+        t0 = time.perf_counter()
+        with profiler.span("mx.serve.decode.device", step=self._decode_steps,
                            active=len(active)):
-            t0 = time.perf_counter()
-            with profiler.span("mx.serve.decode.device"):
-                chain_len, k_i, props, logits = self._spec_propose_verify(
-                    active)
-            seconds = time.perf_counter() - t0
-            with profiler.span("mx.serve.decode.sample"):
-                emitted = self._spec_accept(active, chain_len, k_i, props,
-                                            logits)
-            self._step_counts(len(active), emitted, seconds, spec_rounds=1,
-                              decode_steps_after_prefill=int(self._prefilled))
-            self._prefilled = False
-            self._decode_steps += 1
+            chain_len, k_i, props, logits = self._spec_propose_verify(active)
+        seconds = time.perf_counter() - t0
+        with profiler.span("mx.serve.decode.sample"):
+            emitted = self._spec_accept(active, chain_len, k_i, props, logits)
+        self._step_counts(len(active), emitted, seconds, spec_rounds=1,
+                          decode_steps_after_prefill=int(self._prefilled))
+        self._prefilled = False
+        self._decode_steps += 1
 
     def _spec_propose_verify(self, active):
         """Draft and verify phases of one round: per-slot chain lengths,
@@ -1276,11 +1278,14 @@ class GenerateServer:
         return emitted_total
 
     def _wait_for_work(self):
-        """Block until there is something to do; False once stopped."""
+        """Block until there is something to do; False once stopped.  Waits
+        in slices, each a span, so a trace that starts or stops inside the
+        wait loses at most one."""
         with self._cond:
             while (not self._q and not self._active_count()
                    and self._inflight is None and not self._stopped):
-                self._cond.wait()
+                with profiler.span("mx.serve.wait_work"):
+                    self._cond.wait(_WAIT_SLICE_S)
             return not self._stopped
 
     def _turn(self):

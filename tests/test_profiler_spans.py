@@ -3,8 +3,10 @@ generate broker and the jitted steps, with the counters recorded at the same
 boundaries.  CPU, tiny sizes; the Chrome events stand in for the trace's host
 plane (``span`` writes both from the same enter and exit)."""
 import ast
+import gc
 import inspect
 import threading
+import time
 
 import numpy as np
 import pytest
@@ -39,8 +41,9 @@ def _recorded(fn):
 
 
 def _inside(child, parent):
+    # the events' times are whole microseconds, each rounded down
     return (child["tid"] == parent["tid"] and parent["ts"] <= child["ts"]
-            and child["ts"] + child["dur"] <= parent["ts"] + parent["dur"])
+            and child["ts"] + child["dur"] <= parent["ts"] + parent["dur"] + 1)
 
 
 # ---------------------------------------------------------------------------
@@ -291,24 +294,34 @@ def test_broker_spans_and_counters_for_three_requests(model):
         out = next(r for r in results if r["rid"] == e["args"]["rid"])
         assert e["args"]["reason"] == out["finish_reason"] == "length"
         assert e["args"]["tokens"] == len(out["tokens"])
-    # a turn of the decode loop dispatches a step (``active`` slots, numbered
-    # ``step``) and reads the one dispatched a turn before: every step run
-    # is dispatched in one span and read in one, the next or a later one
-    turns = sorted(by["mx.serve.decode_step"], key=lambda e: e["ts"])
-    steps = [e for e in turns if e["args"]["active"]]
-    assert [e["args"]["step"] for e in steps] == list(range(len(steps)))
-    reads = 0
-    for e in turns:
-        assert 0 <= e["args"]["active"] <= 2
-        device = [d for d in by["mx.serve.decode.device"] if _inside(d, e)]
-        sample = [d for d in by["mx.serve.decode.sample"] if _inside(d, e)]
-        assert len(device) == 1 and len(sample) <= 1
-        assert e["args"]["active"] or sample
-        if sample:
-            assert device[0]["ts"] <= sample[0]["ts"]
-            reads += 1
-        assert any(_inside(e, turn) for turn in by["mx.serve.loop"])
-    assert reads == len(steps)
+    # a turn of the decode loop (``mx.serve.decode.device``) dispatches a step
+    # for ``active`` slots and reads the ids of the one dispatched a turn
+    # before: every step run is in one dispatch span and one read span of the
+    # same ``step``, the read after the dispatch, in the same turn or a later
+    assert "mx.serve.decode_step" not in by
+    turns = sorted(by["mx.serve.decode.device"], key=lambda e: e["ts"])
+    steps = by["mx.serve.decode.dispatch"]
+    dispatched = {e["args"]["step"]: e for e in steps}
+    read = {e["args"]["step"]: e for e in by["mx.serve.decode.read"]}
+    assert len(dispatched) == len(steps)
+    assert len(read) == len(by["mx.serve.decode.read"])
+    assert sorted(dispatched) == sorted(read) == list(range(len(steps)))
+
+    def turn_of(e):
+        (i,) = [i for i, t in enumerate(turns) if _inside(e, t)]
+        return i
+
+    for step, d in dispatched.items():
+        r = read[step]
+        assert 1 <= d["args"]["active"] <= 2
+        assert d["ts"] + d["dur"] <= r["ts"] and turn_of(d) <= turn_of(r)
+    for i, t in enumerate(turns):
+        assert any(turn_of(e) == i for e in steps + by["mx.serve.decode.read"])
+        assert any(_inside(t, turn) for turn in by["mx.serve.loop"])
+    assert len(by["mx.serve.decode.sample"]) == len(read)
+    # one span more a turn than a turn spanned before (decode_step, device,
+    # sample): dispatch, read, and the device and sample around them
+    assert len(steps) + len(read) <= 2 * len(turns)
     assert sum(e["args"]["admitted"] for e in by["mx.serve.admit"]) == 3
     assert len(steps) <= len(by["mx.serve.grow_pages"]) <= len(turns)
 
@@ -329,6 +342,49 @@ def test_broker_spans_and_counters_for_three_requests(model):
         1e3 * st["prefill_seconds"] / 3)
     assert len(streamed) == sum(len(r["tokens"]) for r in results)
     assert 0 < st["stream_seconds"] < st["loop_seconds"]
+
+
+def test_idle_broker_waits_in_spans_of_a_slice_and_wakes_on_submit(model, monkeypatch):
+    from mxnet_tpu.serving import broker
+
+    cfg, params = model
+    prompt = np.arange(1, 9)
+    slice_us = 1e6 * broker._WAIT_SLICE_S
+
+    def serve():
+        with GenerateServer(cfg, params, slots=2, page_size=8, max_steps=16,
+                            name="twait") as srv:
+            srv.submit(prompt, max_new_tokens=2).result(timeout=60)  # compiled
+            time.sleep(0.4)
+            # a slice far longer than the test: only the notify can end it
+            monkeypatch.setattr(broker, "_WAIT_SLICE_S", 300.0)
+            time.sleep(0.5)
+            t0 = time.perf_counter()
+            srv.submit(prompt, max_new_tokens=2).result(timeout=60)
+            waited.append(time.perf_counter() - t0)
+
+    waited = []
+    events = _recorded(serve)
+    assert waited[0] < 30
+    waits = [e for e in events if e["name"] == "mx.serve.wait_work"]
+    loop = {e["tid"] for e in events if e["name"] == "mx.serve.loop"}
+    assert {e["tid"] for e in waits} == loop
+    # 0.4 s idle in slices of 50 ms, then the long slice the submit ended
+    short = [e for e in waits if e["dur"] < 2e5]
+    assert len(short) >= 5 and len(waits) - len(short) == 1
+    assert max(e["dur"] for e in short) <= slice_us + 25e3
+
+
+def test_a_collection_is_one_span_with_its_generation():
+    gc.disable()
+    try:
+        events = _recorded(lambda: gc.collect(1))
+    finally:
+        gc.enable()
+    (e,) = [e for e in events if e["name"] == "mx.host.gc"]
+    assert e["args"]["generation"] == 1 and e["args"]["collected"] >= 0
+    assert e["tid"] == threading.get_ident() and e["cat"] == "gc"
+    assert profiler._GC_SPAN == [None]
 
 
 def test_generate_record_still_refuses_unknown_names():
