@@ -143,12 +143,65 @@ def param_specs(config, mesh):
     return sp
 
 
-def _layernorm(x, gamma, beta, eps=1e-5):
+# What the layer scan saves for the backward (ISSUE 41). Autodiff of the
+# float32 LayerNorm would keep float32 copies of activation size (x - mu,
+# the normalised value, ...) and of jax.nn.relu a bool mask of its input,
+# stacked over the layers. These VJPs keep the input as given (bfloat16 in
+# training) with a float32 mean and rstd a row, and the ReLU's output, which
+# ffn-down saves anyway. Forwards compute what they did, so programs that
+# never differentiate (prefill, decode, extend) lower to the same operations.
+def _ln(x, gamma, beta, eps):
+    """(output, mean, rstd): the float32 arithmetic, op for op in the order
+    the programs have always lowered it."""
     x32 = x.astype(jnp.float32)
     mu = jnp.mean(x32, axis=-1, keepdims=True)
     var = jnp.mean(jnp.square(x32 - mu), axis=-1, keepdims=True)
-    y = (x32 - mu) * lax.rsqrt(var + eps)
-    return (y * gamma + beta).astype(x.dtype)
+    centred = x32 - mu
+    rstd = lax.rsqrt(var + eps)
+    return ((centred * rstd) * gamma + beta).astype(x.dtype), mu, rstd
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3,))
+def _layernorm(x, gamma, beta, eps=1e-5):
+    return _ln(x, gamma, beta, eps)[0]
+
+
+def _layernorm_fwd(x, gamma, beta, eps):
+    y, mu, rstd = _ln(x, gamma, beta, eps)
+    return y, (x, mu, rstd, gamma)
+
+
+def _layernorm_bwd(eps, res, g):
+    # gamma and beta are float32 (d,) here; their gradients sum the rows
+    x, mu, rstd, gamma = res
+    xhat = (x.astype(jnp.float32) - mu) * rstd
+    g32 = g.astype(jnp.float32)
+    rows = tuple(range(g.ndim - 1))
+    gx = g32 * gamma
+    dx = rstd * (gx - jnp.mean(gx, axis=-1, keepdims=True)
+                 - xhat * jnp.mean(gx * xhat, axis=-1, keepdims=True))
+    return (dx.astype(x.dtype), jnp.sum(g32 * xhat, axis=rows),
+            jnp.sum(g32, axis=rows))
+
+
+_layernorm.defvjp(_layernorm_fwd, _layernorm_bwd)
+
+
+@jax.custom_vjp
+def _relu(x):
+    return jax.nn.relu(x)
+
+
+def _relu_fwd(x):
+    y = jax.nn.relu(x)
+    return y, y
+
+
+def _relu_bwd(y, g):
+    return (jnp.where(y > 0, g, jnp.zeros_like(g)),)
+
+
+_relu.defvjp(_relu_fwd, _relu_bwd)
 
 
 def _attention(q, k, v, *, axes, causal=True, attn="auto", blocks=None):
@@ -222,7 +275,7 @@ def _ffn(x, lp, c, axes, cdt):
             e0 = lax.axis_index("ep") * e_loc if "ep" in axes else 0
             g_loc = lax.dynamic_slice_in_dim(gate, e0, e_loc, axis=-1).astype(cdt)
             up = jnp.einsum("bsd,edf->besf", h, lp["ffn_up_weight"].astype(cdt))
-            act = jax.nn.relu(up)
+            act = _relu(up)
             down = jnp.einsum("besf,efd->besd", act,
                               lp["ffn_down_weight"].astype(cdt))
             f = jnp.einsum("besd,bse->bsd", down, g_loc)
@@ -231,8 +284,8 @@ def _ffn(x, lp, c, axes, cdt):
             if t:
                 f = lax.psum(f, t)     # d_ff was also mp-sharded
         else:
-            up = jax.nn.relu(jnp.einsum("bsd,df->bsf", h,
-                                        lp["ffn_up_weight"].astype(cdt)))
+            up = _relu(jnp.einsum("bsd,df->bsf", h,
+                                  lp["ffn_up_weight"].astype(cdt)))
             f = jnp.einsum("bsf,fd->bsd", up, lp["ffn_down_weight"].astype(cdt))
             if t:
                 f = lax.psum(f, t)     # row-parallel ffn-down
