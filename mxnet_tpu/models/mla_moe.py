@@ -6,15 +6,22 @@ The block (pre-norm, RMSNorm, residual in the compute type; ``h`` is the
 normed input of a half):
 
 - **latent attention** (DeepSeek-V2/V3's MLA).  ``c_q = RMSNorm(h W_qa)``,
-  ``[q_nope | q_rope] = c_q W_qb`` a head; ``[c_kv | k_rope] = h W_kva``,
-  ``c_kv = RMSNorm(c_kv)``; interleaved rotary on ``q_rope`` and on the one
-  ``k_rope`` all heads share; ``[k_nope | v] = c_kv W_kvb`` a head;
-  ``softmax((q_nope . k_nope + q_rope . k_rope) / sqrt(d_nope + d_rope))``
-  over the *allowed* keys.  The cache holds one latent row
-  ``[c_kv | k_rope]`` a token a layer.  Prefill runs the expanded form,
-  decode the absorbed one (``q' = q_nope W_kvb[k]^T`` scored against
-  ``c_kv`` itself, ``P c_kv`` up-projected by ``W_kvb[v]``).
-- **indexer** (DeepSeek-V3.2's lightning indexer), every layer.
+  ``[q_nope | q_rope] = c_q W_qb`` a head, or with ``q_rank`` 0 a full-rank
+  query ``h W_q``; with ``qk_norm`` an RMSNorm over each head's query, one
+  gain all heads share; ``[c_kv | k_rope] = h W_kva``, ``c_kv =
+  RMSNorm(c_kv)``, with ``qk_norm`` an RMSNorm over ``k_rope`` too; interleaved
+  rotary on ``q_rope`` and on the one ``k_rope`` all heads share (plain, or
+  DeepSeek's YaRN where ``yarn_factor`` is set); ``[k_nope | v] = c_kv
+  W_kvb`` a head; ``softmax((q_nope . k_nope + q_rope . k_rope) s)`` with
+  ``s = (d_nope + d_rope)^-1/2`` (times YaRN's ``mscale^2``) over the
+  *allowed* keys.  The cache holds one latent row ``[c_kv | k_rope]`` a
+  token a layer.  Prefill runs the expanded form, decode the absorbed one
+  (``q' = q_nope W_kvb[k]^T`` scored against ``c_kv`` itself, ``P c_kv``
+  up-projected by ``W_kvb[v]``).
+- **indexer** (DeepSeek-V3.2's lightning indexer), every layer, unless
+  ``indexer`` is off: then every position attends all earlier ones, and
+  decode reads every cached row through :func:`paged_attention` (the
+  Pallas kernel ``mx_mla_paged_decode`` on a TPU).
   ``q_I = c_q W_Iq`` (heads x dim), ``k_I = LayerNorm(h W_Ik)``, rotary on
   the first ``index_rope_dim`` of both, ``w = h W_Iw`` scaled by
   ``heads^-1/2 dim^-1/2``; ``I[t, s] = sum_h w[t, h] relu(q_I[t, h] . k_I[s])``
@@ -36,8 +43,9 @@ normed input of a half):
 
 Entry points are those ``GenerativePredictor`` asks a model module for:
 ``init_kv_cache``, ``kv_page_bytes``, ``make_prefill_fn``,
-``make_decode_fn`` and ``DECODE_COUNTERS``; ``make_forward_fn`` is the
-cache-free one-shot forward the tests hold both against.
+``make_decode_fn``, ``decode_counters`` and ``_decode_block_k``;
+``make_forward_fn`` is the cache-free one-shot forward the tests hold both
+against.
 """
 from __future__ import annotations
 
@@ -49,14 +57,25 @@ import jax.numpy as jnp
 import numpy as np
 from jax import lax
 
+from ..context import kernel_platform
+
 __all__ = ["LatentMoEConfig", "init_params", "init_kv_cache", "kv_page_bytes",
            "make_prefill_fn", "make_decode_fn", "make_forward_fn",
-           "DECODE_COUNTERS"]
+           "decode_counters", "paged_attention", "blocked_attention"]
 
 # what one decode step counts on the device, summed over its layers and
-# returned beside the logits (``profiler.generate_record`` names)
-DECODE_COUNTERS = ("moe_pairs_held", "moe_tokens", "moe_experts_touched",
-                   "moe_pairs_at_max_load", "dsa_keys_scanned", "dsa_keys_selected")
+# returned beside the logits (``profiler.generate_record`` names): with the
+# indexer, and without it (every cached row attended)
+INDEXED_DECODE_COUNTERS = ("moe_pairs_held", "moe_tokens", "moe_experts_touched",
+                           "moe_pairs_at_max_load", "dsa_keys_scanned", "dsa_keys_selected")
+DENSE_DECODE_COUNTERS = ("moe_pairs_held", "moe_tokens", "moe_experts_touched",
+                         "moe_pairs_at_max_load", "attn_rows_read")
+
+DECODE_BLOCK_K = 512     # cached rows an online-softmax turn of dense decode
+# DeepSeek YaRN's correction range: the rotary pairs that turn this many times
+# over the original context bound the ramp (``beta_fast``, ``beta_slow``); its
+# ``mscale`` and ``mscale_all_dim`` are 1, so cos and sin are not scaled
+YARN_BETA_FAST, YARN_BETA_SLOW = 32, 1
 
 
 @dataclasses.dataclass
@@ -72,7 +91,7 @@ class LatentMoEConfig:
     experts_per_token: int = 8
     held_experts: tuple = tuple(range(16))   # ids of the experts held here
     route_scale: float = 2.5
-    q_rank: int = 2048
+    q_rank: int = 2048              # 0: a full-rank query, h W_q
     kv_rank: int = 512
     d_nope: int = 192
     d_rope: int = 64
@@ -81,7 +100,13 @@ class LatentMoEConfig:
     index_dim: int = 128
     index_rope_dim: int = 64
     index_topk: int = 2048
+    indexer: bool = True            # False: every layer attends all earlier rows
+    # RMSNorms over each head's query (one gain) and the shared rotary key
+    qk_norm: bool = False
     rope_theta: float = 1e6
+    # DeepSeek's YaRN (``rope_scaling`` type ``deepseek_yarn``); factor 0: none
+    yarn_factor: float = 0.0
+    yarn_original: int = 4096       # original_max_position_embeddings
     norm_eps: float = 1e-5
     index_norm_eps: float = 1e-6
     max_len: int = 202752
@@ -97,11 +122,15 @@ def param_shapes(config):
     """name -> (shape, kind): ``normal`` matrices, ``ones`` gains, ``zeros``
     offsets, ``bias`` the router's correction bias.  Attention and indexer
     leaves are stacked over all layers, dense FFN leaves over the leading
-    dense layers, router and expert leaves over the layers that follow."""
+    dense layers, router and expert leaves over the layers that follow.  A
+    full-rank query is ``q_weight`` in the place of ``q_a_*`` and
+    ``q_b_weight``; the norms' gains ``q_norm`` and ``k_norm`` are there
+    where ``qk_norm`` is on, the indexer's leaves where the indexer is."""
     c = config
     d, L, H = c.d_model, c.n_layers, c.n_heads
     Ld, Lm, Eh = c.n_dense_layers, c.n_layers - c.n_dense_layers, len(c.held_experts)
-    return {
+    qk = c.d_nope + c.d_rope
+    out = {
         "embed_weight": ((c.vocab, d), "normal"),
         "head_weight": ((c.vocab, d), "normal"),
         "final_norm": ((d,), "ones"),
@@ -131,6 +160,17 @@ def param_shapes(config):
         "shared_up_weight": ((Lm, d, c.d_expert), "normal"),
         "shared_down_weight": ((Lm, c.d_expert, d), "normal"),
     }
+    if not c.q_rank:
+        for k in ("q_a_weight", "q_a_norm", "q_b_weight"):
+            del out[k]
+        out["q_weight"] = ((L, d, H, qk), "normal")
+    if not c.indexer:
+        for k in [k for k in out if k.startswith("index_")]:
+            del out[k]
+    if c.qk_norm:
+        out["q_norm"] = ((L, qk), "ones")
+        out["k_norm"] = ((L, c.d_rope), "ones")
+    return out
 
 
 def init_params(config, seed=0, scale=0.02, bias_scale=0.01):
@@ -163,24 +203,38 @@ def init_kv_cache(config, num_pages, page_size, dtype=None):
     """Zeroed page pool, two arrays a layer under one block table:
     ``latent[l]`` (pages + 1, page, :func:`_latent_width`) rows
     ``[c_kv | k_rope | 0]`` and ``index[l]`` (pages + 1, page, index_dim)
-    rows ``k_I``.  A layer's arrays are its own, so that a step writes its
-    row into them in place and gathers from them without slicing a pool of
-    all layers first.  Page 0 is the scratch page, as in the transformer's
-    pool."""
+    rows ``k_I``; without the indexer the latent rows alone.  A layer's
+    arrays are its own, so that a step writes its row into them in place and
+    gathers from them without slicing a pool of all layers first.  Page 0 is
+    the scratch page, as in the transformer's pool."""
     c = config
     cdt = jnp.dtype(dtype if dtype is not None else c.dtype)
     lead = (int(num_pages) + 1, int(page_size))
-    return {"latent": [jnp.zeros(lead + (_latent_width(c),), cdt)
-                       for _ in range(c.n_layers)],
-            "index": [jnp.zeros(lead + (c.index_dim,), cdt)
-                      for _ in range(c.n_layers)]}
+    pools = {"latent": [jnp.zeros(lead + (_latent_width(c),), cdt)
+                        for _ in range(c.n_layers)]}
+    if c.indexer:
+        pools["index"] = [jnp.zeros(lead + (c.index_dim,), cdt)
+                          for _ in range(c.n_layers)]
+    return pools
 
 
 def kv_page_bytes(config, page_size):
-    """Bytes one page holds over all layers and both arrays."""
+    """Bytes one page holds over all layers and arrays."""
     c = config
-    return (c.n_layers * int(page_size) * (_latent_width(c) + c.index_dim)
+    index = c.index_dim if c.indexer else 0
+    return (c.n_layers * int(page_size) * (_latent_width(c) + index)
             * jnp.dtype(c.dtype).itemsize)
+
+
+def decode_counters(config):
+    """Names of what the decode program counts for ``config``."""
+    return INDEXED_DECODE_COUNTERS if config.indexer else DENSE_DECODE_COUNTERS
+
+
+def _decode_block_k(config, slots, max_ctx):
+    """Cached rows an online-softmax turn of dense decode, as the predictor
+    asks (the kernel and the blocked form cut it to whole pages)."""
+    return min(DECODE_BLOCK_K, int(max_ctx))
 
 
 # -- pieces ------------------------------------------------------------------
@@ -198,12 +252,14 @@ def _layernorm(x, gamma, beta, eps):
         + beta.astype(jnp.float32)
 
 
-def _rope(x, positions, theta):
+def _rope(x, positions, theta, freq=None):
     """Interleaved rotary over the last axis of ``x`` (..., T, [heads,] n):
-    the pairs ``(x[2i], x[2i+1])`` turn by ``positions * theta^(-2i/n)``.
-    ``positions`` (T,) lines up with the axis before the optional heads."""
+    the pairs ``(x[2i], x[2i+1])`` turn by ``positions * freq[i]``, by default
+    ``theta^(-2i/n)``.  ``positions`` (T,) lines up with the axis before the
+    optional heads."""
     n = x.shape[-1]
-    freq = jnp.exp(jnp.arange(0, n, 2, dtype=jnp.float32) * (-np.log(theta) / n))
+    if freq is None:
+        freq = jnp.exp(jnp.arange(0, n, 2, dtype=jnp.float32) * (-np.log(theta) / n))
     ang = positions.astype(jnp.float32)[:, None] * freq          # (T, n/2)
     if x.ndim == ang.ndim + 1:
         ang = ang[:, None, :]                                    # heads axis
@@ -211,6 +267,45 @@ def _rope(x, positions, theta):
     xf = x.astype(jnp.float32).reshape(x.shape[:-1] + (n // 2, 2))
     a, b = xf[..., 0], xf[..., 1]
     return jnp.stack([a * cos - b * sin, a * sin + b * cos], axis=-1).reshape(x.shape)
+
+
+def _yarn_mscale(factor):
+    """YaRN's attention factor ``0.1 ln(factor) + 1`` (1 unscaled)."""
+    return 0.1 * np.log(factor) + 1.0 if factor > 1 else 1.0
+
+
+def _yarn_freq(c, n):
+    """DeepSeek's YaRN frequencies of the ``n / 2`` rotary pairs, float32:
+    pairs up to the correction range's low end keep ``theta^(-2i/n)``, pairs
+    from its high end on turn ``yarn_factor`` times slower, and a linear
+    ramp blends the two between.  The range's ends are the pairs that turn
+    ``YARN_BETA_FAST`` and ``YARN_BETA_SLOW`` times over ``yarn_original``
+    positions."""
+    theta, factor = float(c.rope_theta), float(c.yarn_factor)
+
+    def pair(turns):
+        return n * np.log(c.yarn_original / (turns * 2 * np.pi)) / (2 * np.log(theta))
+
+    low = max(np.floor(pair(YARN_BETA_FAST)), 0)
+    high = min(np.ceil(pair(YARN_BETA_SLOW)), n - 1)
+    high = high + 0.001 if high == low else high
+    extra = theta ** (-np.arange(0, n, 2, dtype=np.float64) / n)
+    ramp = np.clip((np.arange(n // 2) - low) / (high - low), 0.0, 1.0)
+    return (extra / factor * ramp + extra * (1 - ramp)).astype(np.float32)
+
+
+def _rotary(x, positions, c):
+    """:func:`_rope` with the configuration's frequencies: plain, or YaRN's
+    where ``yarn_factor`` is set."""
+    if not getattr(c, "yarn_factor", 0.0):
+        return _rope(x, positions, c.rope_theta)
+    return _rope(x, positions, c.rope_theta, jnp.asarray(_yarn_freq(c, x.shape[-1])))
+
+
+def _softmax_scale(c):
+    """``(d_nope + d_rope)^-1/2``, times YaRN's attention factor squared
+    where YaRN is on."""
+    return (c.d_nope + c.d_rope) ** -0.5 * _yarn_mscale(getattr(c, "yarn_factor", 0.0)) ** 2
 
 
 def _dot(a, b, spec, cdt):
@@ -225,15 +320,18 @@ def _swiglu(x, gate, up, down, cdt):
     return _dot((jax.nn.silu(g) * u).astype(cdt), down, "tf,fd->td", cdt)
 
 
+# the leaves stacked over all layers, those a configuration has
+_LAYER_LEAVES = ("attn_norm", "ffn_norm", "q_a_weight", "q_a_norm", "q_b_weight",
+                 "kv_a_weight", "kv_a_norm", "kv_b_weight", "o_weight",
+                 "index_q_weight", "index_k_weight", "index_k_norm_gamma",
+                 "index_k_norm_beta", "index_w_weight", "q_weight", "q_norm", "k_norm")
+
+
 def _layer(params, i, config):
     """Layer ``i``'s leaves: attention and indexer from the all-layer
     stacks, the FFN's from the dense or the expert stacks."""
     c = config
-    per_layer = ("attn_norm", "ffn_norm", "q_a_weight", "q_a_norm", "q_b_weight",
-                 "kv_a_weight", "kv_a_norm", "kv_b_weight", "o_weight",
-                 "index_q_weight", "index_k_weight", "index_k_norm_gamma",
-                 "index_k_norm_beta", "index_w_weight")
-    lp = {k: params[k][i] for k in per_layer}
+    lp = {k: params[k][i] for k in _LAYER_LEAVES if k in params}
     if i < c.n_dense_layers:
         group, j = ("dense_gate_weight", "dense_up_weight", "dense_down_weight"), i
     else:
@@ -252,16 +350,25 @@ def _layer(params, i, config):
 def _latent_project(h, positions, lp, c, cdt, kv_scale=None):
     """The low-rank projections of rows ``h`` (T, d) at ``positions`` (T,):
     ``c_q`` (T, q_rank) and the latent row as it is cached
-    (T, :func:`_latent_width`), both in the compute type.  ``kv_scale``
-    multiplies the normed ``c_kv`` (not the rotary key) before the cast."""
+    (T, :func:`_latent_width`), both in the compute type.  A full-rank
+    query's ``c_q`` is ``h`` itself (:func:`_queries` projects it).
+    ``kv_scale`` multiplies the normed ``c_kv`` (not the rotary key) before
+    the cast; ``k_norm``, where the layer has it, norms the rotary key
+    before it is turned."""
     with jax.named_scope("mx.gen.latent_proj"):
-        c_q = _rmsnorm(_dot(h, lp["q_a_weight"], "td,dr->tr", cdt),
-                       lp["q_a_norm"], c.norm_eps).astype(cdt)
+        if "q_a_weight" in lp:
+            c_q = _rmsnorm(_dot(h, lp["q_a_weight"], "td,dr->tr", cdt),
+                           lp["q_a_norm"], c.norm_eps).astype(cdt)
+        else:
+            c_q = h
         kv = _dot(h, lp["kv_a_weight"], "td,dr->tr", cdt)
         c_kv = _rmsnorm(kv[:, :c.kv_rank], lp["kv_a_norm"], c.norm_eps)
         if kv_scale is not None:
             c_kv = c_kv * kv_scale
-        k_rope = _rope(kv[:, c.kv_rank:], positions, c.rope_theta)
+        k_r = kv[:, c.kv_rank:]
+        if "k_norm" in lp:
+            k_r = _rmsnorm(k_r, lp["k_norm"], c.norm_eps)
+        k_rope = _rotary(k_r, positions, c)
         pad = jnp.zeros((h.shape[0], _latent_width(c) - c.kv_rank - c.d_rope),
                         jnp.float32)
         latent = jnp.concatenate([c_kv, k_rope, pad], axis=-1).astype(cdt)
@@ -275,12 +382,12 @@ def _index_project(h, c_q, positions, lp, c, cdt):
     with jax.named_scope("mx.gen.index"):
         r = c.index_rope_dim
         q_i = _dot(c_q, lp["index_q_weight"], "tr,rhe->the", cdt)
-        q_i = jnp.concatenate([_rope(q_i[..., :r], positions, c.rope_theta),
+        q_i = jnp.concatenate([_rotary(q_i[..., :r], positions, c),
                                q_i[..., r:]], axis=-1).astype(cdt)
         k_i = _layernorm(_dot(h, lp["index_k_weight"], "td,de->te", cdt),
                          lp["index_k_norm_gamma"], lp["index_k_norm_beta"],
                          c.index_norm_eps)
-        k_i = jnp.concatenate([_rope(k_i[:, :r], positions, c.rope_theta),
+        k_i = jnp.concatenate([_rotary(k_i[:, :r], positions, c),
                                k_i[:, r:]], axis=-1).astype(cdt)
         w = _dot(h, lp["index_w_weight"], "td,dh->th", cdt) \
             * (c.index_heads ** -0.5 * c.index_dim ** -0.5)
@@ -295,13 +402,17 @@ def _project(h, positions, lp, c, cdt):
 
 
 def _queries(c_q, positions, lp, c, cdt, scale=None):
-    """(T, H, d_nope) and rotated (T, H, d_rope) queries from ``c_q``, both
-    times ``scale`` where one is given."""
+    """(T, H, d_nope) and rotated (T, H, d_rope) queries from ``c_q`` (the
+    rows themselves where the query is full-rank), both times ``scale`` where
+    one is given, each head's normed where the layer has ``q_norm``."""
     with jax.named_scope("mx.gen.latent_proj"):
-        q = _dot(c_q, lp["q_b_weight"], "tr,rhe->the", cdt)
+        w = lp["q_b_weight"] if "q_b_weight" in lp else lp["q_weight"]
+        q = _dot(c_q, w, "tr,rhe->the", cdt)
         if scale is not None:
             q = q * scale
-        q_rope = _rope(q[..., c.d_nope:], positions, c.rope_theta)
+        if "q_norm" in lp:
+            q = _rmsnorm(q, lp["q_norm"], c.norm_eps)
+        q_rope = _rotary(q[..., c.d_nope:], positions, c)
         return q[..., :c.d_nope].astype(cdt), q_rope.astype(cdt)
 
 
@@ -444,6 +555,7 @@ def _row_blocks(fn, rows, block, *args):
 
 
 KEY_CHUNKS = 8       # key chunks a query block may skip when they lie ahead
+KEY_SPAN = 4096      # keys a chunk holds at most: longer prompts take more chunks
 
 
 def _expanded_attention(c_q, positions, length, latent, lp, c, cdt, index=None,
@@ -464,9 +576,11 @@ def _expanded_attention(c_q, positions, length, latent, lp, c, cdt, index=None,
         k_nope = _dot(c_kv, _k_b(lp, c), "sr,rhe->she", cdt).astype(cdt)
         v = _dot(c_kv, _v_b(lp, c), "sr,rhe->she", cdt).astype(cdt)
         k_rope = latent[:, c.kv_rank:c.kv_rank + c.d_rope]
-    scale = (c.d_nope + c.d_rope) ** -0.5
+    scale = _softmax_scale(c)
     key_pos = jnp.arange(T)
     chunks = KEY_CHUNKS if T % KEY_CHUNKS == 0 else 1
+    while T // chunks > KEY_SPAN and T % (2 * chunks) == 0:
+        chunks *= 2
     span = T // chunks
     cuts = [slice(j * span, (j + 1) * span) for j in range(chunks)]
     rows = min(T, 256)
@@ -527,12 +641,79 @@ def _expanded_attention(c_q, positions, length, latent, lp, c, cdt, index=None,
                        positions)
 
 
+def blocked_attention(q, pool, block_tables, lengths, d_value, scale, block_k):
+    """The absorbed decode attention in ``jax.numpy``: queries ``q``
+    (S, H, W) against each slot's rows of ``pool`` (pages + 1, page, W),
+    ``block_k`` rows (whole pages) a turn through the block table with an
+    online softmax; turns past the longest length are not made.  Returns
+    (S, H, d_value) float32: softmax(scale q . rows) rows[:, :d_value] over
+    rows ``< lengths[b]``, zeros for a slot of length 0."""
+    S, H, W = q.shape
+    page = pool.shape[1]
+    per_turn = max(1, int(block_k) // page)
+    span = per_turn * page
+    pad = -block_tables.shape[1] % per_turn
+    table = jnp.pad(block_tables, ((0, 0), (0, pad)))
+    turns = (jnp.max(lengths) + span - 1) // span
+
+    def turn(j, state):
+        top, norm, acc = state
+        ids = lax.dynamic_slice_in_dim(table, j * per_turn, per_turn, axis=1)
+        rows = pool[ids].reshape(S, span, W)
+        s = jnp.einsum("shw,skw->shk", q, rows,
+                       preferred_element_type=jnp.float32) * scale
+        valid = (j * span + jnp.arange(span))[None, :] < lengths[:, None]
+        s = jnp.where(valid[:, None, :], s, -jnp.inf)
+        new = jnp.maximum(top, jnp.max(s, axis=-1))
+        shift = jnp.where(new == -jnp.inf, 0.0, new)     # no valid row met yet
+        keep = jnp.exp(top - shift)
+        p = jnp.exp(s - shift[..., None])
+        pv = jnp.einsum("shk,skv->shv", p.astype(rows.dtype), rows[..., :d_value],
+                        preferred_element_type=jnp.float32)
+        return new, norm * keep + jnp.sum(p, axis=-1), acc * keep[..., None] + pv
+
+    _top, norm, acc = lax.fori_loop(
+        0, turns, turn, (jnp.full((S, H), -jnp.inf, jnp.float32),
+                         jnp.zeros((S, H), jnp.float32),
+                         jnp.zeros((S, H, d_value), jnp.float32)))
+    return acc / jnp.maximum(norm, 1e-30)[..., None]
+
+
+def paged_attention(pool, q_nope, q_rope, lengths, block_tables, lp, c, cdt, block_k):
+    """One layer's dense decode attention in the absorbed form, every cached
+    row of each slot up to ``lengths[b]``: the query ``[q_nope W_kb^T | q_rope
+    | 0]`` of every head (S, H, :func:`_latent_width`) against the rows of
+    ``pool`` where they lie, ``block_k`` rows a turn with an online softmax
+    (scale :func:`_softmax_scale`), then ``P c_kv`` up-projected by ``W_vb`` and through the output
+    projection: (S, d) float32.  On a TPU the Pallas kernel
+    ``kernels/mla_paged_decode.py`` reads the pages in place; elsewhere
+    :func:`blocked_attention` gathers a block of pages a turn."""
+    S = q_nope.shape[0]
+    scale = _softmax_scale(c)
+    with jax.named_scope("mx.gen.attn"):
+        q_lat = _dot(q_nope, _k_b(lp, c), "she,rhe->shr", cdt).astype(cdt)
+        pad = jnp.zeros((S, c.n_heads, _latent_width(c) - c.kv_rank - c.d_rope), cdt)
+        q = jnp.concatenate([q_lat, q_rope, pad], axis=-1)       # (S, H, W)
+        if kernel_platform() == "tpu":
+            from ..kernels.mla_paged_decode import mla_paged_decode_attention
+
+            o_lat = mla_paged_decode_attention(q, pool, block_tables, lengths,
+                                               d_value=c.kv_rank, scale=scale,
+                                               block_k=block_k)
+        else:
+            o_lat = blocked_attention(q, pool, block_tables, lengths, c.kv_rank,
+                                      scale, block_k).astype(cdt)
+        o = _dot(o_lat, _v_b(lp, c), "shr,rhe->she", cdt)
+    return _output(o, lp, cdt)
+
+
 # -- programs ----------------------------------------------------------------
 def _sequence_layers(params, x, config, on_layer, length=None):
     """All layers over one whole sequence ``x`` (T, d) at positions
     0..T-1, expanded attention; ``on_layer(i, latent, k_i)`` sees what a
-    cache would hold.  Rows from ``length`` on (a prompt's padded tail) are
-    carried along, not computed."""
+    cache would hold (``k_i`` None without the indexer).  Rows from
+    ``length`` on (a prompt's padded tail) are carried along, not
+    computed."""
     c = config
     cdt = jnp.dtype(c.dtype)
     T = x.shape[0]
@@ -549,10 +730,14 @@ def _sequence_layers(params, x, config, on_layer, length=None):
     for i in range(c.n_layers):
         lp = _layer(params, i, c)
         h = _rmsnorm(x, lp["attn_norm"], c.norm_eps).astype(cdt)
-        c_q, latent, q_i, k_i, w = _project(h, positions, lp, c, cdt)
+        if c.indexer:
+            c_q, latent, q_i, k_i, w = _project(h, positions, lp, c, cdt)
+            index = (q_i, w, k_i)
+        else:
+            c_q, latent = _latent_project(h, positions, lp, c, cdt)
+            k_i = index = None
         on_layer(i, latent, k_i)
-        o = _expanded_attention(c_q, positions, length, latent, lp, c, cdt,
-                                index=(q_i, w, k_i))
+        o = _expanded_attention(c_q, positions, length, latent, lp, c, cdt, index=index)
         x = x + o.astype(cdt)
         x = _row_blocks(functools.partial(ffn, lp), T, ffn_rows, x, real)
     return x
@@ -577,8 +762,8 @@ def make_prefill_fn(config, page_size, mesh=None):
     pages (S_pad // page_size,) int32) -> (cache', logits (vocab,) float32).
 
     One whole prompt, padded to its bucket, through the expanded form with
-    the selection as a mask; every layer's latent rows and index keys are
-    written to the pages named (the padded tail's to the scratch page or to
+    the selection as a mask (where there is an indexer); every layer's latent
+    rows and index keys are written to the pages named (the padded tail's to the scratch page or to
     slots a later token overwrites before they are read, as the
     transformer's prefill leaves them).  Long prompts fit because the two
     things that grow with rows x keys, the index scores and the attention
@@ -601,6 +786,8 @@ def make_prefill_fn(config, page_size, mesh=None):
         def write(i, latent, k_i):
             with jax.named_scope("mx.gen.pool_write"):
                 for name, rows in (("latent", latent), ("index", k_i)):
+                    if name not in pools:
+                        continue
                     paged = rows.reshape(n_pages, page_size, -1)
                     pools[name][i] = pools[name][i].at[pages].set(
                         paged.astype(pools[name][i].dtype))
@@ -616,20 +803,24 @@ def make_decode_fn(config, slots, max_pages_per_slot, page_size,
                    block_k=None, mesh=None):
     """fn(params, cache, tokens (S,), positions (S,), block_tables
     (S, max_pages_per_slot), active (S,)) -> (cache', (logits (S, vocab)
-    float32, counters (len(DECODE_COUNTERS),) int32)).
+    float32, counters (len(decode_counters(config)),) int32)).
 
     One token a slot: its latent row and index key are written in place at
     ``block_tables[b, positions[b] // page_size]``; the slot's cached index
     keys are scored, the ``index_topk`` best causal positions kept
     (``lax.top_k``), their latent rows gathered through the block table, and
-    attended in the absorbed form.  Inactive slots write to the scratch
+    attended in the absorbed form.  Without the indexer every cached row of
+    the slot is attended through :func:`paged_attention`, ``block_k`` rows a
+    turn (default ``DECODE_BLOCK_K``).  Inactive slots write to the scratch
     page, attend nothing that counts and get zero logits."""
     c = config
     cdt = jnp.dtype(c.dtype)
     page_size = int(page_size)
     max_ctx = int(max_pages_per_slot) * page_size
     topk = min(c.index_topk, max_ctx)
-    scale = (c.d_nope + c.d_rope) ** -0.5
+    block_k = int(block_k or DECODE_BLOCK_K)
+    scale = _softmax_scale(c)
+    names = decode_counters(c)
     if mesh is not None:
         raise NotImplementedError("mla_moe: no sharded bind; one chip holds "
                                   "its share of the experts")
@@ -674,25 +865,37 @@ def make_decode_fn(config, slots, max_pages_per_slot, page_size,
         offset = positions % page_size
         lengths = jnp.where(active, positions + 1, 0)
         pools = {name: list(layers) for name, layers in cache.items()}
-        total = {k: jnp.int32(0) for k in DECODE_COUNTERS}
+        total = {k: jnp.int32(0) for k in names}
         for i in range(c.n_layers):
             lp = _layer(params, i, c)
             h = _rmsnorm(x, lp["attn_norm"], c.norm_eps).astype(cdt)
-            c_q, latent, q_i, k_i, w = _project(h, positions, lp, c, cdt)
-            with jax.named_scope("mx.gen.pool_write"):
-                pools["latent"][i] = pools["latent"][i].at[page, offset].set(
-                    latent.astype(pools["latent"][i].dtype))
-                pools["index"][i] = pools["index"][i].at[page, offset].set(
-                    k_i.astype(pools["index"][i].dtype))
-            o, selected = attend(pools, i, c_q, q_i, w, positions, lengths,
-                                 block_tables, lp)
+            if c.indexer:
+                c_q, latent, q_i, k_i, w = _project(h, positions, lp, c, cdt)
+                with jax.named_scope("mx.gen.pool_write"):
+                    pools["latent"][i] = pools["latent"][i].at[page, offset].set(
+                        latent.astype(pools["latent"][i].dtype))
+                    pools["index"][i] = pools["index"][i].at[page, offset].set(
+                        k_i.astype(pools["index"][i].dtype))
+                o, selected = attend(pools, i, c_q, q_i, w, positions, lengths,
+                                     block_tables, lp)
+            else:
+                c_q, latent = _latent_project(h, positions, lp, c, cdt)
+                with jax.named_scope("mx.gen.pool_write"):
+                    pools["latent"][i] = pools["latent"][i].at[page, offset].set(
+                        latent.astype(pools["latent"][i].dtype))
+                q_nope, q_rope = _queries(c_q, positions, lp, c, cdt)
+                o = paged_attention(pools["latent"][i], q_nope, q_rope, lengths,
+                                    block_tables, lp, c, cdt, block_k)
             x, counts = _ffn(x + o.astype(cdt), lp, c, cdt, active)
-            counts["dsa_keys_scanned"] = jnp.sum(lengths)
-            counts["dsa_keys_selected"] = selected
+            if c.indexer:
+                counts["dsa_keys_scanned"] = jnp.sum(lengths)
+                counts["dsa_keys_selected"] = selected
+            else:
+                counts["attn_rows_read"] = jnp.sum(lengths)
             for k, v in counts.items():
                 total[k] = total[k] + v.astype(jnp.int32)
         logits = jnp.where(active[:, None], _head(x, params, c, cdt), 0.0)
-        counters = jnp.stack([total[k] for k in DECODE_COUNTERS])
+        counters = jnp.stack([total[k] for k in names])
         return pools, (logits, counters)
 
     return decode

@@ -35,9 +35,10 @@ are float32.
   expanded form, decode the absorbed one: the query ``[q_nope W_kb^T |
   q_rope | 0]`` of every head against the cached rows, in key blocks with
   an online softmax, blocks past the longest slot skipped and nothing of
-  (slots x max_ctx x row) materialised: on a TPU the Pallas kernel
-  ``kernels/mla_paged_decode.py`` reads the pages where they lie, elsewhere
-  :func:`blocked_attention` gathers a block of pages a turn.
+  (slots x max_ctx x row) materialised (``mla_moe.paged_attention``: on a
+  TPU the Pallas kernel ``kernels/mla_paged_decode.py`` reads the pages
+  where they lie, elsewhere ``blocked_attention`` gathers a block of pages
+  a turn).
 - **MoE**: ``p = softmax(h1 W_r)`` in float32 over ``n_experts +
   n_zero_experts`` outputs; the ``experts_per_token`` largest of ``p + b`` are
   chosen (``b`` moves the choice only); ``g_i = route_scale p_i``, not
@@ -49,7 +50,7 @@ are float32.
 
 Entry points are those ``GenerativePredictor`` asks a model module for
 (``init_kv_cache``, ``kv_page_bytes``, ``make_prefill_fn``,
-``make_decode_fn``, ``DECODE_COUNTERS``, ``_decode_block_k``);
+``make_decode_fn``, ``decode_counters``, ``_decode_block_k``);
 ``make_forward_fn`` is the cache-free one-shot forward.
 
 Assumed where the published configuration gives switches and numbers only
@@ -74,19 +75,24 @@ import jax.numpy as jnp
 import numpy as np
 from jax import lax
 
-from ..context import kernel_platform
 from . import mla_moe as mm
-from .mla_moe import _dot, _rmsnorm, _swiglu
+from .mla_moe import DECODE_BLOCK_K, _rmsnorm, _swiglu
+from .mla_moe import _decode_block_k  # noqa: F401  (the predictor asks for it)
 
 __all__ = ["ShortcutMoEConfig", "init_params", "init_kv_cache", "kv_page_bytes",
-           "make_prefill_fn", "make_decode_fn", "make_forward_fn", "DECODE_COUNTERS"]
+           "make_prefill_fn", "make_decode_fn", "make_forward_fn", "decode_counters",
+           "DECODE_COUNTERS"]
 
 # what one decode step counts on the device, summed over its layers and
 # returned beside the logits (``profiler.generate_record`` names)
 DECODE_COUNTERS = ("moe_pairs_held", "moe_tokens", "moe_experts_touched",
                    "moe_pairs_at_max_load", "moe_pairs_zero", "attn_rows_read")
 
-DECODE_BLOCK_K = 512     # cached rows an online-softmax turn of decode
+
+def decode_counters(config):
+    """Names of what the decode program counts, the same for every
+    configuration."""
+    return DECODE_COUNTERS
 
 
 @dataclasses.dataclass
@@ -217,12 +223,6 @@ def kv_page_bytes(config, page_size):
             * jnp.dtype(c.dtype).itemsize)
 
 
-def _decode_block_k(config, slots, max_ctx):
-    """Cached rows an online-softmax turn of decode, as the predictor asks
-    (the kernel and the blocked form cut it to whole pages)."""
-    return min(DECODE_BLOCK_K, int(max_ctx))
-
-
 # -- pieces ------------------------------------------------------------------
 def _sublayer(params, c, l, a):
     """The leaves of attention ``a`` and dense FFN ``a`` of double layer ``l``."""
@@ -295,44 +295,6 @@ def _attention_half(x, attend, a, sp, c, cdt):
     """``x + MLA[a](N(x))`` with ``attend(a, h, sp)`` prefill's or decode's."""
     h = _rmsnorm(x, sp["attn_norm"], c.norm_eps).astype(cdt)
     return x + attend(a, h, sp).astype(cdt)
-
-
-def blocked_attention(q, pool, block_tables, lengths, d_value, scale, block_k):
-    """The absorbed decode attention in ``jax.numpy``: queries ``q``
-    (S, H, W) against each slot's rows of ``pool`` (pages + 1, page, W),
-    ``block_k`` rows (whole pages) a turn through the block table with an
-    online softmax; turns past the longest length are not made.  Returns
-    (S, H, d_value) float32: softmax(scale q . rows) rows[:, :d_value] over
-    rows ``< lengths[b]``, zeros for a slot of length 0."""
-    S, H, W = q.shape
-    page = pool.shape[1]
-    per_turn = max(1, int(block_k) // page)
-    span = per_turn * page
-    pad = -block_tables.shape[1] % per_turn
-    table = jnp.pad(block_tables, ((0, 0), (0, pad)))
-    turns = (jnp.max(lengths) + span - 1) // span
-
-    def turn(j, state):
-        top, norm, acc = state
-        ids = lax.dynamic_slice_in_dim(table, j * per_turn, per_turn, axis=1)
-        rows = pool[ids].reshape(S, span, W)
-        s = jnp.einsum("shw,skw->shk", q, rows,
-                       preferred_element_type=jnp.float32) * scale
-        valid = (j * span + jnp.arange(span))[None, :] < lengths[:, None]
-        s = jnp.where(valid[:, None, :], s, -jnp.inf)
-        new = jnp.maximum(top, jnp.max(s, axis=-1))
-        shift = jnp.where(new == -jnp.inf, 0.0, new)     # no valid row met yet
-        keep = jnp.exp(top - shift)
-        p = jnp.exp(s - shift[..., None])
-        pv = jnp.einsum("shk,skv->shv", p.astype(rows.dtype), rows[..., :d_value],
-                        preferred_element_type=jnp.float32)
-        return new, norm * keep + jnp.sum(p, axis=-1), acc * keep[..., None] + pv
-
-    _top, norm, acc = lax.fori_loop(
-        0, turns, turn, (jnp.full((S, H), -jnp.inf, jnp.float32),
-                         jnp.zeros((S, H), jnp.float32),
-                         jnp.zeros((S, H, d_value), jnp.float32)))
-    return acc / jnp.maximum(norm, 1e-30)[..., None]
 
 
 # -- programs ----------------------------------------------------------------
@@ -438,31 +400,14 @@ def make_decode_fn(config, slots, max_pages_per_slot, page_size,
     cdt = jnp.dtype(c.dtype)
     page_size = int(page_size)
     block_k = int(block_k or DECODE_BLOCK_K)
-    scale = (c.d_nope + c.d_rope) ** -0.5
-    width = mm._latent_width(c)
     if mesh is not None:
         raise NotImplementedError("scmoe: no sharded bind; one chip holds its "
                                   "share of the experts")
-    if kernel_platform() == "tpu":
-        from ..kernels.mla_paged_decode import mla_paged_decode_attention as over_pages
-    else:
-        over_pages = None
 
     def attention(pool, c_q, positions, lengths, block_tables, sp):
-        S = c_q.shape[0]
         q_nope, q_rope = mm._queries(c_q, positions, sp, c, cdt, c.q_scale)
-        with jax.named_scope("mx.gen.attn"):
-            q_lat = _dot(q_nope, sp["k_b_weight"], "she,rhe->shr", cdt).astype(cdt)
-            pad = jnp.zeros((S, c.n_heads, width - c.kv_rank - c.d_rope), cdt)
-            q = jnp.concatenate([q_lat, q_rope, pad], axis=-1)       # (S, H, W)
-            if over_pages is not None:
-                o_lat = over_pages(q, pool, block_tables, lengths, d_value=c.kv_rank,
-                                   scale=scale, block_k=block_k)
-            else:
-                o_lat = blocked_attention(q, pool, block_tables, lengths, c.kv_rank,
-                                          scale, block_k).astype(cdt)
-            o = _dot(o_lat, sp["v_b_weight"], "shr,rhe->she", cdt)
-        return mm._output(o, sp, cdt)
+        return mm.paged_attention(pool, q_nope, q_rope, lengths, block_tables, sp, c,
+                                  cdt, block_k)
 
     def decode(params, cache, tokens, positions, block_tables, active):
         emb = params["embed_weight"]

@@ -1025,3 +1025,8 @@ def kv_page_bytes(config, page_size):
     c = config
     return (c.n_layers * 2 * int(page_size) * c.n_heads
             * (c.d_model // c.n_heads) * jnp.dtype(c.dtype).itemsize)
+
+
+def decode_counters(config):
+    """Names of what the decode program counts on the device: none."""
+    return ()

@@ -780,7 +780,7 @@ _GEN_ZERO = {
     # verify rounds run
     "draft_proposed": 0, "draft_accepted": 0, "spec_rounds": 0,
     # counted on the device by a decode program that routes tokens to the
-    # experts this chip holds and selects keys (``DECODE_COUNTERS`` of the
+    # experts this chip holds and selects keys (``decode_counters`` of the
     # model module), summed over the step's layers: token-expert pairs that
     # fell on held experts, token-layers routed, held experts with at least
     # one pair, the pairs there would be were every held expert as full as

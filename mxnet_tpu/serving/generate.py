@@ -368,9 +368,9 @@ def _model_module(config_):
     module offers ``init_kv_cache`` (any pytree of page pools under one
     block table), ``kv_page_bytes``, ``make_prefill_fn`` and
     ``make_decode_fn``; optionally ``make_extend_fn`` (prefix tails,
-    speculation), ``_decode_block_k`` and ``DECODE_COUNTERS``, the names
-    of what its decode program counts on the device and returns beside the
-    logits."""
+    speculation) and ``_decode_block_k``; and ``decode_counters(config)``,
+    the names of what its decode program counts on the device and returns
+    beside the logits (none for the transformer)."""
     name = getattr(config_, "module", None)
     if name is None:
         from ..models import transformer
@@ -389,7 +389,7 @@ def _decode_program(model, config_, slots, max_pages_per_slot, page_size,
     fn(params, cache, last (slots + n,) int32, host_ids (slots,) int32,
     from_host (slots,) bool, positions, block_tables, active) →
     (cache', (logits (slots, V), chosen (slots + n,) int32)), n the length
-    of the module's ``DECODE_COUNTERS``.  A slot's token is ``host_ids`` where
+    of the module's ``decode_counters``.  A slot's token is ``host_ids`` where
     ``from_host`` (its prefill chose it, on the host) and the id the step
     before chose, ``last[:slots]``, otherwise: a step can be dispatched
     before the host has read the one before.  ``chosen`` holds
@@ -403,7 +403,7 @@ def _decode_program(model, config_, slots, max_pages_per_slot, page_size,
 
     step = model.make_decode_fn(config_, slots, max_pages_per_slot, page_size,
                                 block_k=block_k, mesh=mesh)
-    counted = bool(getattr(model, "DECODE_COUNTERS", ()))
+    counted = bool(model.decode_counters(config_))
 
     def decode(params, cache, last, host_ids, from_host, positions,
                block_tables, active):
@@ -569,7 +569,7 @@ class GenerativePredictor:
         self.block_k = int(block_k)
         # what the decode program counts on the device, read with the ids
         # it chose: the names, and the last step ``decode`` read
-        self._counter_names = tuple(getattr(tfm, "DECODE_COUNTERS", ()))
+        self._counter_names = tuple(tfm.decode_counters(c))
         self.step_counters = {}
         # the newest decode step's ``chosen`` (ids, then counters), on the
         # device: the next step takes its tokens from it.  Placed as the

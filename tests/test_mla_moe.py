@@ -86,7 +86,7 @@ def _serve_by_hand(cfg, params, tok, n_prompt, page=4, bucket=32):
             table, np.array([False, True]))
         assert not np.asarray(logits)[0].any()          # the idle slot
         out.append(np.asarray(logits)[1])
-        counts.append(dict(zip(mm.DECODE_COUNTERS, np.asarray(count).tolist())))
+        counts.append(dict(zip(mm.decode_counters(cfg), np.asarray(count).tolist())))
     return np.stack(out), counts
 
 

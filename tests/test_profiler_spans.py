@@ -535,10 +535,11 @@ def test_double_block_counters_ride_generate_stats():
 def test_device_counters_of_a_decode_step_ride_generate_stats():
     from mxnet_tpu.models import mla_moe
 
-    profiler.generate_record(**{k: 2 for k in mla_moe.DECODE_COUNTERS})
+    names = mla_moe.decode_counters(mla_moe.LatentMoEConfig())
+    profiler.generate_record(**{k: 2 for k in names})
     profiler.generate_record(moe_pairs_held=6, moe_pairs_at_max_load=10)
     st = profiler.generate_stats(reset=True)
-    assert all(st[k] >= 2 for k in mla_moe.DECODE_COUNTERS)
+    assert all(st[k] >= 2 for k in names)
     # 8 pairs on held experts; 12 were every one as full as the fullest
     assert st["moe_expert_load_max_over_mean"] == pytest.approx(12 / 8)
     profiler.generate_record(decode_steps=1)

@@ -138,7 +138,7 @@ def test_decode_kernel_interpreted_matches_the_blocked_form():
                         .astype(np.int32))
     lengths = jnp.asarray([0, 1, 17, 40], jnp.int32)
     for block_k in (4, 16, 64):
-        want = np.asarray(sm.blocked_attention(q, pool, table, lengths, 64, 0.3, block_k))
+        want = np.asarray(mm.blocked_attention(q, pool, table, lengths, 64, 0.3, block_k))
         got = np.asarray(mla_paged_decode_attention(
             q, pool, table, lengths, d_value=64, scale=0.3, block_k=block_k,
             interpret=True))
@@ -301,6 +301,54 @@ def test_latent_moe_programs_lower_as_before(program):
         with jax.default_matmul_precision(None):
             _DIGESTS.update(latent_moe_digests())
     assert _DIGESTS[program] == LATENT_MOE_PROGRAMS[program]
+
+
+# sha256 of the StableHLO text of the tiny prefill and decode of this module
+# (``models/scmoe.py``), taken at the commit before its dense decode attention
+# moved into ``models/mla_moe.py`` (2f71e1e): the move changed no operation.
+#   python -c "import tests.test_scmoe as t; print(t.shortcut_moe_digests())"
+SHORTCUT_MOE_PROGRAMS = {
+    "prefill": "ddc79fd1adb6031f89d32aee969071bfd3ae28c94584a607cff7a75efc54cad5",
+    "decode": "9f21ea5f4b5d78e72a1f1a603768844b79b43315a7599cb3d15a399e571c1175",
+}
+
+
+def shortcut_moe_digests():
+    cfg = sm.ShortcutMoEConfig(**dict(TINY, dtype="bfloat16"))
+    params = {k: jax.ShapeDtypeStruct(s, jnp.bfloat16)
+              for k, (s, _kind) in sm.param_shapes(cfg).items()}
+    cache = jax.eval_shape(lambda: sm.init_kv_cache(cfg, 20, 4))
+    i32 = lambda *s: jax.ShapeDtypeStruct(s, jnp.int32)  # noqa: E731
+    lowered = {
+        "prefill": jax.jit(sm.make_prefill_fn(cfg, 4)).lower(
+            params, cache, i32(1, 32), i32(), i32(8)),
+        "decode": jax.jit(sm.make_decode_fn(cfg, 2, 16, 4, block_k=8)).lower(
+            params, cache, i32(2), i32(2), i32(2, 16),
+            jax.ShapeDtypeStruct((2,), jnp.bool_)),
+    }
+    return {k: hashlib.sha256(v.as_text().encode()).hexdigest()
+            for k, v in lowered.items()}
+
+
+@pytest.mark.parametrize("program", sorted(SHORTCUT_MOE_PROGRAMS))
+def test_shortcut_moe_programs_lower_as_before(program):
+    if "sc_" + program not in _DIGESTS:
+        with jax.default_matmul_precision(None):
+            _DIGESTS.update({"sc_" + k: v for k, v in shortcut_moe_digests().items()})
+    assert _DIGESTS["sc_" + program] == SHORTCUT_MOE_PROGRAMS[program]
+
+
+def test_the_dense_decode_attention_is_mla_moe_s_own():
+    """One absorbed decode attention over the paged cache serves both latent
+    modules: this one calls ``mla_moe.paged_attention`` and keeps no copy of
+    it, of the blocked form or of the choice of the kernel."""
+    import inspect
+
+    src = inspect.getsource(sm)
+    assert "mm.paged_attention(" in src and not hasattr(sm, "blocked_attention")
+    for copy in ("def blocked_attention", "mla_paged_decode_attention", "kernel_platform",
+                 '"shr,rhe->she"'):
+        assert copy not in src, copy
 
 
 def test_import_mxnet_tpu_loads_none_of_it():

@@ -11,6 +11,7 @@ import math
 import re
 import sys
 
+import numpy as np
 import pytest
 
 import jax
@@ -167,7 +168,7 @@ def test_longcat_decode_reads_its_eight_latent_pools_in_place(one_chip, monkeypa
     import mxnet_tpu.kernels  # noqa: F401  (loads kernels.flash_attention)
     from mxnet_tpu.models import scmoe
 
-    for name in ("mxnet_tpu.models.scmoe", "mxnet_tpu.kernels.flash_attention"):
+    for name in ("mxnet_tpu.models.mla_moe", "mxnet_tpu.kernels.flash_attention"):
         monkeypatch.setattr(sys.modules[name], "kernel_platform", lambda: "tpu")
     cfg = scmoe.ShortcutMoEConfig()
     slots, page, per_slot = 16, 16, 15104 // 16
@@ -202,6 +203,70 @@ def test_longcat_decode_reads_its_eight_latent_pools_in_place(one_chip, monkeypa
     # copy of a dense FFN's matrix (151 MB)
     assert mem.alias_size_in_bytes >= pool_bytes
     assert mem.temp_size_in_bytes < 144 * 2 ** 20
+    shape = r"bf16\[%s\]" % ",".join(map(str, pools[0].shape))
+    assert re.search(shape + r"\{2,1,0:T\(8,128\)\(2,1\)\} parameter\(", text)
+    assert not re.search(r"= %s\S* (copy|pad|slice|dynamic-slice|transpose)\("
+                         % shape, text)
+
+
+def test_sarvam_decode_reads_its_five_latent_pools_in_place(one_chip, monkeypatch):
+    # benchmark/configs/sarvam-105b-ep16.json and
+    # benchmark/traffic/decode-pool-64k.json: the dense latent decode step of
+    # ``mla_moe`` at the cell's size, 1.85 B bfloat16 parameters as shapes, 64-row
+    # pages, a block table of up to 1088 pages a slot (a scalar-prefetch
+    # operand of the kernel) and the pool the runner sizes in bytes
+    import json
+    import os
+
+    import mxnet_tpu.kernels  # noqa: F401  (loads kernels.flash_attention)
+    from mxnet_tpu.models import mla_moe
+
+    from benchmark import traffic_gen
+    from benchmark.runners.serve_decode_pool_sarvam import pool_pages
+
+    for name in ("mxnet_tpu.models.mla_moe", "mxnet_tpu.kernels.flash_attention"):
+        monkeypatch.setattr(sys.modules[name], "kernel_platform", lambda: "tpu")
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    with open(os.path.join(root, "benchmark", "configs", "sarvam-105b-ep16.json")) as f:
+        cfg = mla_moe.LatentMoEConfig(**json.load(f)["program"])
+    with open(os.path.join(root, "benchmark", "traffic", "decode-pool-64k.json")) as f:
+        mix = json.load(f)
+    slots, page = mix["slots"], mix["page_size"]
+    per_slot = mix["max_ctx"] // page
+    prompts = traffic_gen._lognormal_quantiles(
+        mix["prompt_tokens"], (np.arange(slots) + 0.5) / slots)
+    pages = pool_pages(prompts, mix["answer_tokens"], page, slots, mix["pool_margin"])
+    assert pages < 0.65 * slots * per_slot
+
+    def described(x):
+        return jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=one_chip)
+
+    params = {k: jax.ShapeDtypeStruct(s, jnp.bfloat16, sharding=one_chip)
+              for k, (s, _kind) in mla_moe.param_shapes(cfg).items()}
+    cache = jax.tree.map(described, jax.eval_shape(
+        lambda: mla_moe.init_kv_cache(cfg, pages, page)))
+    pools = cache["latent"]
+    assert list(cache) == ["latent"] and len(pools) == 5
+    pool_bytes = sum(2 * math.prod(p.shape) for p in pools)
+    assert pool_bytes == mla_moe.kv_page_bytes(cfg, page) * (pages + 1)
+    fn = jax.jit(mla_moe.make_decode_fn(
+        cfg, slots, per_slot, page,
+        block_k=mla_moe._decode_block_k(cfg, slots, per_slot * page)), donate_argnums=(1,))
+    compiled = fn.lower(
+        params, cache,
+        jax.ShapeDtypeStruct((slots,), jnp.int32, sharding=one_chip),
+        jax.ShapeDtypeStruct((slots,), jnp.int32, sharding=one_chip),
+        jax.ShapeDtypeStruct((slots, per_slot), jnp.int32, sharding=one_chip),
+        jax.ShapeDtypeStruct((slots,), jnp.bool_, sharding=one_chip)).compile()
+    mem = compiled.memory_analysis()
+    text = compiled.as_text()
+    # one Mosaic call a layer, each over its pool where it lies
+    assert text.count('custom_call_target="tpu_custom_call"') == 5
+    assert "mx_mla_paged_decode" in text
+    # the donated pools come back as the outputs; the temporaries hold no
+    # second pool and no gathered rows (16 x 68608 x 640 x 2 B = 1.4 GB)
+    assert mem.alias_size_in_bytes >= pool_bytes
+    assert mem.temp_size_in_bytes < 96 * 2 ** 20
     shape = r"bf16\[%s\]" % ",".join(map(str, pools[0].shape))
     assert re.search(shape + r"\{2,1,0:T\(8,128\)\(2,1\)\} parameter\(", text)
     assert not re.search(r"= %s\S* (copy|pad|slice|dynamic-slice|transpose)\("
@@ -270,9 +335,9 @@ def _decode_arguments(cell, sharding=None):
 
 def _as_on_tpu(monkeypatch):
     import mxnet_tpu.kernels  # noqa: F401  (loads kernels.flash_attention)
-    from mxnet_tpu.models import scmoe  # noqa: F401
+    from mxnet_tpu.models import mla_moe  # noqa: F401
 
-    for name in ("mxnet_tpu.models.transformer", "mxnet_tpu.models.scmoe",
+    for name in ("mxnet_tpu.models.transformer", "mxnet_tpu.models.mla_moe",
                  "mxnet_tpu.kernels.flash_attention"):
         monkeypatch.setattr(sys.modules[name], "kernel_platform", lambda: "tpu")
 
@@ -315,7 +380,7 @@ def test_served_decode_chooses_the_token_on_the_device(cell, one_chip,
     _as_on_tpu(monkeypatch)
     mod, cfg, params, cache, (ids, tables, mask), (
         slots, per_slot, page, block_k) = _decode_arguments(cell, one_chip)
-    counters = len(getattr(mod, "DECODE_COUNTERS", ()))
+    counters = len(mod.decode_counters(cfg))
     last = jax.ShapeDtypeStruct((slots + counters,), jnp.int32,
                                 sharding=one_chip)
     fn = jax.jit(generate._decode_program(mod, cfg, slots, per_slot, page,
