@@ -292,6 +292,18 @@ def _ffn(x, lp, c, axes, cdt):
         return x + f
 
 
+# The stacked matrices ``_block`` and ``_ffn`` multiply in the compute dtype
+# (their ``.astype(cdt)``). ``_forward_local`` casts these before the layer
+# scan and passes the casts as its ``xs``: cast inside the body, each layer's
+# cast is saved again into a stack for the backward, and the weight gradients
+# leave the loop as float32 stacks that are zeroed first. Cast outside, the
+# backward reads the forward's stacks and writes compute-dtype gradients that
+# the optimizer widens. The LayerNorm parameters and ``moe_gate_weight`` (the
+# router runs in float32) stay float32.
+_SCAN_CAST = frozenset(("attn_qkv_weight", "attn_out_weight",
+                        "ffn_up_weight", "ffn_down_weight"))
+
+
 def _forward_local(params, tokens, c, axes):
     """Local-shard forward → logits (B_loc, S_loc, V). tokens int32."""
     cdt = jnp.dtype(c.dtype)
@@ -322,7 +334,8 @@ def _forward_local(params, tokens, c, axes):
 
     if c.remat:
         layer = jax.checkpoint(layer)
-    stacked = {k: v for k, v in params.items()
+    stacked = {k: v.astype(cdt) if k in _SCAN_CAST else v
+               for k, v in params.items()
                if k not in ("embed_weight", "pos_embed_weight",
                             "final_ln_gamma", "final_ln_beta")}
     x, _ = lax.scan(layer, x, stacked)

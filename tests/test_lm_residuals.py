@@ -5,6 +5,11 @@ input as given with a float32 mean and rstd a row, the ReLU its output. The
 forwards compute what the plain formulas compute, bit for bit, so the
 serving programs (which never differentiate) are unchanged; the gradients
 are the plain formulas' autodiff up to float32 summation order.
+
+The scan reads its weight matrices cast to the compute dtype before it
+(``_SCAN_CAST``), so it saves no second copy of them and gives their
+gradients in that dtype; the values are those of a cast in its body, bit for
+bit.
 """
 import sys
 
@@ -174,6 +179,108 @@ def test_train_step_matches_autodiff_of_the_plain_formulas(plain, dtype, tol, kw
         assert gap <= tol * max(np.linalg.norm(ref_grads[k]), 1e-12), k
 
 
+# -- the weights the layer scan reads -------------------------------------------
+# ``_forward_local`` casts the matrices of ``_SCAN_CAST`` before the scan. With
+# the set empty the body casts them, as it did before: the forward scan then
+# stacks each layer's cast again for the backward, and the weight gradients
+# come out of the backward scan in float32.
+_KINDS = pytest.mark.parametrize("kw", [{}, {"remat": True}, {"n_experts": 2}],
+                                 ids=["dense", "remat", "moe"])
+
+
+def _small(**kw):
+    return tfm.TransformerConfig(vocab=64, d_model=32, n_heads=4, n_layers=2,
+                                 d_ff=64, max_len=32, dtype="bfloat16", **kw)
+
+
+def _layer_scans(cfg):
+    """(forward, backward) layer scans in the jaxpr of the loss's gradient,
+    and the parameters' shapes."""
+    mesh = make_mesh({"dp": 1}, devices=jax.devices()[:1])
+    loss_fn, _ = tfm.make_loss_fn(cfg, mesh)
+    params = jax.eval_shape(lambda: tfm.init_params(cfg))
+    tokens = jax.ShapeDtypeStruct((4, 17), jnp.int32)
+    jaxpr = jax.make_jaxpr(jax.grad(loss_fn))(params, tokens).jaxpr
+
+    def scans(j):
+        for eqn in j.eqns:
+            if eqn.primitive.name == "scan":
+                yield eqn
+            for sub in tfm._sub_jaxprs(eqn):
+                yield from scans(sub)
+
+    layer = [e for e in scans(jaxpr) if e.params["length"] == cfg.n_layers]
+    fwd = [e for e in layer if not e.params["reverse"]]
+    bwd = [e for e in layer if e.params["reverse"]]
+    assert len(fwd) == len(bwd) == 1
+    return fwd[0], bwd[0], params
+
+
+# what the block reads in float32: the LayerNorms and the router's gate
+_READ_IN_FLOAT32 = {"ln1_gamma", "ln1_beta", "ln2_gamma", "ln2_beta", "moe_gate_weight"}
+
+
+@_KINDS
+def test_layer_scan_reads_cast_weights_and_gives_their_gradients_in_cdt(monkeypatch, kw):
+    cfg = _small(**kw)
+    cdt, f32 = jnp.dtype(cfg.dtype), jnp.dtype(jnp.float32)
+
+    def read():
+        fwd, bwd, params = _layer_scans(cfg)
+        layer = {k: p.shape for k, p in params.items()
+                 if p.shape[:1] == (cfg.n_layers,)}
+        matrices = {s for k, s in layer.items() if k not in _READ_IN_FLOAT32}
+        xs = fwd.invars[fwd.params["num_consts"] + fwd.params["num_carry"]:]
+        saved = sorted(v.aval.shape for v in fwd.outvars if v.aval.shape in matrices)
+        grads = {k: [v.aval.dtype for v in bwd.outvars if v.aval.shape == s]
+                 for k, s in layer.items()}
+        return matrices, {(v.aval.shape, v.aval.dtype) for v in xs}, saved, grads
+
+    matrices, xs, saved, grads = read()
+    assert len(matrices) == 4 and ("moe_gate_weight" in grads) == bool(cfg.n_experts)
+    # the forward scan reads each matrix stack in the compute dtype as its
+    # xs and returns no array shaped like one
+    assert xs >= {(s, cdt) for s in matrices}
+    assert not saved
+    # the backward gives the matrices' gradients in the compute dtype and
+    # those of what the block reads in float32 in float32
+    for k, dtypes in grads.items():
+        want = f32 if k in _READ_IN_FLOAT32 else cdt
+        assert dtypes and set(dtypes) == {want}, (k, dtypes)
+
+    # cast in the body: each layer's cast is stacked again (unless the layer
+    # is recomputed) and the gradients leave the loop in float32
+    monkeypatch.setattr(tfm, "_SCAN_CAST", frozenset())
+    _matrices, xs, saved, grads = read()
+    assert not xs & {(s, cdt) for s in matrices}
+    assert saved == ([] if cfg.remat else sorted(matrices))
+    assert all(set(d) == {f32} for d in grads.values())
+
+
+@_KINDS
+def test_cast_before_the_scan_is_the_cast_in_its_body_bit_for_bit(monkeypatch, kw):
+    cfg = _small(**kw)
+    mesh = make_mesh({"dp": 1}, devices=jax.devices()[:1])
+    params = tfm.init_params(cfg, seed=6)
+    tokens = jnp.asarray(np.random.RandomState(5).randint(
+        0, cfg.vocab, (4, 17)).astype(np.int32))
+
+    def loss_and_grads():
+        loss_fn, _ = tfm.make_loss_fn(cfg, mesh)
+        loss, grads = jax.jit(jax.value_and_grad(loss_fn))(params, tokens)
+        return np.asarray(loss), {k: np.asarray(v) for k, v in grads.items()}
+
+    loss, grads = loss_and_grads()
+    monkeypatch.setattr(tfm, "_SCAN_CAST", frozenset())   # cast in the body
+    ref_loss, ref_grads = loss_and_grads()
+    np.testing.assert_array_equal(loss.view(np.uint32), ref_loss.view(np.uint32))
+    assert grads.keys() == ref_grads.keys() == params.keys()
+    for k in grads:
+        assert grads[k].dtype == ref_grads[k].dtype == np.float32, k
+        np.testing.assert_array_equal(grads[k].view(np.uint32),
+                                      ref_grads[k].view(np.uint32), err_msg=k)
+
+
 # -- what the layer scan saves ------------------------------------------------
 TINY = dict(vocab=512, d_model=256, n_heads=4, n_layers=2, d_ff=1024,
             max_len=128, dtype="bfloat16")
@@ -228,3 +335,33 @@ def test_layer_scan_saves_no_float32_activation_and_no_mask(monkeypatch, plain):
     predicted = L * (6 * 4 * bsd + bsf - 2 * 2 * bsd) + 4 * 4 * bsd - 2 * bsd
     saved = sum(r[2] for r in before) - sum(r[2] for r in res)
     assert saved >= predicted
+
+
+def test_residual_account_counts_the_cast_weights_apart_from_the_stacks(monkeypatch):
+    import os
+    monkeypatch.syspath_prepend(os.path.join(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__))), "tools"))
+    import lm_residuals
+
+    import mxnet_tpu.kernels  # noqa: F401  (loads kernels.flash_attention)
+    for name in ("mxnet_tpu.models.transformer", "mxnet_tpu.kernels.flash_attention"):
+        monkeypatch.setattr(sys.modules[name], "kernel_platform", lambda: "tpu")
+    cfg = tfm.TransformerConfig(**TINY)
+    L, d, f, S = cfg.n_layers, cfg.d_model, cfg.d_ff, TINY["max_len"]
+
+    def account():
+        res = lm_residuals.residuals(cfg, BATCH, S)
+        return res, lm_residuals.summary(res, cfg, BATCH, S)
+
+    res, report = account()
+    weights = {(L, d, 3, cfg.n_heads, d // cfg.n_heads), (L, cfg.n_heads, d // cfg.n_heads, d),
+               (L, d, f), (L, f, d)}
+    assert {r[0] for r in res if r[3] == "scan_xs"} == weights
+    assert report["scan_xs_bytes"] == 2 * L * (4 * d * d + 2 * d * f)
+    assert not [r for r in res if r[3] == "stacked" and r[0] in weights]
+
+    monkeypatch.setattr(tfm, "_SCAN_CAST", frozenset())   # cast in the body
+    _res, before = account()
+    assert before["scan_xs_bytes"] == 0
+    assert before["stacked_bytes"] - report["stacked_bytes"] == report["scan_xs_bytes"]
+    assert before["total_bytes"] == report["total_bytes"]

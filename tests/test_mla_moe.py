@@ -233,13 +233,14 @@ def test_bfloat16_weights_are_bound_as_given():
 # sha256 of the StableHLO text of the tiny transformer's prefill, decode and
 # train step, taken at the commit before this model came (a214a91); the train
 # step's replaced in ISSUE 41 (the LayerNorm's and the ReLU's own VJPs change
-# what it saves, not prefill or decode).  A PR that means to change those
-# programs says so and replaces the digests:
+# what it saves, not prefill or decode), and again when the layer scan came to
+# read its weight matrices cast to bfloat16 before it.  A PR that means to
+# change those programs says so and replaces the digests:
 #   python -c "import tests.test_mla_moe as t; print(t.transformer_digests())"
 TRANSFORMER_PROGRAMS = {
     "prefill": "48361dcb20cc12453f83f81f0eea47ea8681ae739ce3db2812c28741c1f7a304",
     "decode": "7373bd46d3bf3fae509f9dc328af67c364658a135ae4e064a5f22e8e1df92c10",
-    "train_step": "a2fa007dad315b716e83a475c11d0eb32361bf378aad58973ee5df3d877c0da5",
+    "train_step": "6b1ee40567717145a93ef6c5985c287846f9f85101f3ffeb3ee952e388483e0e",
 }
 
 
