@@ -3,8 +3,20 @@ sigmoid top-k expert routing over a chip's share of the experts, for
 incremental decode through :class:`~mxnet_tpu.serving.GenerativePredictor`.
 
 The block (pre-norm, RMSNorm, residual in the compute type; ``h`` is the
-normed input of a half):
+normed input of a sublayer; each sublayer gives its branch output ``F(u)``
+and :func:`_residual` puts it on the residual path):
 
+- **residual path**.  With ``hc_mult`` 1 one stream, ``x + F(x)``.  With
+  ``hc_mult`` n > 1 n streams (the embedding copied into each, summed
+  before the head), mixed around every sublayer by a manifold-constrained
+  hyper-connection (Hyper-Connections, in DeepSeek's mHC form): from the
+  streams ``X`` (n, d) of a token, ``v = vec(X) / rms(vec(X))``,
+  ``a = v phi`` (n (n + 2) outputs), ``H_pre = sigmoid(alpha_0 a[:n] + b)``,
+  ``H_post = hc_magnitude sigmoid(alpha_1 a[n:2n] + b)``, ``H_res`` the
+  Sinkhorn projection (``HC_SINKHORN_ITERS`` turns of row then column
+  normalisation, ``hc_eps``) of ``exp(alpha_2 a[2n:] + b)`` as n x n;
+  ``u = sum_i H_pre[i] X[i]`` goes through the sublayer and
+  ``X[i] <- sum_j H_res[i, j] X[j] + H_post[i] F(u)``.  The maps are float32.
 - **latent attention** (DeepSeek-V2/V3's MLA).  ``c_q = RMSNorm(h W_qa)``,
   ``[q_nope | q_rope] = c_q W_qb`` a head, or with ``q_rank`` 0 a full-rank
   query ``h W_q``; with ``qk_norm`` an RMSNorm over each head's query, one
@@ -14,10 +26,13 @@ normed input of a half):
   DeepSeek's YaRN where ``yarn_factor`` is set); ``[k_nope | v] = c_kv
   W_kvb`` a head; ``softmax((q_nope . k_nope + q_rope . k_rope) s)`` with
   ``s = (d_nope + d_rope)^-1/2`` (times YaRN's ``mscale^2``) over the
-  *allowed* keys.  The cache holds one latent row ``[c_kv | k_rope]`` a
-  token a layer.  Prefill runs the expanded form, decode the absorbed one
-  (``q' = q_nope W_kvb[k]^T`` scored against ``c_kv`` itself, ``P c_kv``
-  up-projected by ``W_kvb[v]``).
+  *allowed* keys.  With ``attn_sink`` one learned logit a head joins the
+  softmax's denominator and carries no value; with ``attn_gate`` each head's
+  output is multiplied elementwise by ``sigmoid(h W_g)`` before ``W_o``
+  (Gated Attention, position G1).  The cache holds one latent row
+  ``[c_kv | k_rope]`` a token a layer.  Prefill runs the expanded form,
+  decode the absorbed one (``q' = q_nope W_kvb[k]^T`` scored against
+  ``c_kv`` itself, ``P c_kv`` up-projected by ``W_kvb[v]``).
 - **indexer** (DeepSeek-V3.2's lightning indexer), every layer, unless
   ``indexer`` is off: then every position attends all earlier ones, and
   decode reads every cached row through :func:`paged_attention` (the
@@ -27,7 +42,12 @@ normed input of a half):
   ``heads^-1/2 dim^-1/2``; ``I[t, s] = sum_h w[t, h] relu(q_I[t, h] . k_I[s])``
   in float32; position ``t`` may attend the ``index_topk`` causal positions
   of largest ``I`` (all of them while there are no more).  The cache holds
-  ``k_I`` beside the latent row, under the same block table.
+  ``k_I`` beside the latent row, under the same block table.  Where
+  ``indexer_types`` names a layer ``shared`` (IndexCache's cross-layer
+  reuse), that layer has no indexer, no index keys and no index pool: it
+  attends the positions the nearest earlier ``full`` layer chose for the
+  same token (in decode that layer's kept ids, in prefill its mask, packed
+  32 positions a word).
 - **experts** (DeepSeek-V3's ``noaux_tc`` router without groups), layers
   past the leading dense ones.  ``s = sigmoid(h W_r)`` in float32 over all
   ``n_experts``; the ``experts_per_token`` largest of ``s + b`` are chosen
@@ -37,9 +57,12 @@ normed input of a half):
   shared expert.  What the absent experts would have added is left out:
   the partial result goes on, as on one chip of an expert-parallel pool.
   An expert no token of the step chose is skipped (``lax.cond``), so a
-  decode step reads the experts it touches.
+  decode step reads the experts it touches.  With ``swiglu_limit`` every
+  SwiGLU's gate input is clamped from above and its up input to
+  ``[-limit, limit]``.
 - **head**: RMSNorm, then an untied head over the rows of the vocabulary
-  held here; the embedding has the same rows.
+  held here; the embedding has the same rows.  With ``head_fp32`` the
+  product is float32.
 
 Entry points are those ``GenerativePredictor`` asks a model module for:
 ``init_kv_cache``, ``kv_page_bytes``, ``make_prefill_fn``,
@@ -70,8 +93,13 @@ INDEXED_DECODE_COUNTERS = ("moe_pairs_held", "moe_tokens", "moe_experts_touched"
                            "moe_pairs_at_max_load", "dsa_keys_scanned", "dsa_keys_selected")
 DENSE_DECODE_COUNTERS = ("moe_pairs_held", "moe_tokens", "moe_experts_touched",
                          "moe_pairs_at_max_load", "attn_rows_read")
+# and where some layers reuse an earlier layer's selection: active slots a
+# shared layer
+REUSED_DECODE_COUNTER = "dsa_selections_reused"
 
 DECODE_BLOCK_K = 512     # cached rows an online-softmax turn of dense decode
+# turns of the hyper-connections' Sinkhorn projection (mHC's count)
+HC_SINKHORN_ITERS = 20
 # DeepSeek YaRN's correction range: the rotary pairs that turn this many times
 # over the original context bound the ramp (``beta_fast``, ``beta_slow``); its
 # ``mscale`` and ``mscale_all_dim`` are 1, so cos and sin are not scaled
@@ -111,11 +139,31 @@ class LatentMoEConfig:
     index_norm_eps: float = 1e-6
     max_len: int = 202752
     dtype: str = "bfloat16"         # weights as given; cache and products
+    # per layer "full" (an indexer of its own) or "shared" (the selection of
+    # the nearest earlier full layer); empty: every layer full
+    indexer_types: tuple = ()
+    # streams of the residual path; more than 1: hyper-connected
+    hc_mult: int = 1
+    hc_magnitude: float = 2.0       # the scale of H_post
+    hc_eps: float = 1e-6            # Sinkhorn's epsilon
+    attn_gate: bool = False         # o * sigmoid(h W_g) a head before W_o
+    attn_sink: bool = False         # a learned logit a head in the softmax
+    swiglu_limit: float = 0.0       # the SwiGLU inputs' clamp; 0: none
+    head_fp32: bool = False         # the head's product in float32
     # the module whose programs serve this configuration
     module: str = "mxnet_tpu.models.mla_moe"
 
     def __post_init__(self):
         self.held_experts = tuple(int(e) for e in self.held_experts)
+        self.indexer_types = tuple(str(t) for t in self.indexer_types)
+        types = self.indexer_types
+        if types and (not self.indexer or len(types) != self.n_layers
+                      or types[0] != "full" or set(types) - {"full", "shared"}):
+            raise ValueError("indexer_types: one of 'full' or 'shared' a layer, "
+                             "the first 'full', and an indexer; got %r" % (types,))
+        if (self.attn_gate or self.attn_sink) and not self.indexer:
+            raise ValueError("a gated or sink attention decodes through the "
+                             "indexed form only")
 
 
 def param_shapes(config):
@@ -125,9 +173,14 @@ def param_shapes(config):
     dense layers, router and expert leaves over the layers that follow.  A
     full-rank query is ``q_weight`` in the place of ``q_a_*`` and
     ``q_b_weight``; the norms' gains ``q_norm`` and ``k_norm`` are there
-    where ``qk_norm`` is on, the indexer's leaves where the indexer is."""
+    where ``qk_norm`` is on, the indexer's leaves where the indexer is,
+    stacked over the layers with an indexer of their own.  Hyper-connections
+    add a map a sublayer (``hc_attn_*``, ``hc_ffn_*``: ``proj`` drawn ``hc``,
+    ``bias`` drawn ``hc_bias``, ``scale`` the alphas); the gate adds
+    ``o_gate_weight``, the sink ``attn_sink``."""
     c = config
     d, L, H = c.d_model, c.n_layers, c.n_heads
+    Lf = len(_full_layers(c))
     Ld, Lm, Eh = c.n_dense_layers, c.n_layers - c.n_dense_layers, len(c.held_experts)
     qk = c.d_nope + c.d_rope
     out = {
@@ -143,11 +196,11 @@ def param_shapes(config):
         "kv_a_norm": ((L, c.kv_rank), "ones"),
         "kv_b_weight": ((L, c.kv_rank, H, c.d_nope + c.d_v), "normal"),
         "o_weight": ((L, H, c.d_v, d), "normal"),
-        "index_q_weight": ((L, c.q_rank, c.index_heads, c.index_dim), "normal"),
-        "index_k_weight": ((L, d, c.index_dim), "normal"),
-        "index_k_norm_gamma": ((L, c.index_dim), "ones"),
-        "index_k_norm_beta": ((L, c.index_dim), "zeros"),
-        "index_w_weight": ((L, d, c.index_heads), "normal"),
+        "index_q_weight": ((Lf, c.q_rank, c.index_heads, c.index_dim), "normal"),
+        "index_k_weight": ((Lf, d, c.index_dim), "normal"),
+        "index_k_norm_gamma": ((Lf, c.index_dim), "ones"),
+        "index_k_norm_beta": ((Lf, c.index_dim), "zeros"),
+        "index_w_weight": ((Lf, d, c.index_heads), "normal"),
         "dense_gate_weight": ((Ld, d, c.d_ff), "normal"),
         "dense_up_weight": ((Ld, d, c.d_ff), "normal"),
         "dense_down_weight": ((Ld, c.d_ff, d), "normal"),
@@ -170,13 +223,31 @@ def param_shapes(config):
     if c.qk_norm:
         out["q_norm"] = ((L, qk), "ones")
         out["k_norm"] = ((L, c.d_rope), "ones")
+    n = c.hc_mult
+    if n > 1:
+        for half in ("attn", "ffn"):
+            out["hc_%s_proj" % half] = ((L, n * d, n * (n + 2)), "hc")
+            out["hc_%s_bias" % half] = ((L, n * (n + 2)), "hc_bias")
+            out["hc_%s_scale" % half] = ((L, 3), "ones")
+    if c.attn_gate:
+        # the heads' lanes side by side: a (d, H, d_v) matrix is no bitcast
+        # of (d, H d_v) under the TPU's tiling, and its product would copy
+        # it to another layout every step
+        out["o_gate_weight"] = ((L, d, H * c.d_v), "normal")
+    if c.attn_sink:
+        out["attn_sink"] = ((L, H), "sink")
     return out
 
 
 def init_params(config, seed=0, scale=0.02, bias_scale=0.01):
     """Seeded float32 parameters on the host (tests and examples; the
-    benchmark makes its own on the device)."""
+    benchmark makes its own on the device): matrices normal(0, ``scale``),
+    a hyper-connection's projection normal(0, ``scale / sqrt(hc_mult)``)
+    (its input is ``hc_mult`` times wider), its biases and the sinks
+    normal(0, 1), the router's bias normal(0, ``bias_scale``)."""
     rng = np.random.RandomState(seed)
+    std = {"bias": bias_scale, "hc": scale / np.sqrt(config.hc_mult),
+           "hc_bias": 1.0, "sink": 1.0}
     out = {}
     for name, (shape, kind) in sorted(param_shapes(config).items()):
         if kind == "ones":
@@ -184,8 +255,7 @@ def init_params(config, seed=0, scale=0.02, bias_scale=0.01):
         elif kind == "zeros":
             out[name] = np.zeros(shape, np.float32)
         else:
-            out[name] = rng.normal(0.0, bias_scale if kind == "bias" else scale,
-                                   shape).astype(np.float32)
+            out[name] = rng.normal(0.0, std.get(kind, scale), shape).astype(np.float32)
     return {k: jnp.asarray(v) for k, v in out.items()}
 
 
@@ -199,11 +269,28 @@ def _latent_width(config):
     return -(-(config.kv_rank + config.d_rope) // 128) * 128
 
 
+def _full_layers(config):
+    """The layers with an indexer of their own, in order."""
+    c = config
+    if not c.indexer:
+        return ()
+    types = c.indexer_types or ("full",) * c.n_layers
+    return tuple(i for i, t in enumerate(types) if t == "full")
+
+
+def _index_rank(config, i):
+    """Layer ``i``'s place among :func:`_full_layers` (its index leaves and
+    index pool), None where it has no indexer of its own."""
+    full = _full_layers(config)
+    return full.index(i) if i in full else None
+
+
 def init_kv_cache(config, num_pages, page_size, dtype=None):
-    """Zeroed page pool, two arrays a layer under one block table:
-    ``latent[l]`` (pages + 1, page, :func:`_latent_width`) rows
-    ``[c_kv | k_rope | 0]`` and ``index[l]`` (pages + 1, page, index_dim)
-    rows ``k_I``; without the indexer the latent rows alone.  A layer's
+    """Zeroed page pool under one block table: ``latent[l]`` (pages + 1,
+    page, :func:`_latent_width`) rows ``[c_kv | k_rope | 0]`` a layer and
+    ``index[f]`` (pages + 1, page, index_dim) rows ``k_I`` a layer with an
+    indexer of its own (:func:`_full_layers`); without the indexer the
+    latent rows alone.  A layer's
     arrays are its own, so that a step writes its row into them in place and
     gathers from them without slicing a pool of all layers first.  Page 0 is
     the scratch page, as in the transformer's pool."""
@@ -214,21 +301,25 @@ def init_kv_cache(config, num_pages, page_size, dtype=None):
                         for _ in range(c.n_layers)]}
     if c.indexer:
         pools["index"] = [jnp.zeros(lead + (c.index_dim,), cdt)
-                          for _ in range(c.n_layers)]
+                          for _ in _full_layers(c)]
     return pools
 
 
 def kv_page_bytes(config, page_size):
     """Bytes one page holds over all layers and arrays."""
     c = config
-    index = c.index_dim if c.indexer else 0
-    return (c.n_layers * int(page_size) * (_latent_width(c) + index)
+    return (int(page_size) * (c.n_layers * _latent_width(c)
+                              + len(_full_layers(c)) * c.index_dim)
             * jnp.dtype(c.dtype).itemsize)
 
 
 def decode_counters(config):
     """Names of what the decode program counts for ``config``."""
-    return INDEXED_DECODE_COUNTERS if config.indexer else DENSE_DECODE_COUNTERS
+    if not config.indexer:
+        return DENSE_DECODE_COUNTERS
+    if len(_full_layers(config)) < config.n_layers:
+        return INDEXED_DECODE_COUNTERS + (REUSED_DECODE_COUNTER,)
+    return INDEXED_DECODE_COUNTERS
 
 
 def _decode_block_k(config, slots, max_ctx):
@@ -314,24 +405,44 @@ def _dot(a, b, spec, cdt):
                       preferred_element_type=jnp.float32)
 
 
-def _swiglu(x, gate, up, down, cdt):
+def _swiglu(x, gate, up, down, cdt, limit=0.0):
+    """``(silu(x W_g) * (x W_u)) W_d``; with ``limit`` the gate input is
+    clamped from above and the up input to ``[-limit, limit]``."""
     g = _dot(x, gate, "td,df->tf", cdt)
     u = _dot(x, up, "td,df->tf", cdt)
+    if limit:
+        g, u = jnp.minimum(g, limit), jnp.clip(u, -limit, limit)
     return _dot((jax.nn.silu(g) * u).astype(cdt), down, "tf,fd->td", cdt)
 
 
-# the leaves stacked over all layers, those a configuration has
+# the leaves stacked over all layers, those a configuration has (the
+# indexer's over the layers with one of their own)
 _LAYER_LEAVES = ("attn_norm", "ffn_norm", "q_a_weight", "q_a_norm", "q_b_weight",
                  "kv_a_weight", "kv_a_norm", "kv_b_weight", "o_weight",
                  "index_q_weight", "index_k_weight", "index_k_norm_gamma",
-                 "index_k_norm_beta", "index_w_weight", "q_weight", "q_norm", "k_norm")
+                 "index_k_norm_beta", "index_w_weight", "q_weight", "q_norm", "k_norm",
+                 "hc_attn_proj", "hc_attn_bias", "hc_attn_scale", "hc_ffn_proj",
+                 "hc_ffn_bias", "hc_ffn_scale", "attn_sink")
 
 
 def _layer(params, i, config):
-    """Layer ``i``'s leaves: attention and indexer from the all-layer
-    stacks, the FFN's from the dense or the expert stacks."""
+    """Layer ``i``'s leaves: attention and indexer from their stacks (none
+    of the indexer's where the layer reuses a selection), the FFN's from
+    the dense or the expert stacks."""
     c = config
-    lp = {k: params[k][i] for k in _LAYER_LEAVES if k in params}
+    f = _index_rank(c, i)
+    lp = {}
+    for k in _LAYER_LEAVES:
+        if k not in params:
+            continue
+        if not k.startswith("index_"):
+            lp[k] = params[k][i]
+        elif f is not None:
+            lp[k] = params[k][f]
+    if "o_gate_weight" in params:
+        # the stack whole, with the layer's index: sliced where it is used
+        # (inside prefill's row blocks), never copied out to be handed in
+        lp["o_gate_weight"] = (i, params["o_gate_weight"])
     if i < c.n_dense_layers:
         group, j = ("dense_gate_weight", "dense_up_weight", "dense_down_weight"), i
     else:
@@ -499,7 +610,8 @@ def _held_experts(h, ids, gates, experts, c, cdt, y):
 
             def run(j=j, gate=gate):
                 at = j if layer is None else (layer, j)
-                out = _swiglu(h, *(w[at] for w in stacks), cdt)
+                out = _swiglu(h, *(w[at] for w in stacks), cdt,
+                              getattr(c, "swiglu_limit", 0.0))
                 return out * gate[:, None]
 
             y = y + lax.cond(loads[-1] > 0, run, lambda: jnp.zeros_like(y))
@@ -522,25 +634,108 @@ def _moe(h, lp, c, cdt, active):
     ids, gates = _route(h, lp, c, active)
     with jax.named_scope("mx.lm.moe.shared"):
         y = _swiglu(h, lp["shared_gate_weight"], lp["shared_up_weight"],
-                    lp["shared_down_weight"], cdt)
+                    lp["shared_down_weight"], cdt, c.swiglu_limit)
     y, loads = _held_experts(h, ids, gates, lp["experts"], c, cdt, y)
     return y, _moe_counts(loads, active, c)
 
 
-def _ffn(x, lp, c, cdt, active):
-    """The FFN half of a block on residual rows ``x`` (T, d)."""
-    h = _rmsnorm(x, lp["ffn_norm"], c.norm_eps).astype(cdt)
+def _ffn(u, lp, c, cdt, active):
+    """The FFN sublayer's branch on rows ``u`` (T, d): its output and what
+    it counted."""
+    h = _rmsnorm(u, lp["ffn_norm"], c.norm_eps).astype(cdt)
     if "router_weight" in lp:
         y, counts = _moe(h, lp, c, cdt, active)
     else:
         with jax.named_scope("mx.lm.ffn"):
             y = _swiglu(h, lp["dense_gate_weight"], lp["dense_up_weight"],
-                        lp["dense_down_weight"], cdt)
+                        lp["dense_down_weight"], cdt, c.swiglu_limit)
         counts = {}
-    return x + y.astype(cdt), counts
+    return y, counts
+
+
+# -- the residual path -------------------------------------------------------
+def _streams(x, c):
+    """Embedded rows (T, d) as the residual path's carry: themselves, or
+    copied into each of ``hc_mult`` streams (T, n, d)."""
+    if c.hc_mult == 1:
+        return x
+    with jax.named_scope("mx.lm.hc"):
+        return jnp.broadcast_to(x[:, None], (x.shape[0], c.hc_mult, x.shape[1]))
+
+
+def _merged(x, c):
+    """The carry as the head takes it: the streams' sum in float32."""
+    if c.hc_mult == 1:
+        return x
+    with jax.named_scope("mx.lm.hc"):
+        return jnp.sum(x.astype(jnp.float32), axis=1)
+
+
+def _hc_in(x, lp, half, c, cdt):
+    """What sublayer ``half`` reads of the carry ``x`` and the maps that put
+    its output back: ``(x, None)`` on a plain residual; with
+    hyper-connections (``hc_<half>_*`` leaves) ``u = sum_i H_pre[i] X[i]``
+    (T, d) and ``(H_post (T, n), H_res (T, n, n))``, float32, from
+    ``v = vec(X) / rms(vec(X))`` projected once (``v phi`` is ``vec(X) phi``
+    over the rms, so the product takes the carry as it is)."""
+    if "hc_%s_proj" % half not in lp:
+        return x, None
+    T, n, d = x.shape
+    with jax.named_scope("mx.lm.hc"):
+        flat = x.reshape(T, n * d)
+        ms = jnp.mean(jnp.square(flat.astype(jnp.float32)), axis=-1, keepdims=True)
+        a = _dot(flat, lp["hc_%s_proj" % half], "tk,km->tm", cdt) \
+            * lax.rsqrt(ms + c.norm_eps)
+        alpha = lp["hc_%s_scale" % half].astype(jnp.float32)
+        b = lp["hc_%s_bias" % half].astype(jnp.float32)
+        pre = jax.nn.sigmoid(alpha[0] * a[:, :n] + b[:n])
+        post = c.hc_magnitude * jax.nn.sigmoid(alpha[1] * a[:, n:2 * n] + b[n:2 * n])
+        res = jnp.exp(alpha[2] * a[:, 2 * n:] + b[2 * n:]).reshape(T, n, n)
+        for _ in range(HC_SINKHORN_ITERS):
+            res = res / (jnp.sum(res, axis=-1, keepdims=True) + c.hc_eps)
+            res = res / (jnp.sum(res, axis=-2, keepdims=True) + c.hc_eps)
+        u = jnp.sum(pre[:, :, None] * x.astype(jnp.float32), axis=1).astype(cdt)
+    return u, (post, res)
+
+
+def _hc_out(x, y, maps, cdt):
+    """The carry after a sublayer whose branch gave ``y`` (T, d): ``x + y``
+    on a plain residual, else ``X[i] <- sum_j H_res[i, j] X[j] +
+    H_post[i] y``."""
+    if maps is None:
+        return x + y.astype(cdt)
+    post, res = maps
+    with jax.named_scope("mx.lm.hc"):
+        mixed = jnp.sum(res[..., None] * x.astype(jnp.float32)[:, None], axis=2)
+        return (mixed + post[:, :, None] * y.astype(cdt)[:, None, :]).astype(cdt)
+
+
+def _residual(x, branch, lp, half, c, cdt):
+    """Sublayer ``half`` on the residual path: ``branch(u)`` gives its output
+    and what it counted; returns the new carry and the count."""
+    u, maps = _hc_in(x, lp, half, c, cdt)
+    y, extra = branch(u)
+    return _hc_out(x, y, maps, cdt), extra
+
+
+def _exact_dot(x, w, spec):
+    """Float32 ``x`` times ``w`` to float32 rounding: where ``w`` is narrower,
+    ``x`` as the sum of three parts of ``w``'s type, each product with ``w``
+    exact, accumulated in float32 in one product."""
+    if w.dtype == jnp.float32:
+        return jnp.einsum(spec, x, w, preferred_element_type=jnp.float32)
+    parts, rest = [], x
+    for _ in range(3):
+        parts.append(rest.astype(w.dtype))
+        rest = rest - parts[-1].astype(jnp.float32)
+    y = jnp.einsum(spec, jnp.concatenate(parts), w, preferred_element_type=jnp.float32)
+    return sum(jnp.split(y, 3))
 
 
 def _head(x, params, c, cdt):
+    if getattr(c, "head_fp32", False):
+        return _exact_dot(_rmsnorm(x, params["final_norm"], c.norm_eps),
+                          params["head_weight"], "td,vd->tv")
     h = _rmsnorm(x, params["final_norm"], c.norm_eps).astype(cdt)
     return _dot(h, params["head_weight"], "td,vd->tv", cdt)
 
@@ -558,8 +753,32 @@ KEY_CHUNKS = 8       # key chunks a query block may skip when they lie ahead
 KEY_SPAN = 4096      # keys a chunk holds at most: longer prompts take more chunks
 
 
+def _pack(mask):
+    """(rows, K) bool as (rows, ceil(K / 32)) uint32, position ``32 w + j``
+    in bit ``j`` of word ``w``."""
+    rows, k = mask.shape
+    bits = jnp.pad(mask, ((0, 0), (0, -k % 32))).reshape(rows, -1, 32)
+    return jnp.sum(bits.astype(jnp.uint32) << jnp.arange(32, dtype=jnp.uint32), axis=-1,
+                   dtype=jnp.uint32)
+
+
+def _unpack(words, k):
+    """:func:`_pack`'s inverse: (rows, k) bool."""
+    bits = (words[:, :, None] >> jnp.arange(32, dtype=jnp.uint32)) & 1
+    return bits.reshape(words.shape[0], -1)[:, :k].astype(bool)
+
+
+def _gate(h, lp, c, cdt):
+    """``sigmoid(h W_g)`` (T, H, d_v) float32, the elementwise output gate
+    of rows ``h`` (T, d); ``W_g`` is (d, H d_v)."""
+    layer, stack = lp["o_gate_weight"]
+    with jax.named_scope("mx.gen.latent_proj"):
+        g = _dot(h, stack[layer], "td,df->tf", cdt)
+        return jax.nn.sigmoid(g).reshape(h.shape[0], c.n_heads, c.d_v)
+
+
 def _expanded_attention(c_q, positions, length, latent, lp, c, cdt, index=None,
-                        q_scale=None):
+                        q_scale=None, h=None, keep=False, out_dtype=jnp.float32):
     """Causal attention of a whole sequence in the expanded form: keys and
     values of every head are made from the latent rows once; the queries go
     through in row blocks and the keys in ``KEY_CHUNKS`` chunks (online
@@ -567,9 +786,14 @@ def _expanded_attention(c_q, positions, length, latent, lp, c, cdt, index=None,
     A chunk of keys that lies wholly ahead of a block's rows is skipped, and
     so is a block of rows wholly past ``length`` (the padded tail of a
     prompt).  With ``index`` = (``q_I``, ``w``, ``k_I``) a row attends the
-    ``index_topk`` causal keys of largest index score, without it every
-    causal key; ``q_scale`` goes to :func:`_queries`.  Returns (T, d) in
-    float32, through the output projection."""
+    ``index_topk`` causal keys of largest index score, with ``index`` an
+    earlier layer's selection (T, ceil(T / 32)) as :func:`_pack` packs it
+    those keys, without it every causal key; ``q_scale`` goes to
+    :func:`_queries`.  A layer with ``attn_sink`` starts each head's softmax
+    from its sink; one with ``o_gate_weight`` gates the heads' values by
+    :func:`_gate` of its normed rows ``h``.  Returns (T, d) in ``out_dtype``,
+    through the output projection, and with ``keep`` the selection, packed,
+    beside it."""
     T = c_q.shape[0]
     with jax.named_scope("mx.gen.attn"):
         c_kv = latent[:, :c.kv_rank]
@@ -587,6 +811,8 @@ def _expanded_attention(c_q, positions, length, latent, lp, c, cdt, index=None,
     while T % rows:
         rows -= 1
     H = c.n_heads
+    carried = index is not None and not isinstance(index, tuple)
+    gated = "o_gate_weight" in lp
 
     def select(q_i, w, pos, last):
         with jax.named_scope("mx.gen.index"):
@@ -598,10 +824,14 @@ def _expanded_attention(c_q, positions, length, latent, lp, c, cdt, index=None,
             scores = jnp.where(causal, jnp.concatenate(parts, axis=-1), -jnp.inf)
             return causal & _largest_k(scores, c.index_topk)
 
-    def attend(c_q, selectors, pos):
+    def attend(c_q, selectors, pos, h_rows):
         last = pos[-1]
-        allowed = select(*selectors, pos, last) if index is not None \
-            else key_pos[None, :] <= pos[:, None]
+        if carried:
+            allowed = _unpack(selectors[0], T)
+        elif index is not None:
+            allowed = select(*selectors, pos, last)
+        else:
+            allowed = key_pos[None, :] <= pos[:, None]
         q_nope, q_rope = _queries(c_q, pos, lp, c, cdt, q_scale)
 
         def chunk(cut, top, norm, acc):
@@ -613,31 +843,49 @@ def _expanded_attention(c_q, positions, length, latent, lp, c, cdt, index=None,
             new = jnp.maximum(top, jnp.max(s, axis=-1))
             # a row that has met no allowed key yet keeps -inf: shift by 0
             shift = jnp.where(new == -jnp.inf, 0.0, new)
-            keep = jnp.exp(top - shift)
+            keep_ = jnp.exp(top - shift)
             p = jnp.exp(s - shift[..., None])
             pv = jnp.einsum("hts,she->hte", p.astype(cdt), v[cut],
                             preferred_element_type=jnp.float32)
-            return (new, norm * keep + jnp.sum(p, axis=-1),
-                    acc * keep[..., None] + pv)
+            return (new, norm * keep_ + jnp.sum(p, axis=-1),
+                    acc * keep_[..., None] + pv)
 
+        gate = _gate(h_rows, lp, c, cdt) if gated else None
         with jax.named_scope("mx.gen.attn"):
-            state = (jnp.full((H, rows), -jnp.inf, jnp.float32),
-                     jnp.zeros((H, rows), jnp.float32),
-                     jnp.zeros((H, rows, c.d_v), jnp.float32))
+            if "attn_sink" in lp:
+                # the sink is a key of logit ``sink`` and no value
+                sink = jnp.broadcast_to(lp["attn_sink"].astype(jnp.float32)[:, None],
+                                        (H, rows))
+                state = (sink, jnp.ones((H, rows), jnp.float32),
+                         jnp.zeros((H, rows, c.d_v), jnp.float32))
+            else:
+                state = (jnp.full((H, rows), -jnp.inf, jnp.float32),
+                         jnp.zeros((H, rows), jnp.float32),
+                         jnp.zeros((H, rows, c.d_v), jnp.float32))
             for cut in cuts:
                 state = lax.cond(cut.start <= last,
                                  lambda st, cut=cut: chunk(cut, *st),
                                  lambda st: st, state)
             _top, norm, acc = state
             o = (acc / norm[..., None]).transpose(1, 0, 2)
-        return _output(o, lp, cdt)
+            if gate is not None:
+                o = o * gate
+        out = _output(o, lp, cdt).astype(out_dtype)
+        return (out, _pack(allowed)) if keep else out
 
     def block(c_q, *rest):
         *selectors, pos = rest         # the indexer's rows, where there is one
-        return lax.cond(pos[0] < length, lambda: attend(c_q, selectors, pos),
-                        lambda: jnp.zeros((rows, c.d_model), jnp.float32))
+        h_rows = selectors.pop() if gated else None
 
-    return _row_blocks(block, T, rows, c_q, *(index[:2] if index is not None else ()),
+        def skipped():
+            out = jnp.zeros((rows, c.d_model), out_dtype)
+            return (out, jnp.zeros((rows, -(-T // 32)), jnp.uint32)) if keep else out
+
+        return lax.cond(pos[0] < length, lambda: attend(c_q, selectors, pos, h_rows),
+                        skipped)
+
+    selectors = () if index is None else (index,) if carried else index[:2]
+    return _row_blocks(block, T, rows, c_q, *selectors, *((h,) if gated else ()),
                        positions)
 
 
@@ -711,9 +959,10 @@ def paged_attention(pool, q_nope, q_rope, lengths, block_tables, lp, c, cdt, blo
 def _sequence_layers(params, x, config, on_layer, length=None):
     """All layers over one whole sequence ``x`` (T, d) at positions
     0..T-1, expanded attention; ``on_layer(i, latent, k_i)`` sees what a
-    cache would hold (``k_i`` None without the indexer).  Rows from
-    ``length`` on (a prompt's padded tail) are carried along, not
-    computed."""
+    cache would hold (``k_i`` None where the layer has no indexer of its
+    own).  Rows from ``length`` on (a prompt's padded tail) are carried
+    along, not computed.  Returns the carry: (T, d), or (T, n, d) with
+    ``hc_mult`` n streams, whose maps go a block of rows at a time."""
     c = config
     cdt = jnp.dtype(c.dtype)
     T = x.shape[0]
@@ -723,22 +972,45 @@ def _sequence_layers(params, x, config, on_layer, length=None):
     ffn_rows = min(T, 1024)
     while T % ffn_rows:
         ffn_rows -= 1
+    full = _full_layers(c)
 
     def ffn(lp, xb, real):
-        return lax.cond(real[0], lambda: _ffn(xb, lp, c, cdt, real)[0], lambda: xb)
+        return lax.cond(real[0], lambda: _residual(
+            xb, lambda u: _ffn(u, lp, c, cdt, real), lp, "ffn", c, cdt)[0], lambda: xb)
 
+    def hc_in(lp, xb):
+        return _hc_in(xb, lp, "attn", c, cdt)
+
+    def hc_out(xb, ob, *maps):
+        return _hc_out(xb, ob, maps, cdt)
+
+    x = _streams(x, c)
+    selection = None           # the latest full layer's, where a shared one follows
     for i in range(c.n_layers):
         lp = _layer(params, i, c)
-        h = _rmsnorm(x, lp["attn_norm"], c.norm_eps).astype(cdt)
-        if c.indexer:
+        if c.hc_mult == 1:
+            u, maps = x, None
+        else:
+            u, maps = _row_blocks(functools.partial(hc_in, lp), T, ffn_rows, x)
+        h = _rmsnorm(u, lp["attn_norm"], c.norm_eps).astype(cdt)
+        if i in full:
             c_q, latent, q_i, k_i, w = _project(h, positions, lp, c, cdt)
             index = (q_i, w, k_i)
         else:
             c_q, latent = _latent_project(h, positions, lp, c, cdt)
-            k_i = index = None
+            k_i, index = None, selection
         on_layer(i, latent, k_i)
-        o = _expanded_attention(c_q, positions, length, latent, lp, c, cdt, index=index)
-        x = x + o.astype(cdt)
+        # a full layer hands its selection on where a shared one follows
+        keep = i in full and i + 1 < c.n_layers and i + 1 not in full
+        o = _expanded_attention(c_q, positions, length, latent, lp, c, cdt, index=index,
+                                h=h, keep=keep,
+                                out_dtype=jnp.float32 if maps is None else cdt)
+        if keep:
+            o, selection = o
+        if maps is None:
+            x = _hc_out(x, o, None, cdt)
+        else:
+            x = _row_blocks(hc_out, T, ffn_rows, x, o, *maps)
         x = _row_blocks(functools.partial(ffn, lp), T, ffn_rows, x, real)
     return x
 
@@ -752,7 +1024,7 @@ def make_forward_fn(config):
     def forward(params, tokens):
         x = jnp.take(params["embed_weight"], tokens, axis=0).astype(cdt)
         x = _sequence_layers(params, x, c, lambda *_: None)
-        return _head(x, params, c, cdt)
+        return _head(_merged(x, c), params, c, cdt)
 
     return jax.jit(forward)
 
@@ -785,16 +1057,17 @@ def make_prefill_fn(config, page_size, mesh=None):
 
         def write(i, latent, k_i):
             with jax.named_scope("mx.gen.pool_write"):
-                for name, rows in (("latent", latent), ("index", k_i)):
-                    if name not in pools:
+                for name, rows, at in (("latent", latent, i),
+                                       ("index", k_i, _index_rank(c, i))):
+                    if name not in pools or rows is None:
                         continue
                     paged = rows.reshape(n_pages, page_size, -1)
-                    pools[name][i] = pools[name][i].at[pages].set(
-                        paged.astype(pools[name][i].dtype))
+                    pools[name][at] = pools[name][at].at[pages].set(
+                        paged.astype(pools[name][at].dtype))
 
         x = _sequence_layers(params, x, c, write, length)
         last = lax.dynamic_index_in_dim(x, length - 1, axis=0, keepdims=True)
-        return pools, _head(last, params, c, cdt)[0]
+        return pools, _head(_merged(last, c), params, c, cdt)[0]
 
     return prefill
 
@@ -809,10 +1082,12 @@ def make_decode_fn(config, slots, max_pages_per_slot, page_size,
     ``block_tables[b, positions[b] // page_size]``; the slot's cached index
     keys are scored, the ``index_topk`` best causal positions kept
     (``lax.top_k``), their latent rows gathered through the block table, and
-    attended in the absorbed form.  Without the indexer every cached row of
-    the slot is attended through :func:`paged_attention`, ``block_k`` rows a
-    turn (default ``DECODE_BLOCK_K``).  Inactive slots write to the scratch
-    page, attend nothing that counts and get zero logits."""
+    attended in the absorbed form; a layer that shares an earlier layer's
+    selection gathers its own rows at the ids that layer kept.  Without the
+    indexer every cached row of the slot is attended through
+    :func:`paged_attention`, ``block_k`` rows a turn (default
+    ``DECODE_BLOCK_K``).  Inactive slots write to the scratch page, attend
+    nothing that counts and get zero logits."""
     c = config
     cdt = jnp.dtype(c.dtype)
     page_size = int(page_size)
@@ -821,18 +1096,24 @@ def make_decode_fn(config, slots, max_pages_per_slot, page_size,
     block_k = int(block_k or DECODE_BLOCK_K)
     scale = _softmax_scale(c)
     names = decode_counters(c)
+    full = _full_layers(c)
     if mesh is not None:
         raise NotImplementedError("mla_moe: no sharded bind; one chip holds "
                                   "its share of the experts")
 
-    def attend(cache, i, c_q, q_i, w, positions, lengths, block_tables, lp):
-        S = c_q.shape[0]
+    def select(cache, f, q_i, w, lengths, block_tables):
+        """The slot's ``topk`` cached positions of largest index score (S,
+        topk) and which of them hold a key."""
+        S = q_i.shape[0]
         with jax.named_scope("mx.gen.index"):
-            keys = cache["index"][i][block_tables].reshape(S, max_ctx, -1)
+            keys = cache["index"][f][block_tables].reshape(S, max_ctx, -1)
             valid = jnp.arange(max_ctx)[None, :] < lengths[:, None]
             scores = jnp.where(valid, _index_scores(q_i, w, keys), -jnp.inf)
             top, chosen = lax.top_k(scores, topk)                # (S, topk)
             kept = top > -jnp.inf
+        return chosen, kept
+
+    def attend(cache, i, c_q, chosen, kept, positions, block_tables, lp, gate):
         q_nope, q_rope = _queries(c_q, positions, lp, c, cdt)
         with jax.named_scope("mx.gen.attn"):
             page = jnp.take_along_axis(block_tables, chosen // page_size, axis=1)
@@ -848,17 +1129,28 @@ def make_decode_fn(config, slots, max_pages_per_slot, page_size,
                  + jnp.einsum("she,ske->shk", q_rope, k_rope,
                               preferred_element_type=jnp.float32)) * scale
             s = jnp.where(kept[:, None, :], s, -jnp.inf)
-            # an inactive slot keeps nothing: its row is all -inf
-            p = jnp.where(kept[:, None, :], jax.nn.softmax(s, axis=-1), 0.0)
+            if "attn_sink" in lp:
+                # exp(s) / (exp(sink) + sum exp(s)); an inactive slot's row
+                # is all -inf and comes out all 0
+                sink = lp["attn_sink"].astype(jnp.float32)[None, :]     # (1, H)
+                top = jnp.maximum(jnp.max(s, axis=-1), sink)
+                e = jnp.exp(s - top[..., None])
+                p = e / (jnp.sum(e, axis=-1) + jnp.exp(sink - top))[..., None]
+            else:
+                # an inactive slot keeps nothing: its row is all -inf
+                p = jnp.where(kept[:, None, :], jax.nn.softmax(s, axis=-1), 0.0)
             o_lat = jnp.einsum("shk,skr->shr", p.astype(cdt), c_kv,
                                preferred_element_type=jnp.float32).astype(cdt)
             o = jnp.einsum("shr,rhe->she", o_lat, kv_b[..., c.d_nope:],
                            preferred_element_type=jnp.float32)
+            if gate is not None:
+                o = o * gate
         return _output(o, lp, cdt), jnp.sum(kept)
 
     def decode(params, cache, tokens, positions, block_tables, active):
         emb = params["embed_weight"]
         x = jnp.take(emb, jnp.clip(tokens, 0, emb.shape[0] - 1), axis=0).astype(cdt)
+        x = _streams(x, c)
         page = jnp.take_along_axis(block_tables, (positions // page_size)[:, None],
                                    axis=1)[:, 0]
         page = jnp.where(active, page, 0)        # inactive slots write to scratch
@@ -866,35 +1158,48 @@ def make_decode_fn(config, slots, max_pages_per_slot, page_size,
         lengths = jnp.where(active, positions + 1, 0)
         pools = {name: list(layers) for name, layers in cache.items()}
         total = {k: jnp.int32(0) for k in names}
+        selection = None           # the latest full layer's kept ids
         for i in range(c.n_layers):
             lp = _layer(params, i, c)
-            h = _rmsnorm(x, lp["attn_norm"], c.norm_eps).astype(cdt)
-            if c.indexer:
-                c_q, latent, q_i, k_i, w = _project(h, positions, lp, c, cdt)
+
+            def attention(u, i=i, lp=lp):
+                nonlocal selection
+                h = _rmsnorm(u, lp["attn_norm"], c.norm_eps).astype(cdt)
+                f = _index_rank(c, i)      # None: no indexer of its own
+                if f is not None:
+                    c_q, latent, q_i, k_i, w = _project(h, positions, lp, c, cdt)
+                else:
+                    c_q, latent = _latent_project(h, positions, lp, c, cdt)
                 with jax.named_scope("mx.gen.pool_write"):
                     pools["latent"][i] = pools["latent"][i].at[page, offset].set(
                         latent.astype(pools["latent"][i].dtype))
-                    pools["index"][i] = pools["index"][i].at[page, offset].set(
-                        k_i.astype(pools["index"][i].dtype))
-                o, selected = attend(pools, i, c_q, q_i, w, positions, lengths,
-                                     block_tables, lp)
-            else:
-                c_q, latent = _latent_project(h, positions, lp, c, cdt)
-                with jax.named_scope("mx.gen.pool_write"):
-                    pools["latent"][i] = pools["latent"][i].at[page, offset].set(
-                        latent.astype(pools["latent"][i].dtype))
-                q_nope, q_rope = _queries(c_q, positions, lp, c, cdt)
-                o = paged_attention(pools["latent"][i], q_nope, q_rope, lengths,
-                                    block_tables, lp, c, cdt, block_k)
-            x, counts = _ffn(x + o.astype(cdt), lp, c, cdt, active)
-            if c.indexer:
+                    if f is not None:
+                        pools["index"][f] = pools["index"][f].at[page, offset].set(
+                            k_i.astype(pools["index"][f].dtype))
+                if not c.indexer:
+                    q_nope, q_rope = _queries(c_q, positions, lp, c, cdt)
+                    return paged_attention(pools["latent"][i], q_nope, q_rope, lengths,
+                                           block_tables, lp, c, cdt, block_k), None
+                if f is not None:
+                    selection = select(pools, f, q_i, w, lengths, block_tables)
+                gate = _gate(h, lp, c, cdt) if "o_gate_weight" in lp else None
+                return attend(pools, i, c_q, *selection, positions, block_tables, lp,
+                              gate)
+
+            x, selected = _residual(x, attention, lp, "attn", c, cdt)
+            x, counts = _residual(x, lambda u, lp=lp: _ffn(u, lp, c, cdt, active),
+                                  lp, "ffn", c, cdt)
+            if not c.indexer:
+                counts["attn_rows_read"] = jnp.sum(lengths)
+            elif i in full:
                 counts["dsa_keys_scanned"] = jnp.sum(lengths)
                 counts["dsa_keys_selected"] = selected
             else:
-                counts["attn_rows_read"] = jnp.sum(lengths)
+                counts["dsa_keys_selected"] = selected
+                counts[REUSED_DECODE_COUNTER] = jnp.sum(active)
             for k, v in counts.items():
                 total[k] = total[k] + v.astype(jnp.int32)
-        logits = jnp.where(active[:, None], _head(x, params, c, cdt), 0.0)
+        logits = jnp.where(active[:, None], _head(_merged(x, c), params, c, cdt), 0.0)
         counters = jnp.stack([total[k] for k in names])
         return pools, (logits, counters)
 

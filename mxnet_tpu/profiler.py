@@ -82,7 +82,11 @@ kernels' own names (``mx_flash_fwd``, ``mx_flash_dq``, ``mx_flash_dkv``,
 key-value projections), ``mx.gen.index`` (the indexer: its projections, the
 index scores and the top-k), ``mx.lm.moe.route``, ``mx.lm.moe.experts`` and
 ``mx.lm.moe.shared``; its ``mx.gen.attn`` is the sparse absorbed attention
-with the gather of the selected latent rows.  The shortcut-connected double
+with the gather of the selected latent rows (and, where the attention is
+gated or has sinks, the gate's multiply and the sink; the gate's projection
+is under ``mx.gen.latent_proj``), and ``mx.lm.hc`` holds a hyper-connected
+residual path's maps (the norm over the streams, their projection, Sinkhorn,
+the reads and writes of the streams).  The shortcut-connected double
 block (``models/scmoe.py``) has the same names without the indexer and the
 shared expert, ``mx.lm.moe.zero`` (the identity experts' term) and, under
 ``mx.gen.attn``, the kernel ``mx_mla_paged_decode`` over every cached row.
@@ -786,11 +790,12 @@ _GEN_ZERO = {
     # one pair, the pairs there would be were every held expert as full as
     # the fullest (over ``moe_pairs_held`` it rides generate_stats as
     # moe_expert_load_max_over_mean); cached index keys scored and keys
-    # kept for attention; pairs that fell on identity ("zero-compute")
-    # experts, and cached rows a dense latent attention read
+    # kept for attention, and slot-layers that attended an earlier layer's
+    # keys without scoring their own; pairs that fell on identity
+    # ("zero-compute") experts, and cached rows a dense latent attention read
     "moe_pairs_held": 0, "moe_tokens": 0, "moe_experts_touched": 0,
     "moe_pairs_at_max_load": 0,
-    "dsa_keys_scanned": 0, "dsa_keys_selected": 0,
+    "dsa_keys_scanned": 0, "dsa_keys_selected": 0, "dsa_selections_reused": 0,
     "moe_pairs_zero": 0, "attn_rows_read": 0,
 }
 _GEN_FLOATS = ("prefill_seconds", "decode_seconds", "loop_seconds",

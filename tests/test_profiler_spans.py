@@ -522,6 +522,47 @@ def test_double_block_programs_carry_their_scope_names():
         assert scope in text, scope
 
 
+def test_hyper_connected_programs_carry_their_scope_names():
+    """The hyper-connections' maps under ``mx.lm.hc`` in prefill and
+    decode; the gate's product under ``mx.gen.latent_proj``, its multiply and
+    the sink under ``mx.gen.attn``; the indexer's scope only where a layer
+    has one of its own; the reuse counted beside GLM-5's six counters."""
+    import re
+
+    import jax
+    import jax.numpy as jnp
+
+    from mxnet_tpu.models import mla_moe
+
+    cfg = mla_moe.LatentMoEConfig(
+        vocab=32, d_model=32, n_heads=2, n_layers=3, n_dense_layers=1, d_ff=48,
+        d_expert=16, n_experts=8, experts_per_token=2, held_experts=(0, 1),
+        q_rank=16, kv_rank=8, d_nope=4, d_rope=4, d_v=8, index_heads=2, index_dim=8,
+        index_rope_dim=4, index_topk=4, indexer_types=("full", "shared", "shared"),
+        hc_mult=4, attn_gate=True, attn_sink=True, swiglu_limit=10.0, head_fp32=True,
+        max_len=32, dtype="float32")
+    params = mla_moe.init_params(cfg)
+    cache = mla_moe.init_kv_cache(cfg, num_pages=8, page_size=4)
+    decode = jax.jit(mla_moe.make_decode_fn(cfg, 2, 4, 4))
+    prefill = jax.jit(mla_moe.make_prefill_fn(cfg, 4))
+    texts = [_op_names(decode.lower(
+        params, cache, jnp.zeros((2,), jnp.int32), jnp.zeros((2,), jnp.int32),
+        jnp.zeros((2, 4), jnp.int32), jnp.ones((2,), bool))), _op_names(prefill.lower(
+            params, cache, jnp.zeros((1, 8), jnp.int32), jnp.int32(5),
+            jnp.zeros((2,), jnp.int32)))]
+    for text in texts:
+        for scope in ("mx.lm.hc", "mx.gen.latent_proj", "mx.gen.index", "mx.gen.attn",
+                      "mx.gen.pool_write", "mx.lm.moe.experts", "mx.lm.ffn"):
+            assert scope in text, scope
+        # the sink's exp and the gate's multiply under attention, the gate's
+        # sigmoid beside its product
+        assert re.search(r"mx\.gen\.latent_proj/logistic", text)
+    assert mla_moe.decode_counters(cfg) == mla_moe.INDEXED_DECODE_COUNTERS + (
+        "dsa_selections_reused",)
+    profiler.generate_record(dsa_selections_reused=4)
+    assert profiler.generate_stats(reset=True)["dsa_selections_reused"] == 4
+
+
 def test_double_block_counters_ride_generate_stats():
     from mxnet_tpu.models import scmoe
 

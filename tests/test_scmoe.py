@@ -261,34 +261,47 @@ def test_a_lower_precision_breaks_the_tolerance(control):
 
 # -- the pieces it borrows still lower as before ------------------------------
 # sha256 of the StableHLO text of the tiny GLM-5-shaped prefill and decode of
-# ``models/mla_moe.py``, taken at the commit before this model came (3f00fb6).
+# ``models/mla_moe.py``, taken at the commit before this model came (3f00fb6),
+# and of the tiny Sarvam-105B-shaped ones (a full-rank query, q/k norm, YaRN,
+# no indexer; dense decode in the blocked form), taken at the commit before
+# hyper-connections, shared index selections, gates and sinks came (62dccb2).
 # A PR that means to change those programs says so and replaces the digests:
 #   python -c "import tests.test_scmoe as t; print(t.latent_moe_digests())"
 LATENT_MOE_PROGRAMS = {
     "prefill": "cd5754b0df3b55127f537906b21a68cdea34051d8b371627f4c48cece4f8bc98",
     "decode": "f61a87396735936225b8c4fcc0619c6729874a5e7496b0175cb9df370d29142a",
+    "sarvam_prefill": "eb068fc4830ccad17ba57faf273bbec7c4b8ad474356083dcadfbe26f2a36197",
+    "sarvam_decode": "538afcf4e51b8a4bfd7b160d428ce6c84a6656b98e4c9736193441905d1b6db2",
 }
 
 
 def latent_moe_digests():
-    cfg = mm.LatentMoEConfig(
-        vocab=64, d_model=64, n_heads=4, n_layers=2, n_dense_layers=1, d_ff=96,
-        d_expert=32, n_experts=32, experts_per_token=4, held_experts=(0, 1),
-        q_rank=32, kv_rank=16, d_nope=8, d_rope=8, d_v=16, index_heads=4, index_dim=16,
-        index_rope_dim=8, index_topk=8, rope_theta=1e4, max_len=128, dtype="bfloat16")
-    params = {k: jax.ShapeDtypeStruct(s, jnp.bfloat16)
-              for k, (s, _kind) in mm.param_shapes(cfg).items()}
-    cache = jax.eval_shape(lambda: mm.init_kv_cache(cfg, 20, 4))
-    i32 = lambda *s: jax.ShapeDtypeStruct(s, jnp.int32)  # noqa: E731
-    lowered = {
-        "prefill": jax.jit(mm.make_prefill_fn(cfg, 4)).lower(
-            params, cache, i32(1, 32), i32(), i32(8)),
-        "decode": jax.jit(mm.make_decode_fn(cfg, 2, 16, 4)).lower(
-            params, cache, i32(2), i32(2), i32(2, 16),
-            jax.ShapeDtypeStruct((2,), jnp.bool_)),
+    tiny = dict(vocab=64, d_model=64, n_heads=4, n_layers=2, n_dense_layers=1, d_ff=96,
+                d_expert=32, n_experts=32, experts_per_token=4, held_experts=(0, 1),
+                kv_rank=16, d_nope=8, d_rope=8, d_v=16, rope_theta=1e4, max_len=128,
+                dtype="bfloat16")
+    configs = {
+        "": (mm.LatentMoEConfig(q_rank=32, index_heads=4, index_dim=16, index_rope_dim=8,
+                                index_topk=8, **tiny), None),
+        "sarvam_": (mm.LatentMoEConfig(q_rank=0, indexer=False, qk_norm=True,
+                                       yarn_factor=40.0, yarn_original=4096, **tiny), 8),
     }
-    return {k: hashlib.sha256(v.as_text().encode()).hexdigest()
-            for k, v in lowered.items()}
+    i32 = lambda *s: jax.ShapeDtypeStruct(s, jnp.int32)  # noqa: E731
+    out = {}
+    for name, (cfg, block_k) in configs.items():
+        params = {k: jax.ShapeDtypeStruct(s, jnp.bfloat16)
+                  for k, (s, _kind) in mm.param_shapes(cfg).items()}
+        cache = jax.eval_shape(lambda cfg=cfg: mm.init_kv_cache(cfg, 20, 4))
+        lowered = {
+            "prefill": jax.jit(mm.make_prefill_fn(cfg, 4)).lower(
+                params, cache, i32(1, 32), i32(), i32(8)),
+            "decode": jax.jit(mm.make_decode_fn(cfg, 2, 16, 4, block_k=block_k)).lower(
+                params, cache, i32(2), i32(2), i32(2, 16),
+                jax.ShapeDtypeStruct((2,), jnp.bool_)),
+        }
+        out.update({name + k: hashlib.sha256(v.as_text().encode()).hexdigest()
+                    for k, v in lowered.items()})
+    return out
 
 
 _DIGESTS = {}
