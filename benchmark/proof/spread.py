@@ -27,7 +27,7 @@ def main(paths):
             v = [r["metrics"][n]["value"] for r in rows if n in r["metrics"]]
             if n == "setup_s":
                 v = v[1:]                       # the first run compiles
-            if len(v) >= 2:
+            if len(v) >= 2 and statistics.median(v) != 0:
                 print("  %-24s median %.6g  spread %.3f %%  min %.6g max %.6g  n=%d"
                       % (n, statistics.median(v), 100 * spread(v), min(v), max(v), len(v)))
         for n in sorted({k for r in rows for k in r.get("compared", {})}):

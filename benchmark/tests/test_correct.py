@@ -86,17 +86,24 @@ def fit_fault(monkeypatch, fault):
                         {"state_unchanged": unchanged, "half_batch": half}[fault])
 
 
-def chat_fault(monkeypatch, fault):
+def alter_read_tokens(monkeypatch):
+    """Every id the host reads from a decode step moved one along, where the
+    broker's loop takes it (``read_step``); the device's own copy, which the
+    next step feeds on, stays."""
     from mxnet_tpu.serving import generate
 
-    decode = generate.GenerativePredictor.decode
+    read = generate.GenerativePredictor.read_step
 
-    def altered(self, *args, **kwargs):
-        # the token is the argmax of these logits: move it one id along
-        return np.roll(decode(self, *args, **kwargs), 1, axis=-1)
+    def altered(self, chosen):
+        ids, counters = read(self, chosen)
+        return (ids + 1) % self.config.vocab, counters
 
+    monkeypatch.setattr(generate.GenerativePredictor, "read_step", altered)
+
+
+def chat_fault(monkeypatch, fault):
     assert fault == "token_altered"
-    monkeypatch.setattr(generate.GenerativePredictor, "decode", altered)
+    alter_read_tokens(monkeypatch)
 
 
 FAULTS = [(LM, lm_fault, "state_unchanged"), (LM, lm_fault, "half_batch"),

@@ -8,7 +8,6 @@ contract as the others do."""
 import json
 import os
 
-import numpy as np
 import pytest
 
 from benchmark import harness
@@ -40,11 +39,9 @@ def test_a_stream_that_ends_inside_the_window_counts_as_failed(capsys):
 
 
 def token_altered(monkeypatch):
-    from mxnet_tpu.serving import generate
+    from benchmark.tests.test_correct import alter_read_tokens
 
-    decode = generate.GenerativePredictor.decode
-    monkeypatch.setattr(generate.GenerativePredictor, "decode",
-                        lambda self, *a, **k: np.roll(decode(self, *a, **k), 1, axis=-1))
+    alter_read_tokens(monkeypatch)
 
 
 def selection_dropped(monkeypatch):

@@ -12,7 +12,7 @@ RECORDED = os.path.join(os.path.dirname(__file__), "data", "span_trace.xplane.pb
 PROGRAM = {"d_model": 8, "d_ff": 16, "n_layers": 2, "vocab": 100}
 PEAKS = {"bf16_flops_per_s": 1e6, "hbm_bytes_per_s": 1e5}
 FLOPS = {"match": "^jit_decode$", "of": "flops"}
-BYTES = {"match": "^jit_decode$", "of": "bytes", "bytes_per_param": 4}
+BYTES = {"match": "^jit_decode$", "of": "bytes", "bytes_per_param": 2}
 
 
 def context(modules, work, chips=1):
@@ -31,8 +31,11 @@ def test_both_shares_by_hand():
     ctx = context(modules, {"decode_steps": 10, "active_slot_steps": 25})
     # 2 * 1824 * 25 = 91200 operations: 0.0912 s at the peak, of 0.5 s
     assert decode_step_share.read(ctx, FLOPS) == pytest.approx(18.24)
-    # 4 * 1824 * 10 = 72960 bytes: 0.7296 s at the peak, of 0.5 s: over 100,
-    # and not clipped
+    # 2 * 1824 * 10 = 36480 bytes: 0.3648 s at the peak, of 0.5 s
+    assert decode_step_share.read(ctx, BYTES) == pytest.approx(72.96)
+    # of 0.25 s: over 100, and not clipped
+    fast = dict(modules, jit_decode={"seconds": 0.25, "count": 10})
+    ctx = context(fast, {"decode_steps": 10, "active_slot_steps": 25})
     assert decode_step_share.read(ctx, BYTES) == pytest.approx(145.92)
     # four chips hold four times the peak
     ctx = context(modules, {"decode_steps": 10, "active_slot_steps": 25}, chips=4)
@@ -62,6 +65,12 @@ def test_the_metric_files_name_the_reader_and_what_it_needs():
         spec = harness.load_json(os.path.join(harness.HERE, "metrics", name + ".json"))
         assert spec["reader"] == "decode_step_share" and spec["args"]["of"] == of
         assert spec["args"]["match"] == "^jit_decode$"
+    # the matrices at the width the step computes in (bfloat16), as the pool
+    # cells' readers count them: a step that reads no float32 copy stays
+    # under the memory's peak
+    spec = harness.load_json(os.path.join(harness.HERE, "metrics",
+                                          "serve_decode_step_hbm_roofline.json"))
+    assert spec["args"] == BYTES
     spec = harness.load_json(os.path.join(harness.HERE, "metrics",
                                           "serve_decode_kv_read_share.json"))
     assert spec["reader"] == "program_stat"

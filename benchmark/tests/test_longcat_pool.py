@@ -122,16 +122,21 @@ def test_every_reader_returns_nothing_where_there_is_nothing_to_read(path):
 
 def test_new_metrics_are_appended_and_list_the_new_cell_alone():
     bench = contract.load("BENCHMARK.json")
-    mine = [m for m in bench["per_layer"] if m["name"] in NEW]
-    assert [m["name"] for m in mine] == list(NEW)
-    assert bench["per_layer"][-len(NEW):] == mine          # appended, nothing between
-    for m in mine:
-        assert m["workloads"] == [longcat_pool.CELL] and m["moves"] == "serve_itl_p95_ms"
+    names = [m["name"] for m in bench["per_layer"]]
+    at = [names.index(n) for n in NEW]
+    assert at == list(range(at[0], at[0] + len(NEW)))    # together, in this order
+    assert at[0] > names.index("dsa_selected_share")     # after what was there
+    for name in NEW:
+        m = bench["per_layer"][names.index(name)]
+        # the cell's own, or shared with a later cell that lists itself after it
+        assert m["workloads"][0] == longcat_pool.CELL and m["moves"] == "serve_itl_p95_ms"
     for m in bench["end_to_end"] + bench["per_layer"]:
         if longcat_pool.CELL in m.get("workloads", []) and m["name"] not in NEW:
-            assert m["workloads"][-1] == longcat_pool.CELL
-    assert bench["workloads"][-1]["name"] == longcat_pool.CELL
-    assert bench["workloads"][-1]["chips"] == 1
+            assert m["workloads"].index(longcat_pool.CELL) > m["workloads"].index(
+                "glm-5.decode-pool-16k"), m["name"]
+    cells = [w["name"] for w in bench["workloads"]]
+    assert cells.index(longcat_pool.CELL) > cells.index("glm-5.decode-pool-16k")
+    assert bench["workloads"][cells.index(longcat_pool.CELL)]["chips"] == 1
 
 
 @pytest.fixture(scope="module")

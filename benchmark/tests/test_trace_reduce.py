@@ -62,6 +62,24 @@ def test_readers_read_the_summary_and_return_nothing_without_one(summary):
     assert device_idle_share.read({"trace": None}, {}) is None
 
 
+def test_a_trace_without_a_device_plane_reads_as_an_idle_chip(summary):
+    """A traced segment in which no operation ran leaves no device plane (a
+    serving window whose traced seconds hold no request): the recorded trace
+    with its device planes taken out."""
+    from types import SimpleNamespace
+
+    data = trace_reduce._load(TRACE)
+    host = SimpleNamespace(planes=[p for p in data.planes
+                                   if not p.name.startswith("/device:")])
+    idle = trace_reduce.reduce(TRACE, chips=1, data=host)
+    assert idle["segment_found"] and idle["window_s"] == summary["window_s"]
+    assert idle["busy_s"] == 0
+    assert idle["ops"] == idle["modules"] == {} and idle["top_ops"] == []
+    assert sum(s for _label, s in idle["top_gaps"]) == pytest.approx(idle["window_s"])
+    assert device_idle_share.read({"trace": idle}, {}) == 100.0
+    assert module_ms_per_run.read({"trace": idle}, {"match": "^jit_f$"}) is None
+
+
 def test_self_times_take_children_off_their_parent():
     events = [(0, 100, "while", None), (10, 30, "a", None), (40, 90, "b", None),
               (50, 60, "c", None)]

@@ -13,7 +13,8 @@ events):
 
 - ``window_s``   the segment's length,
 - ``busy_s``     seconds in which an operation ran on the device: the union of
-  the ``XLA Ops`` intervals, averaged over the chips used,
+  the ``XLA Ops`` intervals, averaged over the chips used (0 where the trace
+  has no device plane: no operation ran in it, and the chip was idle),
 - ``ops``        {name: {"seconds": self time, "count": n}} by operation, self
   time being an event's duration less what its nested events cover, summed
   over chips and divided by their number,
@@ -109,19 +110,20 @@ def host_spans(data, prefix="bench_"):
 def reduce(path, chips=1, data=None):
     data = data if data is not None else _load(path)
     planes = _device_planes(data)[:chips]
-    if not planes:
-        raise RuntimeError("trace %s has no /device:TPU plane" % path)
     spans = host_spans(data)
     seg = [s for s in spans if s[2] == SEGMENT]
     per_plane = []
     for p in planes:
         lines = [ln for ln in p.lines if ln.name == OPS_LINE]
         per_plane.append(_events(lines[0]) if lines else [])
+    # a segment in which no operation ran leaves no device plane: one idle chip
+    per_plane = per_plane or [[]]
+    n_planes = len(per_plane)
     if seg:
         lo, hi = seg[0][0], seg[0][1]
     else:
         every = [t for ev in per_plane for t in ev]
-        lo, hi = min(t[0] for t in every), max(t[1] for t in every)
+        lo, hi = (min(t[0] for t in every), max(t[1] for t in every)) if every else (0, 0)
 
     busy_ns, ops, gaps, modules = 0, {}, {}, {}
     for p in planes:
@@ -132,7 +134,7 @@ def reduce(path, chips=1, data=None):
                 if b <= lo or a >= hi:
                     continue
                 rec = modules.setdefault(name.split("(")[0], {"seconds": 0.0, "count": 0})
-                rec["seconds"] += (min(b, hi) - max(a, lo)) / 1e9 / len(planes)
+                rec["seconds"] += (min(b, hi) - max(a, lo)) / 1e9 / n_planes
                 rec["count"] += 1
     inner = sorted((s for s in spans if s[2] != SEGMENT), key=lambda s: s[1] - s[0])
     for events in per_plane:
@@ -142,7 +144,7 @@ def reduce(path, chips=1, data=None):
         busy_ns += sum(b - a for a, b in merged)
         for name, ns in _self_times(clipped):
             rec = ops.setdefault(name, {"seconds": 0.0, "count": 0})
-            rec["seconds"] += ns / 1e9 / len(planes)
+            rec["seconds"] += ns / 1e9 / n_planes
             rec["count"] += 1
         edges = [lo] + [x for ab in merged for x in ab] + [hi]
         for a, b in zip(edges[0::2], edges[1::2]):
@@ -153,11 +155,11 @@ def reduce(path, chips=1, data=None):
                 if s[0] <= a < s[1]:
                     label = s[2]
                     break
-            gaps[label] = gaps.get(label, 0.0) + (b - a) / 1e9 / len(planes)
+            gaps[label] = gaps.get(label, 0.0) + (b - a) / 1e9 / n_planes
     top_ops = sorted(([short_name(n), r["seconds"]] for n, r in ops.items()),
                      key=lambda t: -t[1])[:10]
     top_gaps = sorted(([n, s] for n, s in gaps.items()), key=lambda t: -t[1])[:10]
-    return {"window_s": (hi - lo) / 1e9, "busy_s": busy_ns / 1e9 / len(planes),
+    return {"window_s": (hi - lo) / 1e9, "busy_s": busy_ns / 1e9 / n_planes,
             "ops": ops, "modules": modules, "top_ops": top_ops, "top_gaps": top_gaps,
             "segment_found": bool(seg)}
 

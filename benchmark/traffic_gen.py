@@ -16,7 +16,11 @@ Kinds of mix (the ``generator`` key of a traffic file):
   distribution (a Poisson process's gaps), prompt and answer lengths the
   quantiles of clipped log-normals (``median``, ``sigma``, ``min``, ``max``),
   so every seed has the same set of gaps and lengths; the seed puts each of
-  the three in an order of its own and draws the prompts' token ids.
+  the three in an order of its own and draws the prompts' token ids.  A mix
+  that gives ``schedule_seed`` takes the three orders from it instead: every
+  seed then sends the same requests at the same times, so as many streams
+  overlap and every step costs the same in every run, and the seed draws
+  only the token ids (and the runner's weights).
 """
 import statistics
 
@@ -53,9 +57,11 @@ def open_loop_requests(mix, vocab, seed, seconds):
     prompts = _lognormal_quantiles(mix["prompt_tokens"], q)
     answers = _lognormal_quantiles(mix["answer_tokens"], q)
     rng = np.random.default_rng([int(seed), 4])
-    gaps = rng.permutation(gaps)
+    order = rng if "schedule_seed" not in mix else \
+        np.random.default_rng([int(mix["schedule_seed"]), 4])
+    gaps = order.permutation(gaps)
     due = np.cumsum(gaps) - gaps[0]            # the first is due as the window opens
-    prompts, answers = rng.permutation(prompts), rng.permutation(answers)
+    prompts, answers = order.permutation(prompts), order.permutation(answers)
     ids = rng.integers(0, int(vocab), int(prompts.sum()), dtype=np.int32)
     cuts = np.concatenate([[0], np.cumsum(prompts)])
     return [{"due_s": float(due[i]), "prompt": ids[cuts[i]:cuts[i + 1]],
