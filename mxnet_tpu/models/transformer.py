@@ -48,7 +48,8 @@ __all__ = ["TransformerConfig", "init_params", "param_specs", "make_loss_fn",
            "make_train_step", "make_forward_fn", "init_kv_cache",
            "make_prefill_fn", "make_decode_fn", "make_extend_fn",
            "draft_from_layers", "decode_schedule_shape",
-           "block_collective_counts", "kv_cache_spec", "kv_page_bytes"]
+           "block_collective_counts", "kv_cache_spec", "kv_page_bytes",
+           "compute_dtype_leaves"]
 
 
 def _mp_axis(axes):
@@ -302,6 +303,18 @@ def _ffn(x, lp, c, axes, cdt):
 # router runs in float32) stay float32.
 _SCAN_CAST = frozenset(("attn_qkv_weight", "attn_out_weight",
                         "ffn_up_weight", "ffn_down_weight"))
+
+
+def compute_dtype_leaves(config):
+    """The parameter leaves that every program of this module reads only in
+    the compute dtype: ``_SCAN_CAST``, through ``.astype(cdt)``, which is the
+    identity on a leaf already in it. A holder that never trains them
+    (``serving.generate.GenerativePredictor``) casts them once when it binds
+    them, so that no prefill or decode run casts them again. The embedding
+    (its lookup adds float32 position rows before the cast), the LayerNorms
+    and ``moe_gate_weight`` are read in float32 and are not among them."""
+    del config  # the same four leaves in every configuration
+    return _SCAN_CAST
 
 
 def _forward_local(params, tokens, c, axes):

@@ -368,9 +368,11 @@ def _model_module(config_):
     module offers ``init_kv_cache`` (any pytree of page pools under one
     block table), ``kv_page_bytes``, ``make_prefill_fn`` and
     ``make_decode_fn``; optionally ``make_extend_fn`` (prefix tails,
-    speculation) and ``_decode_block_k``; and ``decode_counters(config)``,
-    the names of what its decode program counts on the device and returns
-    beside the logits (none for the transformer)."""
+    speculation), ``_decode_block_k`` and ``compute_dtype_leaves(config)``
+    (the leaves its programs read only in the compute dtype); and
+    ``decode_counters(config)``, the names of what its decode program
+    counts on the device and returns beside the logits (none for the
+    transformer)."""
     name = getattr(config_, "module", None)
     if name is None:
         from ..models import transformer
@@ -437,7 +439,16 @@ class GenerativePredictor:
         ``init_params``-layout arrays (numpy or jax); frozen onto the
         device once.  A ``jax.Array`` is bound as it is given, in its
         own dtype and without a copy through the host (bfloat16 weights
-        stay bfloat16); anything else is copied to the device.
+        stay bfloat16); anything else is copied to the device.  The
+        leaves the model module names in ``compute_dtype_leaves(config)``
+        (the transformer's four stacked matrices), which its programs
+        read only through a cast to ``config.dtype``, are cast to it on
+        the device as they are bound, one leaf at a time, where they are
+        wider than it: the programs then read the same values without
+        casting every stacked matrix on every run, and no wider copy is
+        kept beside them (nothing here trains them).  Every other leaf
+        keeps its dtype.  :meth:`pool_stats` reports what the bind held
+        (``param_bytes``) and cast (``params_cast``).
     slots : int, optional
         Batch-slot count of the decode program
         (``MXNET_GENERATE_SLOTS``).
@@ -539,6 +550,17 @@ class GenerativePredictor:
             else "gen-%d" % id(self)
         self._dtype_name = str(cdt)
 
+        narrow = getattr(tfm, "compute_dtype_leaves", lambda _c: ())(c)
+        cast = []
+
+        def bind(k, a):
+            # on the device, so a float32 leaf and its cast are held
+            # together for one leaf at a time
+            if k in narrow and a.dtype.itemsize > cdt.itemsize:
+                cast.append(k)
+                return a.astype(cdt)
+            return a
+
         if mesh is not None:
             from jax.sharding import NamedSharding
             from jax.sharding import PartitionSpec as P
@@ -550,7 +572,7 @@ class GenerativePredictor:
                     jnp.asarray(np.asarray(a)),
                     NamedSharding(mesh, spec if spec is not None else P()))
 
-            self._params = {k: put(v, pspecs.get(k))
+            self._params = {k: bind(k, put(v, pspecs.get(k)))
                             for k, v in params.items()}
             self._kv = put(tfm.init_kv_cache(c, num_pages, self.page_size),
                            tfm.kv_cache_spec(mesh))
@@ -560,9 +582,12 @@ class GenerativePredictor:
                     a = jnp.asarray(np.asarray(a))
                 return jax.device_put(a, device) if device is not None else a
 
-            self._params = {k: put(v) for k, v in params.items()}
+            self._params = {k: bind(k, put(v)) for k, v in params.items()}
             self._kv = jax.tree.map(
                 put, tfm.init_kv_cache(c, num_pages, self.page_size))
+        self._bind_stats = {
+            "param_bytes": sum(int(a.nbytes) for a in self._params.values()),
+            "params_cast": len(cast)}
         if block_k is None:
             pick = getattr(tfm, "_decode_block_k", None)
             block_k = pick(c, self.slots, self.max_ctx) if pick else 0
@@ -782,7 +807,10 @@ class GenerativePredictor:
         return logits[0, n - 1]
 
     def pool_stats(self):
-        return self.pool.stats()
+        """The page pool's counters, and what the bind holds:
+        ``param_bytes`` (every bound leaf, one copy of each) and
+        ``params_cast`` (the leaves cast to the compute dtype)."""
+        return dict(self.pool.stats(), **self._bind_stats)
 
     def sharded_stats(self):
         """Measured per-chip bytes of the sharded bind (ISSUE 20):

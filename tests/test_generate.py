@@ -538,6 +538,102 @@ def test_pool_bytes_knob_sizes_the_pool(model, monkeypatch):
 
 
 # ---------------------------------------------------------------------------
+# the bind casts the leaves the programs read only in the compute dtype
+# ---------------------------------------------------------------------------
+_STACKS = ("attn_qkv_weight", "attn_out_weight", "ffn_up_weight",
+           "ffn_down_weight")
+
+
+def _bound_dtypes(cfg, params):
+    narrow = jnp.dtype(cfg.dtype) != jnp.float32
+    return {k: jnp.dtype(cfg.dtype) if narrow and k in _STACKS
+            else v.dtype for k, v in params.items()}
+
+
+@pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
+def test_bind_casts_the_stacked_matrices_once(dtype):
+    """At a bfloat16 compute dtype the four stacked matrices are bound in
+    bfloat16, every other leaf as it was handed, and prefill and decode give
+    the bits the module's own programs give on the float32 parameters. At
+    float32 nothing is cast: every handed array is bound as it is."""
+    import jax
+
+    cfg = _cfg(dtype=dtype)
+    params = tfm.init_params(cfg, seed=4)
+    pred = GenerativePredictor(cfg, params, slots=2, page_size=4, max_ctx=32)
+    dtypes = _bound_dtypes(cfg, params)
+    for k, v in params.items():
+        assert pred._params[k].dtype == dtypes[k], k
+        if dtypes[k] == v.dtype:
+            assert pred._params[k] is v, k
+    stats = pred.pool_stats()
+    assert stats["params_cast"] == sum(dtypes[k] != v.dtype
+                                       for k, v in params.items())
+    assert stats["params_cast"] == (0 if dtype == "float32" else 4)
+    assert stats["param_bytes"] == sum(v.size * dtypes[k].itemsize
+                                       for k, v in params.items())
+    assert stats["in_use"] == 0            # beside the pool's own counters
+
+    n, steps, page = 9, 8, pred.page_size
+    prompt = _prompt(n, 5)
+    pages = pred.pool.alloc(pred.pages_needed(n + steps))
+    first = pred.pages_needed(n)
+    bucket = pred.pick_bucket(n)
+    padded = np.zeros((1, bucket), np.int32)
+    padded[0, :n] = prompt
+    page_arr = np.zeros((bucket // page,), np.int32)
+    page_arr[:first] = pages[:first]
+    cache = tfm.init_kv_cache(cfg, pred.pool.num_pages, page)
+    cache, want = jax.jit(tfm.make_prefill_fn(cfg, page))(
+        params, cache, padded, np.int32(n), page_arr)
+    got = pred.prefill(prompt, pages[:first])
+    np.testing.assert_array_equal(got, np.asarray(want))
+
+    decode = jax.jit(tfm.make_decode_fn(cfg, 2, pred.max_pages_per_slot,
+                                        page, block_k=pred.block_k))
+    table = np.zeros((2, pred.max_pages_per_slot), np.int32)
+    table[1, :len(pages)] = pages
+    active = np.array([False, True])
+    token = int(np.argmax(got))
+    for i in range(steps):
+        tokens = np.array([0, token], np.int32)
+        positions = np.array([0, n + i], np.int32)
+        cache, want = decode(params, cache, tokens, positions, table, active)
+        got = pred.decode(tokens, positions, table, active)
+        np.testing.assert_array_equal(got, np.asarray(want),
+                                      err_msg="step %d" % i)
+        token = int(np.argmax(got[1]))
+
+
+@pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
+def test_mesh_bind_casts_the_stacked_matrices_too(dtype):
+    """The sharded bind casts the same leaves to the same bits as the
+    one-device bind, each leaf placed as ``param_specs`` shards it."""
+    from jax.sharding import NamedSharding
+    from jax.sharding import PartitionSpec as P
+
+    from mxnet_tpu.parallel.mesh import train_mesh
+
+    cfg = _cfg(dtype=dtype)
+    params = tfm.init_params(cfg, seed=4)
+    mesh = train_mesh(mp=2)
+    specs = tfm.param_specs(cfg, mesh)
+    gp = GenerativePredictor(cfg, params, slots=2, page_size=4, max_ctx=32,
+                             mesh=mesh)
+    gr = GenerativePredictor(cfg, params, slots=2, page_size=4, max_ctx=32)
+    dtypes = _bound_dtypes(cfg, params)
+    for k, a in gp._params.items():
+        assert a.dtype == dtypes[k], k
+        assert a.sharding.is_equivalent_to(
+            NamedSharding(mesh, specs.get(k, P())), a.ndim), k
+        np.testing.assert_array_equal(np.asarray(a), np.asarray(gr._params[k]))
+    got, want = gp.pool_stats(), gr.pool_stats()
+    assert got["params_cast"] == want["params_cast"] == (
+        0 if dtype == "float32" else 4)
+    assert got["param_bytes"] == want["param_bytes"]
+
+
+# ---------------------------------------------------------------------------
 # ISSUE 39: the plain decode loop keeps one step in flight. The token is
 # chosen inside the decode program and fed to the next step on the device;
 # the host reads a step's ids while the next one runs. Every served chain is

@@ -64,34 +64,44 @@ def test_param_shapes_are_init_params_own():
                                   tfm.init_params(cfg).items()}
 
 
-def test_opt_1_3b_decode_holds_one_pool_and_reads_it_in_place(one_chip, on_tpu):
-    # benchmark/configs/opt-1.3b.json and benchmark/traffic/serve-chat.json
-    cfg = tfm.TransformerConfig(vocab=50272, d_model=2048, n_heads=32,
-                                n_layers=24, d_ff=8192, max_len=2048,
-                                dtype="bfloat16")
+# benchmark/configs/opt-1.3b.json and benchmark/traffic/serve-chat.json
+_OPT = tfm.TransformerConfig(vocab=50272, d_model=2048, n_heads=32,
+                             n_layers=24, d_ff=8192, max_len=2048,
+                             dtype="bfloat16")
+
+
+def _opt_decode_compiled(one_chip, narrow=()):
+    """OPT's served decode step compiled for the described chip, the leaves
+    in ``narrow`` handed in bfloat16 and every other in float32; and its
+    pool."""
     slots, page, per_slot = 16, 16, 1216 // 16
 
-    def described(x):
-        return jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=one_chip)
+    def described(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
 
-    params = {k: jax.ShapeDtypeStruct(v, jnp.float32, sharding=one_chip)
-              for k, v in _param_shapes(cfg).items()}
-    cache = described(jax.eval_shape(
-        lambda: tfm.init_kv_cache(cfg, slots * per_slot, page)))
-    pool_bytes = 2 * math.prod(cache.shape)
-    fn = jax.jit(tfm.make_decode_fn(cfg, slots, per_slot, page),
+    params = {k: described(v, jnp.bfloat16 if k in narrow else jnp.float32)
+              for k, v in _param_shapes(_OPT).items()}
+    cache = jax.eval_shape(
+        lambda: tfm.init_kv_cache(_OPT, slots * per_slot, page))
+    cache = described(cache.shape, cache.dtype)
+    fn = jax.jit(tfm.make_decode_fn(_OPT, slots, per_slot, page),
                  donate_argnums=(1,))
     compiled = fn.lower(
-        params, cache,
-        jax.ShapeDtypeStruct((slots,), jnp.int32, sharding=one_chip),
-        jax.ShapeDtypeStruct((slots,), jnp.int32, sharding=one_chip),
-        jax.ShapeDtypeStruct((slots, per_slot), jnp.int32, sharding=one_chip),
-        jax.ShapeDtypeStruct((slots,), jnp.bool_, sharding=one_chip)).compile()
+        params, cache, described((slots,), jnp.int32),
+        described((slots,), jnp.int32), described((slots, per_slot), jnp.int32),
+        described((slots,), jnp.bool_)).compile()
+    return compiled, cache
+
+
+def test_opt_1_3b_decode_holds_one_pool_and_reads_it_in_place(one_chip, on_tpu):
+    compiled, cache = _opt_decode_compiled(one_chip)
+    pool_bytes = 2 * math.prod(cache.shape)
     mem = compiled.memory_analysis()
     text = compiled.as_text()
     assert "tpu_custom_call" in text and "mx_paged_decode" in text
     # the donated pool comes back as the output, and the temporaries (the
-    # matrices cast to bfloat16, 2.42 GB) hold no second pool
+    # float32 weight stacks cast to bfloat16 before the layer loop, 2.42 GB,
+    # where the parameters come in float32) hold no second pool
     assert mem.alias_size_in_bytes >= pool_bytes
     assert mem.temp_size_in_bytes < pool_bytes
     # the pool keeps its own order on the device (a page is contiguous) ...
@@ -103,6 +113,24 @@ def test_opt_1_3b_decode_holds_one_pool_and_reads_it_in_place(one_chip, on_tpu):
                          % shape, text)
     # nor one of the gathered keys' and values' size
     assert "bf16[16,32,1216,64]" not in text
+
+
+@pytest.mark.parametrize("bound", ["float32", "as_served"])
+def test_opt_1_3b_decode_casts_no_weight_stack_as_served(bound, one_chip,
+                                                         on_tpu):
+    """OPT's decode step at the dtypes ``GenerativePredictor`` binds: the
+    leaves of ``compute_dtype_leaves`` come in bfloat16, and the step casts
+    no stack of 24 layers and holds no copy of one among its temporaries.
+    Handed float32 (the control), it casts all four, 2.42 GB of them."""
+    narrow = tfm.compute_dtype_leaves(_OPT) if bound == "as_served" else ()
+    compiled, _cache = _opt_decode_compiled(one_chip, narrow)
+    text = compiled.as_text()
+    stack_casts = re.findall(r"= bf16\[24,[\d,]*\]\S* convert\(", text)
+    temp = compiled.memory_analysis().temp_size_in_bytes
+    if narrow:
+        assert stack_casts == [] and temp < 0.2e9
+    else:
+        assert len(stack_casts) == 4 and temp > 2.4e9
 
 
 def test_lm_cell_attention_feeds_three_flash_kernels_head_dim_64(one_chip,
