@@ -66,9 +66,11 @@ and :func:`_residual` puts it on the residual path):
 
 Entry points are those ``GenerativePredictor`` asks a model module for:
 ``init_kv_cache``, ``kv_page_bytes``, ``make_prefill_fn``,
-``make_decode_fn``, ``decode_counters`` and ``_decode_block_k``;
+``make_decode_fn``, ``decode_counters``, ``served_leaves`` and
+``_decode_block_k``;
 ``make_forward_fn`` is the cache-free one-shot forward the tests hold both
-against.
+against.  Prefill and decode read a leaf of ``served_leaves`` in either
+form, as drawn or as bound, whichever the parameters hold.
 """
 from __future__ import annotations
 
@@ -82,8 +84,8 @@ from jax import lax
 
 from ..context import kernel_platform
 
-__all__ = ["LatentMoEConfig", "init_params", "init_kv_cache", "kv_page_bytes",
-           "make_prefill_fn", "make_decode_fn", "make_forward_fn",
+__all__ = ["LatentMoEConfig", "init_params", "served_leaves", "init_kv_cache",
+           "kv_page_bytes", "make_prefill_fn", "make_decode_fn", "make_forward_fn",
            "decode_counters", "paged_attention", "blocked_attention"]
 
 # what one decode step counts on the device, summed over its layers and
@@ -237,6 +239,26 @@ def param_shapes(config):
     if c.attn_sink:
         out["attn_sink"] = ((L, H), "sink")
     return out
+
+
+# leaf -> the name it is served under, (layers, heads, width, rank): the
+# rank axis minor-most
+_RANK_MINOR = {"q_b_weight": "q_b_weight_t", "index_q_weight": "index_q_weight_t",
+               "kv_b_weight": "kv_b_weight_t"}
+
+
+def served_leaves(config):
+    """name -> (name bound, axes): the leaves the predictor binds transposed
+    by ``axes`` under another name (:func:`param_shapes` stays what a
+    checkpoint holds).  Each layer's ``q_b_weight``, ``index_q_weight`` and
+    ``kv_b_weight`` is then a head-major stack of (width, rank) matrices
+    that a decode product reads where it lies.  As drawn, heads and widths
+    fill a TPU tile and the rank axis lies outside it: a product that
+    contracts the rank axis must re-lay the matrix first, and XLA does that
+    to a layer's slice of a stack only after copying the slice out (every
+    layer, every step)."""
+    shapes = param_shapes(config)
+    return {k: (t, (0, 2, 3, 1)) for k, t in _RANK_MINOR.items() if k in shapes}
 
 
 def init_params(config, seed=0, scale=0.02, bias_scale=0.01):
@@ -422,7 +444,7 @@ _LAYER_LEAVES = ("attn_norm", "ffn_norm", "q_a_weight", "q_a_norm", "q_b_weight"
                  "index_q_weight", "index_k_weight", "index_k_norm_gamma",
                  "index_k_norm_beta", "index_w_weight", "q_weight", "q_norm", "k_norm",
                  "hc_attn_proj", "hc_attn_bias", "hc_attn_scale", "hc_ffn_proj",
-                 "hc_ffn_bias", "hc_ffn_scale", "attn_sink")
+                 "hc_ffn_bias", "hc_ffn_scale", "attn_sink") + tuple(_RANK_MINOR.values())
 
 
 def _layer(params, i, config):
@@ -492,7 +514,7 @@ def _index_project(h, c_q, positions, lp, c, cdt):
     head weights ``w`` (T, heads) in float32."""
     with jax.named_scope("mx.gen.index"):
         r = c.index_rope_dim
-        q_i = _dot(c_q, lp["index_q_weight"], "tr,rhe->the", cdt)
+        q_i = _dot(c_q, *_from_rank(lp, "index_q_weight"), cdt)
         q_i = jnp.concatenate([_rotary(q_i[..., :r], positions, c),
                                q_i[..., r:]], axis=-1).astype(cdt)
         k_i = _layernorm(_dot(h, lp["index_k_weight"], "td,de->te", cdt),
@@ -517,8 +539,8 @@ def _queries(c_q, positions, lp, c, cdt, scale=None):
     rows themselves where the query is full-rank), both times ``scale`` where
     one is given, each head's normed where the layer has ``q_norm``."""
     with jax.named_scope("mx.gen.latent_proj"):
-        w = lp["q_b_weight"] if "q_b_weight" in lp else lp["q_weight"]
-        q = _dot(c_q, w, "tr,rhe->the", cdt)
+        q = _dot(c_q, *_from_rank(lp, "q_weight" if "q_weight" in lp else "q_b_weight"),
+                 cdt)
         if scale is not None:
             q = q * scale
         if "q_norm" in lp:
@@ -527,16 +549,64 @@ def _queries(c_q, positions, lp, c, cdt, scale=None):
         return q[..., :c.d_nope].astype(cdt), q_rope.astype(cdt)
 
 
+def _rank_axes(lp, name):
+    """Layer matrix ``name`` (rank, H, width) as the layer holds it, and its
+    axes' letters: ``rhe`` as drawn, ``her`` as the predictor binds a leaf
+    of :func:`served_leaves` (``<name>_t``)."""
+    if name + "_t" in lp:
+        return lp[name + "_t"], "her"
+    return lp[name], "rhe"
+
+
+def _from_rank(lp, name):
+    """Layer matrix ``name`` and the einsum that takes rows (T, rank)
+    through it to (T, H, width)."""
+    w, axes = _rank_axes(lp, name)
+    return w, "tr,%s->the" % axes
+
+
 def _k_b(lp, c):
-    """The key half of the up-projection, (kv_rank, H, d_nope): a leaf of
-    its own where the layer keeps the halves apart, else the head of
-    ``kv_b_weight``."""
-    return lp["k_b_weight"] if "k_b_weight" in lp else lp["kv_b_weight"][..., :c.d_nope]
+    """The key half of the up-projection and its axes' letters (as
+    :func:`_rank_axes`): a leaf of its own where the layer keeps the halves
+    apart, else the head of ``kv_b_weight``'s width axis."""
+    if "k_b_weight" in lp:
+        return lp["k_b_weight"], "rhe"
+    w, axes = _rank_axes(lp, "kv_b_weight")
+    return (w[:, :c.d_nope] if axes == "her" else w[..., :c.d_nope]), axes
 
 
 def _v_b(lp, c):
-    """The value half, (kv_rank, H, d_v), likewise."""
-    return lp["v_b_weight"] if "v_b_weight" in lp else lp["kv_b_weight"][..., c.d_nope:]
+    """The value half, likewise."""
+    if "v_b_weight" in lp:
+        return lp["v_b_weight"], "rhe"
+    w, axes = _rank_axes(lp, "kv_b_weight")
+    return (w[:, c.d_nope:] if axes == "her" else w[..., c.d_nope:]), axes
+
+
+def _absorb_query(q_nope, lp, c, cdt):
+    """``q_nope W_kb^T`` (S, H, kv_rank) in the compute type: each head's
+    query in the latent space.  Against ``kv_b_weight`` as the predictor
+    binds it the query is zero-padded over the value rows, so the product
+    reads the layer's whole matrix where it lies (the same terms, and
+    zeros): a cut of it would be copied out of the stack on every step."""
+    if "kv_b_weight_t" in lp:
+        pad = jnp.zeros(q_nope.shape[:-1] + (c.d_v,), q_nope.dtype)
+        # heads first, as the product makes them (XLA:CPU has no bfloat16
+        # kernel for it with the heads between)
+        return _dot(jnp.concatenate([q_nope, pad], axis=-1), lp["kv_b_weight_t"],
+                    "she,her->hsr", cdt).transpose(1, 0, 2).astype(cdt)
+    w, axes = _k_b(lp, c)
+    return _dot(q_nope, w, "she,%s->shr" % axes, cdt).astype(cdt)
+
+
+def _up_value(o_lat, lp, c, cdt):
+    """``o_lat W_vb`` (S, H, d_v) float32 of latent outputs (S, H,
+    kv_rank); against the bound ``kv_b_weight`` the product's key rows are
+    dropped (as :func:`_absorb_query`)."""
+    if "kv_b_weight_t" in lp:
+        return _dot(o_lat, lp["kv_b_weight_t"], "shr,her->she", cdt)[..., c.d_nope:]
+    w, axes = _v_b(lp, c)
+    return _dot(o_lat, w, "shr,%s->she" % axes, cdt)
 
 
 def _output(o, lp, cdt):
@@ -797,8 +867,10 @@ def _expanded_attention(c_q, positions, length, latent, lp, c, cdt, index=None,
     T = c_q.shape[0]
     with jax.named_scope("mx.gen.attn"):
         c_kv = latent[:, :c.kv_rank]
-        k_nope = _dot(c_kv, _k_b(lp, c), "sr,rhe->she", cdt).astype(cdt)
-        v = _dot(c_kv, _v_b(lp, c), "sr,rhe->she", cdt).astype(cdt)
+        w, axes = _k_b(lp, c)
+        k_nope = _dot(c_kv, w, "sr,%s->she" % axes, cdt).astype(cdt)
+        w, axes = _v_b(lp, c)
+        v = _dot(c_kv, w, "sr,%s->she" % axes, cdt).astype(cdt)
         k_rope = latent[:, c.kv_rank:c.kv_rank + c.d_rope]
     scale = _softmax_scale(c)
     key_pos = jnp.arange(T)
@@ -939,7 +1011,7 @@ def paged_attention(pool, q_nope, q_rope, lengths, block_tables, lp, c, cdt, blo
     S = q_nope.shape[0]
     scale = _softmax_scale(c)
     with jax.named_scope("mx.gen.attn"):
-        q_lat = _dot(q_nope, _k_b(lp, c), "she,rhe->shr", cdt).astype(cdt)
+        q_lat = _absorb_query(q_nope, lp, c, cdt)
         pad = jnp.zeros((S, c.n_heads, _latent_width(c) - c.kv_rank - c.d_rope), cdt)
         q = jnp.concatenate([q_lat, q_rope, pad], axis=-1)       # (S, H, W)
         if kernel_platform() == "tpu":
@@ -951,7 +1023,7 @@ def paged_attention(pool, q_nope, q_rope, lengths, block_tables, lp, c, cdt, blo
         else:
             o_lat = blocked_attention(q, pool, block_tables, lengths, c.kv_rank,
                                       scale, block_k).astype(cdt)
-        o = _dot(o_lat, _v_b(lp, c), "shr,rhe->she", cdt)
+        o = _up_value(o_lat, lp, c, cdt)
     return _output(o, lp, cdt)
 
 
@@ -1121,9 +1193,7 @@ def make_decode_fn(config, slots, max_pages_per_slot, page_size,
                 page * page_size + chosen % page_size]           # (S, topk, w)
             c_kv = rows[..., :c.kv_rank]
             k_rope = rows[..., c.kv_rank:c.kv_rank + c.d_rope]
-            kv_b = lp["kv_b_weight"].astype(cdt)
-            q_lat = jnp.einsum("she,rhe->shr", q_nope, kv_b[..., :c.d_nope],
-                               preferred_element_type=jnp.float32).astype(cdt)
+            q_lat = _absorb_query(q_nope, lp, c, cdt)
             s = (jnp.einsum("shr,skr->shk", q_lat, c_kv,
                             preferred_element_type=jnp.float32)
                  + jnp.einsum("she,ske->shk", q_rope, k_rope,
@@ -1141,8 +1211,7 @@ def make_decode_fn(config, slots, max_pages_per_slot, page_size,
                 p = jnp.where(kept[:, None, :], jax.nn.softmax(s, axis=-1), 0.0)
             o_lat = jnp.einsum("shk,skr->shr", p.astype(cdt), c_kv,
                                preferred_element_type=jnp.float32).astype(cdt)
-            o = jnp.einsum("shr,rhe->she", o_lat, kv_b[..., c.d_nope:],
-                           preferred_element_type=jnp.float32)
+            o = _up_value(o_lat, lp, c, cdt)
             if gate is not None:
                 o = o * gate
         return _output(o, lp, cdt), jnp.sum(kept)

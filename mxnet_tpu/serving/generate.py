@@ -368,8 +368,10 @@ def _model_module(config_):
     module offers ``init_kv_cache`` (any pytree of page pools under one
     block table), ``kv_page_bytes``, ``make_prefill_fn`` and
     ``make_decode_fn``; optionally ``make_extend_fn`` (prefix tails,
-    speculation), ``_decode_block_k`` and ``compute_dtype_leaves(config)``
-    (the leaves its programs read only in the compute dtype); and
+    speculation), ``_decode_block_k``, ``compute_dtype_leaves(config)``
+    (the leaves its programs read only in the compute dtype) and
+    ``served_leaves(config)`` (leaves to bind transposed, under another
+    name: name -> (name bound, axes)); and
     ``decode_counters(config)``, the names of what its decode program
     counts on the device and returns beside the logits (none for the
     transformer)."""
@@ -447,8 +449,11 @@ class GenerativePredictor:
         wider than it: the programs then read the same values without
         casting every stacked matrix on every run, and no wider copy is
         kept beside them (nothing here trains them).  Every other leaf
-        keeps its dtype.  :meth:`pool_stats` reports what the bind held
-        (``param_bytes``) and cast (``params_cast``).
+        keeps its dtype.  The leaves the module names in
+        ``served_leaves(config)`` are bound transposed, under the name it
+        gives them: a copy made on the device in the place of the leaf.
+        :meth:`pool_stats` reports what the bind held (``param_bytes``),
+        cast (``params_cast``) and transposed (``params_transposed``).
     slots : int, optional
         Batch-slot count of the decode program
         (``MXNET_GENERATE_SLOTS``).
@@ -551,15 +556,20 @@ class GenerativePredictor:
         self._dtype_name = str(cdt)
 
         narrow = getattr(tfm, "compute_dtype_leaves", lambda _c: ())(c)
-        cast = []
+        served = getattr(tfm, "served_leaves", lambda _c: {})(c)
+        cast, transposed = [], []
 
         def bind(k, a):
-            # on the device, so a float32 leaf and its cast are held
-            # together for one leaf at a time
+            # on the device, so a float32 leaf and its cast (or its
+            # transpose) are held together for one leaf at a time
             if k in narrow and a.dtype.itemsize > cdt.itemsize:
                 cast.append(k)
-                return a.astype(cdt)
-            return a
+                a = a.astype(cdt)
+            if k in served:
+                k, axes = served[k]
+                transposed.append(k)
+                a = jnp.transpose(a, axes)
+            return k, a
 
         if mesh is not None:
             from jax.sharding import NamedSharding
@@ -572,8 +582,8 @@ class GenerativePredictor:
                     jnp.asarray(np.asarray(a)),
                     NamedSharding(mesh, spec if spec is not None else P()))
 
-            self._params = {k: bind(k, put(v, pspecs.get(k)))
-                            for k, v in params.items()}
+            self._params = dict(bind(k, put(v, pspecs.get(k)))
+                                for k, v in params.items())
             self._kv = put(tfm.init_kv_cache(c, num_pages, self.page_size),
                            tfm.kv_cache_spec(mesh))
         else:
@@ -582,12 +592,12 @@ class GenerativePredictor:
                     a = jnp.asarray(np.asarray(a))
                 return jax.device_put(a, device) if device is not None else a
 
-            self._params = {k: bind(k, put(v)) for k, v in params.items()}
+            self._params = dict(bind(k, put(v)) for k, v in params.items())
             self._kv = jax.tree.map(
                 put, tfm.init_kv_cache(c, num_pages, self.page_size))
         self._bind_stats = {
             "param_bytes": sum(int(a.nbytes) for a in self._params.values()),
-            "params_cast": len(cast)}
+            "params_cast": len(cast), "params_transposed": len(transposed)}
         if block_k is None:
             pick = getattr(tfm, "_decode_block_k", None)
             block_k = pick(c, self.slots, self.max_ctx) if pick else 0
@@ -808,8 +818,10 @@ class GenerativePredictor:
 
     def pool_stats(self):
         """The page pool's counters, and what the bind holds:
-        ``param_bytes`` (every bound leaf, one copy of each) and
-        ``params_cast`` (the leaves cast to the compute dtype)."""
+        ``param_bytes`` (every bound leaf, one copy of each),
+        ``params_cast`` (the leaves cast to the compute dtype) and
+        ``params_transposed`` (the leaves bound transposed,
+        ``served_leaves``)."""
         return dict(self.pool.stats(), **self._bind_stats)
 
     def sharded_stats(self):

@@ -117,6 +117,79 @@ def test_absorbed_decode_matches_expanded_forward():
     assert np.abs(absorbed - expanded[8:]).max() < TOL
 
 
+def served_logits(cfg, params, tok, n_prompt, steps=8, page=4):
+    """What ``GenerativePredictor`` serves, the leaves of ``served_leaves``
+    bound transposed: the prompt's last logits from its prefill, then
+    ``steps`` decode steps in slot 1 of 2 (slot 0 idle), each fed the next
+    token of ``tok``."""
+    pred = GenerativePredictor(cfg, params, slots=2, page_size=page, max_ctx=64)
+    assert pred.pool_stats()["params_transposed"] == len(mm.served_leaves(cfg))
+    pages = pred.pool.alloc(pred.pages_needed(n_prompt + steps))
+    out = [pred.prefill(tok[:n_prompt], pages[:pred.pages_needed(n_prompt)])]
+    table = np.zeros((2, pred.max_pages_per_slot), np.int32)
+    table[1, :len(pages)] = pages
+    for t in range(n_prompt, n_prompt + steps):
+        logits = pred.decode(np.array([0, tok[t]], np.int32), np.array([0, t], np.int32),
+                             table, np.array([False, True]))
+        assert not logits[0].any()                      # the idle slot
+        out.append(logits[1])
+    return np.stack(out)
+
+
+@pytest.mark.parametrize("n_prompt", [5, 21])
+def test_served_prefill_and_eight_decode_steps_match_the_forward(n_prompt):
+    """Through the predictor, whose bind transposes ``q_b_weight``,
+    ``index_q_weight`` and ``kv_b_weight``: prefill and 8 decode steps give
+    the one-shot forward's logits on the leaves as drawn."""
+    cfg, params, _m = build(seed=7)
+    tok = tokens(n_prompt + 8, seed=4)
+    want = np.asarray(mm.make_forward_fn(cfg)(params, jnp.asarray(tok)))
+    got = served_logits(cfg, params, tok, n_prompt)
+    assert np.abs(want).max() > 0.5
+    assert np.abs(got - want[n_prompt - 1:]).max() < TOL
+
+
+def test_served_bind_compiles_each_program_once():
+    """The transposed leaves are placed as the others are: the second
+    prefill and decode run what the first compiled."""
+    cfg, params, _m = build()
+    pred = GenerativePredictor(cfg, params, slots=2, page_size=4, max_ctx=64)
+    pages = pred.pool.alloc(4)
+    table = np.zeros((2, pred.max_pages_per_slot), np.int32)
+    table[1, :4] = pages
+    for t in (5, 6):
+        pred.prefill(tokens(5), pages[:2])
+        pred.decode(np.array([0, 1], np.int32), np.array([0, t], np.int32), table,
+                    np.array([False, True]))
+    assert pred._prefill_exec(pred.pick_bucket(5))._cache_size() == 1
+    assert pred._decode_exec()._cache_size() == 1
+
+
+def test_zero_padded_absorption_matches_the_split_one():
+    """Decode's products against a whole ``kv_b_weight`` as the predictor
+    binds it: the query zero-padded over the value rows gains only exact
+    zeros (float32 accumulation's order aside), and the value half of the
+    up-projection is the product with the value half alone."""
+    cfg, params, _m = build()
+    served = {name: jnp.transpose(params[k], axes)
+              for k, (name, axes) in mm.served_leaves(cfg).items()}
+    lp = mm._layer(dict(params, **served), 1, cfg)
+    kv_b = lp["kv_b_weight"]
+    assert lp["kv_b_weight_t"].shape == (cfg.n_heads, cfg.d_nope + cfg.d_v, cfg.kv_rank)
+    del lp["kv_b_weight"]
+    rng = np.random.RandomState(6)
+    q_nope = jnp.asarray(rng.randn(2, cfg.n_heads, cfg.d_nope).astype(np.float32))
+    o_lat = jnp.asarray(rng.randn(2, cfg.n_heads, cfg.kv_rank).astype(np.float32))
+    split_q = jnp.einsum("she,rhe->shr", q_nope, kv_b[..., :cfg.d_nope])
+    split_o = jnp.einsum("shr,rhe->she", o_lat, kv_b[..., cfg.d_nope:])
+    got_q = mm._absorb_query(q_nope, lp, cfg, jnp.float32)
+    got_o = mm._up_value(o_lat, lp, cfg, jnp.float32)
+    assert got_q.shape == (2, cfg.n_heads, cfg.kv_rank)
+    assert got_o.shape == (2, cfg.n_heads, cfg.d_v)
+    np.testing.assert_allclose(np.asarray(got_q), np.asarray(split_q), rtol=1e-6, atol=1e-6)
+    np.testing.assert_array_equal(np.asarray(got_o), np.asarray(split_o))
+
+
 def test_equal_index_scores_keep_the_earlier_position():
     """The mask the prefill builds without a sort is the set ``lax.top_k``
     returns, ties and rows with fewer than k entries included."""
@@ -217,11 +290,22 @@ def test_generate_server_serves_it_under_page_growth_and_slot_reuse():
 
 def test_bfloat16_weights_are_bound_as_given():
     """A ``jax.Array`` is bound without a copy through the host and in its
-    own type: 3.9 B bfloat16 parameters have no float32 twin."""
+    own type: 3.9 B bfloat16 parameters have no float32 twin.  The three
+    leaves of ``served_leaves`` are transposed on the device, in the place
+    of the leaf handed in."""
     cfg, params, _m = build(dtype="bfloat16")
     params = {k: v.astype(jnp.bfloat16) for k, v in params.items()}
     pred = GenerativePredictor(cfg, params, slots=2, page_size=4, max_ctx=32)
-    assert all(pred._params[k] is params[k] for k in params)
+    served = mm.served_leaves(cfg)
+    assert sorted(served) == ["index_q_weight", "kv_b_weight", "q_b_weight"]
+    assert all(pred._params[k] is params[k] for k in params if k not in served)
+    assert not set(served) & set(pred._params)
+    for k, (name, axes) in served.items():
+        bound = pred._params[name]
+        assert bound.dtype == jnp.bfloat16
+        np.testing.assert_array_equal(np.asarray(bound, np.float32),
+                                      np.asarray(params[k], np.float32).transpose(axes))
+    assert pred.pool_stats()["params_transposed"] == 3
     assert pred._params["q_a_weight"].dtype == jnp.bfloat16
     assert all(a.dtype == jnp.bfloat16 for a in pred._kv["latent"] + pred._kv["index"])
     with pytest.raises(Exception, match="no extend program"):

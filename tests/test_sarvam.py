@@ -18,7 +18,7 @@ import pytest
 
 from mxnet_tpu import profiler
 from mxnet_tpu.models import mla_moe as mm
-from mxnet_tpu.serving import GenerateServer
+from mxnet_tpu.serving import GenerateServer, GenerativePredictor
 
 ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 if ROOT not in sys.path:
@@ -180,6 +180,29 @@ def test_prefill_then_decode_matches_the_reference_full_forward(n_prompt, block_
         assert c["moe_tokens"] == experts                 # one active slot
         assert c["moe_pairs_held"] <= cfg.experts_per_token * experts
         assert c["moe_experts_touched"] <= c["moe_pairs_held"] <= c["moe_pairs_at_max_load"]
+
+
+@pytest.mark.parametrize("n_prompt", [5, 21])
+def test_served_prefill_and_eight_decode_steps_match_the_forward(n_prompt):
+    """Through the predictor, whose bind transposes the leaves of
+    ``served_leaves``: prefill and 8 decode steps (full-rank query and every cached row attended in the
+    blocked form) give the one-shot
+    forward's logits on the leaves as drawn."""
+    cfg, params, _m = build(seed=7)
+    tok = tokens(n_prompt + 8, seed=4)
+    want = np.asarray(mm.make_forward_fn(cfg)(params, jnp.asarray(tok)))
+    pred = GenerativePredictor(cfg, params, slots=2, page_size=4, max_ctx=64)
+    assert sorted(mm.served_leaves(cfg)) == ["kv_b_weight"]
+    assert pred.pool_stats()["params_transposed"] == len(mm.served_leaves(cfg))
+    pages = pred.pool.alloc(pred.pages_needed(n_prompt + 8))
+    got = [pred.prefill(tok[:n_prompt], pages[:pred.pages_needed(n_prompt)])]
+    table = np.zeros((2, pred.max_pages_per_slot), np.int32)
+    table[1, :len(pages)] = pages
+    for t in range(n_prompt, n_prompt + 8):
+        got.append(pred.decode(np.array([0, tok[t]], np.int32), np.array([0, t], np.int32),
+                               table, np.array([False, True]))[1])
+    assert np.abs(want).max() > 0.5
+    assert np.abs(np.stack(got) - want[n_prompt - 1:]).max() < TOL
 
 
 def test_prefill_then_decode_through_the_interpreted_kernel_at_64_row_pages(monkeypatch):

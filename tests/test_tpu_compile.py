@@ -306,16 +306,20 @@ def test_sarvam_decode_reads_its_five_latent_pools_in_place(one_chip, monkeypatc
 # (``serving.generate._decode_program``): the token is chosen on the device and
 # can be fed to the next step there. The modules' own programs stay what they
 # were: sha256 of the StableHLO text each lowers to for the TPU at its cell's
-# sizes, taken at the commit before (6c94e2d). A PR that means to change one
-# says so and replaces its digest:
+# sizes, taken at the commit before (6c94e2d). GLM-5's replaced when its
+# decode came to read ``q_b_weight``, ``index_q_weight`` and ``kv_b_weight``
+# as the predictor binds them (``served_leaves``); both pool cells' taken
+# again at the pools the cells hold since their answers grew to 4096 tokens
+# (20480 and 16384 rows a slot; LongCat's the same on the commit before).
+# A PR that means to change one says so and replaces its digest:
 #   python -c "import tests.test_tpu_compile as t; print(t.decode_digests())"
 MODULE_DECODE_PROGRAMS = {
     "opt-1.3b.serve-chat":
         "72d700654c3a0e7db8c247bd392929f8df8da70ea0fe3e80be7a0c5a7d447728",
     "glm-5.decode-pool-16k":
-        "f9292b53755e26a6d1ccd3f127041541bdc2fd0432c42c5f89203ea5787402a3",
+        "11b5385c2ea0334d4b053321d7c74f9acf82eb57959642dd2c4eb19769dbcd6c",
     "longcat-flash.decode-pool-12k":
-        "41949d1eb0a3197721cb27a6924453d2becf1b3a4035b2df17906a8e4db095c4",
+        "929c2ea9133c555eb5ade78672aeec9b69da158afba8c7762b356a5abd98aee2",
 }
 
 
@@ -330,17 +334,22 @@ def _served_cells():
     glm, longcat = mla_moe.LatentMoEConfig(), scmoe.ShortcutMoEConfig()
 
     def table(mod, cfg):
-        return {k: (s, jnp.dtype(cfg.dtype))
-                for k, (s, _kind) in mod.param_shapes(cfg).items()}
+        # the leaves as ``GenerativePredictor`` binds them
+        out = {k: (s, jnp.dtype(cfg.dtype))
+               for k, (s, _kind) in mod.param_shapes(cfg).items()}
+        for k, (name, axes) in getattr(mod, "served_leaves", lambda _c: {})(cfg).items():
+            s, dtype = out.pop(k)
+            out[name] = (tuple(s[a] for a in axes), dtype)
+        return out
 
     return {
         "opt-1.3b.serve-chat": (
             tfm, opt, {k: (s, jnp.float32)
                        for k, s in _param_shapes(opt).items()}, 16, 1216 // 16, 16),
         "glm-5.decode-pool-16k": (
-            mla_moe, glm, table(mla_moe, glm), 16, 18688 // 16, 16),
+            mla_moe, glm, table(mla_moe, glm), 16, 20480 // 16, 16),
         "longcat-flash.decode-pool-12k": (
-            scmoe, longcat, table(scmoe, longcat), 16, 15104 // 16, 16),
+            scmoe, longcat, table(scmoe, longcat), 16, 16384 // 16, 16),
     }
 
 
@@ -428,3 +437,120 @@ def test_served_decode_chooses_the_token_on_the_device(cell, one_chip,
     pool_bytes = sum(math.prod(x.shape) * x.dtype.itemsize
                      for x in jax.tree.leaves(cache))
     assert compiled.memory_analysis().alias_size_in_bytes >= pool_bytes
+
+
+# -- the latent decode reads every weight stack where it lies -----------------
+# benchmark/configs/<config>.json and benchmark/traffic/<mix>.json of the three
+# cells that serve ``models/mla_moe.py``
+_LATENT_POOLS = {"glm-5.decode-pool-16k": ("glm-5-ep16", "decode-pool-16k"),
+                 "hy4-preview.decode-pool-32k": ("hy4-preview-ep32", "decode-pool-32k"),
+                 "sarvam-105b.decode-pool-64k": ("sarvam-105b-ep16", "decode-pool-64k")}
+# decode's temporaries (bytes) when every stack came in the order it is drawn
+# in and decode cut ``kv_b_weight`` into its key and value halves: each
+# layer's ``q_b_weight``, ``index_q_weight`` and ``kv_b_weight`` copied out of
+# its stack on every step
+_TEMP_BEFORE = {"glm-5.decode-pool-16k": 499874304,
+                "hy4-preview.decode-pool-32k": 589467136,
+                "sarvam-105b.decode-pool-64k": 60897792}
+_DTYPE_BYTES = {"bf16": 2, "f32": 4, "s32": 4, "u32": 4, "pred": 1}
+
+
+def _hbm_bytes(shape_text, echoes=()):
+    """Bytes of the arrays in an instruction's result that lie in HBM: an
+    array with a memory space (``S(1)`` is the core's own memory) is left
+    out, and so is one that only names an operand (an async copy's source)."""
+    total = 0
+    for array in re.findall(r"(?:bf16|f32|s32|u32|pred)\[[\d,]*\]\{[^}]*\}",
+                            shape_text):
+        if re.search(r"S\(\d+\)\}$", array) or array in echoes:
+            continue
+        dtype, dims = re.match(r"(\w+)\[([\d,]*)\]", array).groups()
+        total += _DTYPE_BYTES[dtype] * math.prod(int(d) for d in dims.split(",") if d)
+    return total
+
+
+def weight_copies(text, n_weights):
+    """name -> HBM bytes written by each instruction of the entry
+    computation that reads one of the first ``n_weights`` parameters (the
+    weight stacks), directly or through a bitcast."""
+    entry = text[text.index("\nENTRY "):].split("\n}\n")[0]
+    weights = {}
+    for name, shape, at in re.findall(r"(%\S+) = (\S+) parameter\((\d+)\)", entry):
+        if int(at) < n_weights:
+            weights[name] = shape
+    for name, shape, src in re.findall(r"(%\S+) = (\S+) bitcast\((%[^)]+)\)", entry):
+        if src in weights:
+            weights[name] = shape
+    out = {}
+    for line in entry.splitlines():
+        m = re.match(r"\s*(?:ROOT )?(%\S+) = (.+?) ([a-z-]+)\((%[^)]*)\)", line)
+        if not m or m.group(3) in ("parameter", "bitcast", "get-tuple-element", "tuple"):
+            continue
+        read = [weights[o] for o in re.findall(r"%[\w.-]+", m.group(4)) if o in weights]
+        if read:
+            out[m.group(1)] = _hbm_bytes(m.group(2), read)
+    return out
+
+
+@pytest.mark.parametrize("bound", ["as_drawn", "as_served"])
+@pytest.mark.parametrize("cell", sorted(_LATENT_POOLS))
+def test_latent_decode_copies_no_weight_stack(cell, bound, one_chip, monkeypatch):
+    """The decode step of each cell that serves ``mla_moe``, at its slots,
+    pages and pool, with the leaves as ``GenerativePredictor`` binds them
+    (``served_leaves`` transposed): no instruction that reads a weight stack
+    writes more than 8 MB to HBM (the head's product, the largest left,
+    writes 1.2-2.9 MB), and the temporaries are smaller than when each
+    layer's ``q_b_weight``, ``index_q_weight`` and ``kv_b_weight`` were
+    copied out of their stacks on every step.  In the order the leaves are
+    drawn in (the control) the scan finds those copies."""
+    import json
+    import os
+
+    import mxnet_tpu.kernels  # noqa: F401  (loads kernels.flash_attention)
+    from mxnet_tpu.models import mla_moe
+
+    from benchmark import traffic_gen
+    from benchmark.runners.serve_decode_pool_sarvam import pool_pages
+
+    for name in ("mxnet_tpu.models.mla_moe", "mxnet_tpu.kernels.flash_attention"):
+        monkeypatch.setattr(sys.modules[name], "kernel_platform", lambda: "tpu")
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    config, traffic = _LATENT_POOLS[cell]
+    with open(os.path.join(root, "benchmark", "configs", config + ".json")) as f:
+        cfg = mla_moe.LatentMoEConfig(**json.load(f)["program"])
+    with open(os.path.join(root, "benchmark", "traffic", traffic + ".json")) as f:
+        mix = json.load(f)
+    slots, page = mix["slots"], mix["page_size"]
+    per_slot = mix["max_ctx"] // page
+    pages = slots * per_slot
+    if "pool_margin" in mix:
+        prompts = traffic_gen._lognormal_quantiles(
+            mix["prompt_tokens"], (np.arange(slots) + 0.5) / slots)
+        pages = pool_pages(prompts, mix["answer_tokens"], page, slots, mix["pool_margin"])
+
+    shapes = {k: s for k, (s, _kind) in mla_moe.param_shapes(cfg).items()}
+    if bound == "as_served":
+        for k, (name, axes) in mla_moe.served_leaves(cfg).items():
+            drawn = shapes.pop(k)
+            shapes[name] = tuple(drawn[a] for a in axes)
+    params = {k: jax.ShapeDtypeStruct(s, jnp.bfloat16, sharding=one_chip)
+              for k, s in shapes.items()}
+    cache = jax.eval_shape(lambda: mla_moe.init_kv_cache(cfg, pages, page))
+    fn = jax.jit(mla_moe.make_decode_fn(
+        cfg, slots, per_slot, page,
+        block_k=mla_moe._decode_block_k(cfg, slots, per_slot * page)),
+        in_shardings=(one_chip, jax.tree.map(lambda _: one_chip, cache)) + (one_chip,) * 4,
+        donate_argnums=(1,))
+    step = (jax.ShapeDtypeStruct((slots,), jnp.int32),) * 2 + (
+        jax.ShapeDtypeStruct((slots, per_slot), jnp.int32),
+        jax.ShapeDtypeStruct((slots,), jnp.bool_))
+    compiled = fn.lower(params, cache, *step).compile()
+    written = weight_copies(compiled.as_text(), len(shapes))
+    temp = compiled.memory_analysis().temp_size_in_bytes
+    # the head's product reads ``head_weight``: the scan sees the stacks
+    assert len(written) > 5 * cfg.n_layers
+    if bound == "as_served":
+        assert max(written.values()) < 8e6, sorted(written.items(), key=lambda kv: -kv[1])[:3]
+        assert temp < _TEMP_BEFORE[cell]
+    else:
+        assert max(written.values()) > 8e6
